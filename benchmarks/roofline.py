@@ -39,12 +39,12 @@ from repro.core.costmodel import MachineProfile, dryrun_record_terms
 # TPU v5e table rates — kept as module constants for scripts that import
 # them, but sourced from (and asserted against) the cost model's profile
 # table so the two can never drift apart.
-_PROFILE = MachineProfile.default("tpu:v5e")
+_PROFILE = MachineProfile.default("tpu:TPU v5 lite")
 PEAK_FLOPS = _PROFILE.peak_flops   # 197e12  bf16
 HBM_BW = _PROFILE.hbm_bw           # 819e9   bytes/s
-ICI_BW = _PROFILE.link_bw          # 50e9    bytes/s/link
+ICI_BW = _PROFILE.link_bw          # 200e9   bytes/s chip-to-chip
 DCN_BW = _PROFILE.dcn_bw           # 25e9    bytes/s cross-pod
-HBM_BYTES = _PROFILE.hbm_bytes     # 16 GiB
+HBM_BYTES = _PROFILE.hbm_bytes     # 16 GB
 
 
 def load_records(out_dir="results/dryrun", mesh=None, variant=None):

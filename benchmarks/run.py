@@ -66,6 +66,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.cache import enable_compile_cache
+
 RESULTS = {}
 
 #: per-bench machine-readable records (written by --json): name →
@@ -80,6 +82,11 @@ SWEEPS: dict[str, list] = {}
 #: the executor the sweep consumers retune — sweep keys are validated
 #: against its declared tunables (``tdp.executor_tunables``) in main().
 SWEEP_EXECUTOR = "pallas_windowed"
+
+#: --windowed NAME: the spelling the windowed variants run under —
+#: ``pallas_windowed_interpret`` (the Pallas interpreter, any host) or
+#: ``pallas_windowed`` (compiled by Mosaic; TPU only).
+WINDOWED = "pallas_windowed_interpret"
 
 #: display/record abbreviations for sweep-variant keys (keeps the
 #: PR 4 ``fused_windowed_pb<N>`` JSON spelling stable).
@@ -282,77 +289,79 @@ def bench_masked_copy(quick=False):
 # fused vs unfused LB timestep (stencil-aware launch)
 # ---------------------------------------------------------------------------
 
-#: subprocess body for the sharded pencil variant: this process owns the
-#: single-device benches, so the multi-device run gets its own
-#: interpreter with forced host devices (same pattern as
-#: tests/test_distributed.py).  Prints one JSON doc on the last line.
+def _pencil_records(grid, reps, steps, devices):
+    """The 2×2-pencil fused two_launch lane over ``devices[:4]``, overlap
+    off and on: per-variant median step time plus the analytic exchange
+    budget (``comm_stats``)."""
+    from jax.sharding import Mesh
+
+    from repro.lb.params import LBParams
+    from repro.lb.sim import BinaryFluidSim
+
+    p = LBParams(A=0.125, B=0.125, kappa=0.02)
+    mesh = Mesh(np.array(devices[:4]).reshape(2, 2), ("px", "py"))
+    out = {}
+    for key, overlap in (("fused_pencil_2x2", False),
+                         ("fused_pencil_2x2_overlap", True)):
+        sim = BinaryFluidSim(grid, params=p, fused="two_launch", mesh=mesh,
+                             shard_axis=("px", "py"), overlap=overlap)
+        st = sim.init_spinodal(seed=0, noise=0.05)
+        ws = sim.programs["collide"].step({"f": st.f, "g": st.g})
+        exe = sim.programs["fused"]
+        ts = _time_stats(lambda: exe.run(dict(ws), steps), reps=reps,
+                         warmup=1)
+        cs = exe.comm_stats()
+        out[key] = {"median_s": ts["median_s"] / steps,
+                    "overlap": cs["overlap"],
+                    "decomposition": cs["decomposition"],
+                    "interior_fraction": cs["interior_fraction"],
+                    "exchanged_bytes_per_step":
+                        cs["exchanged_bytes_per_step"],
+                    "ppermutes_per_step": cs["ppermutes_per_step"]}
+    return out
+
+
+#: child body of the pencil lane on a CPU host: the parent runs its
+#: benches on one device, so four forced host devices need their own
+#: interpreter.  Prints one JSON doc on the last line.
 _SHARDED_BENCH_SRC = r"""
-import json, os, sys, time
-import jax, numpy as np
-from repro.launch.mesh import make_test_mesh
-from repro.lb.params import LBParams
-from repro.lb.sim import BinaryFluidSim
-
+import json, sys
+import jax
+from benchmarks.run import _pencil_records
 grid, reps, steps = json.loads(sys.argv[1])
-grid = tuple(grid)
-p = LBParams(A=0.125, B=0.125, kappa=0.02)
-mesh = make_test_mesh((2, 2), ("px", "py"))
-
-def median_step_s(sim):
-    st = sim.init_spinodal(seed=0, noise=0.05)
-    ws = sim.programs["collide"].step({"f": st.f, "g": st.g})
-    exe = sim.programs["fused"]
-    run = lambda: jax.block_until_ready(exe.run(dict(ws), steps))
-    run()                                    # compile + warm
-    ts = []
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        run()
-        ts.append(time.perf_counter() - t0)
-    return float(np.median(ts)) / steps, exe
-
-out = {}
-for key, overlap in (("fused_pencil_2x2", False),
-                     ("fused_pencil_2x2_overlap", True)):
-    sim = BinaryFluidSim(grid, params=p, fused="two_launch", mesh=mesh,
-                         shard_axis=("px", "py"), overlap=overlap)
-    t, exe = median_step_s(sim)
-    cs = exe.comm_stats()
-    out[key] = {"median_s": t, "overlap": cs["overlap"],
-                "decomposition": cs["decomposition"],
-                "interior_fraction": cs["interior_fraction"],
-                "exchanged_bytes_per_step": cs["exchanged_bytes_per_step"],
-                "ppermutes_per_step": cs["ppermutes_per_step"]}
-print(json.dumps(out))
+print(json.dumps(_pencil_records(tuple(grid), reps, steps, jax.devices())))
 """
 
 
 def _bench_sharded_fused(grid, reps, steps):
-    """Run the 2×2-pencil fused two_launch bench in a 4-fake-device
-    subprocess; returns the per-variant records (or None on failure —
-    the sharded lane is additive, never fatal to the bench)."""
+    """The pencil lane's records, or ``None`` (with the reason printed)
+    where it cannot run.  A chip belongs to one process, so on an
+    accelerator the lane runs here, on this process's devices; on a CPU
+    host it runs in a child with four forced host devices.  A lane that
+    starts and fails raises."""
     import subprocess
 
+    if jax.default_backend() != "cpu":
+        devices = jax.devices()
+        if len(devices) < 4:
+            print(f"[benchmarks] pencil lane not run: it needs 4 devices, "
+                  f"this process holds {len(devices)}", file=sys.stderr)
+            return None
+        return _pencil_records(grid, reps, steps, devices)
+    root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=4")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(os.path.dirname(__file__), "..", "src")]
+        [os.path.join(root, "src"), root]
         + env.get("PYTHONPATH", "").split(os.pathsep))
-    try:
-        res = subprocess.run(
-            [sys.executable, "-c", _SHARDED_BENCH_SRC,
-             json.dumps([list(grid), reps, steps])],
-            capture_output=True, text=True, timeout=1200, env=env)
-    except (subprocess.TimeoutExpired, OSError) as e:
-        print(f"[benchmarks] sharded fused bench skipped: {e}",
-              file=sys.stderr)
-        return None
+    res = subprocess.run(
+        [sys.executable, "-c", _SHARDED_BENCH_SRC,
+         json.dumps([list(grid), reps, steps])],
+        capture_output=True, text=True, timeout=1200, env=env)
     if res.returncode != 0:
-        print(f"[benchmarks] sharded fused bench failed:\n{res.stderr}",
-              file=sys.stderr)
-        return None
+        raise RuntimeError(f"pencil lane failed:\n{res.stderr}")
     return json.loads(res.stdout.strip().splitlines()[-1])
 
 
@@ -378,7 +387,7 @@ def bench_fused_step(quick=False):
     # ProgramPlan's aggregated est. HBM bytes).  The extra
     # "fused_program_scan" variant runs K steps under one lax.scan with
     # donated ping-pong field buffers (CompiledProgram.run).
-    wt = tdp.Target("pallas_windowed", interpret=True)
+    wt = tdp.Target(WINDOWED)
     sim_u = BinaryFluidSim(grid, params=p)
     sim_f = BinaryFluidSim(grid, params=p, fused="one_launch")
     sim_f2 = BinaryFluidSim(grid, params=p, fused="two_launch")
@@ -402,7 +411,8 @@ def bench_fused_step(quick=False):
          sim_f.programs["fused"].step, (ws,)),
         ("fused (two launches, φ intermediate)", "fused_two", "xla",
          sim_f2.programs["fused"].step, (ws,)),
-        ("fused (windowed, gather-free, interpret)", "fused_windowed",
+        (f"fused (windowed, gather-free"
+         f"{', interpret' if wt.interpret else ''})", "fused_windowed",
          "pallas_windowed", sim_w.programs["fused"].step, (ws,)),
     ]
     progs = {
@@ -505,7 +515,8 @@ def bench_fused_step(quick=False):
                  f"{base_t/t:.2f}×", f"{hbm['fused_two']/2**20:.1f}"))
 
     # Sharded lane: the 2×2-pencil decomposition of the same fused_two
-    # step on 4 forced host devices (own subprocess), overlap off vs on.
+    # step on 4 devices (a CPU host's forced host devices, in a child
+    # process; an accelerator's own, in this one), overlap off vs on.
     # The record carries the analytic exchange budget (comm_stats) and
     # the achieved overlap — the fraction of the no-overlap step the
     # interior/boundary split hides.  These CPU numbers demonstrate the
@@ -519,7 +530,7 @@ def bench_fused_step(quick=False):
                 "ns_per_site_step": v["median_s"] / n * 1e9,
                 "executor": "xla", "mesh": "2x2",
             }
-            rows.append((f"{key.replace('_', ' ')} (4 host devices)",
+            rows.append((f"{key.replace('_', ' ')} (4 devices)",
                          f"{v['median_s']*1e3:.2f}",
                          f"{v['median_s']/n*1e9:.1f}",
                          f"{n/v['median_s']/1e6:.1f}",
@@ -561,7 +572,7 @@ def _bench_stencil_launch(name, spec, make_input, quick):
     n = lat.nsites
     x = make_input(lat)
 
-    wt = tdp.Target("pallas_windowed", interpret=True)
+    wt = tdp.Target(WINDOWED)
     targets = [("xla", None, tdp.Target("xla", vvl=128)),
                ("pallas_interpret", None,
                 tdp.Target("pallas_interpret", vvl=128)),
@@ -904,7 +915,8 @@ SWEEP_CONSUMERS = ("fused_step", "stream", "grad")
 
 def main(argv=None):
     global AUTOTUNE, GRID_OVERRIDE, REPS_OVERRIDE, TUNING_CACHE
-    global TOP_K, PREDICT
+    global TOP_K, PREDICT, WINDOWED
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None, metavar="NAME[,NAME...]",
@@ -930,6 +942,10 @@ def main(argv=None):
     ap.add_argument("--steps", type=int, default=None, metavar="K",
                     help="override timing repetitions per variant (and "
                          "autotune reps) — smoke runs")
+    ap.add_argument("--windowed", default=WINDOWED,
+                    choices=("pallas_windowed", "pallas_windowed_interpret"),
+                    help="executor of the windowed variants: compiled by "
+                         "Mosaic (TPU only) or the Pallas interpreter")
     ap.add_argument("--tuning-cache", default="results/tuning",
                     help="tdp.autotune on-disk cache directory")
     ap.add_argument("--top-k", type=int, default=None, metavar="K",
@@ -954,6 +970,7 @@ def main(argv=None):
             return 2
         REPS_OVERRIDE = args.steps
     AUTOTUNE = bool(args.autotune)
+    WINDOWED = args.windowed
     TUNING_CACHE = args.tuning_cache
     TOP_K = args.top_k
     PREDICT = bool(args.predict)

@@ -16,11 +16,13 @@ import sys, os, time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro import tdp
+from repro.launch.cache import enable_compile_cache
 from repro.lb.params import LBParams
 from repro.lb.sim import BinaryFluidSim
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--grid", type=int, default=16)
     ap.add_argument("--steps", type=int, default=400)

@@ -57,6 +57,7 @@ from .registry import (
 )
 from .api import (
     LaunchPlan,
+    WindowShapeError,
     WindowVmemError,
     gather_neighbors,
     halo_extend,
@@ -117,7 +118,7 @@ __all__ = [
     # declarative API
     "Target", "as_target", "FieldSpec", "KernelSpec", "kernel",
     "tdp_launch", "launch_plan", "LaunchPlan", "gather_neighbors",
-    "halo_extend", "pad_sites", "WindowVmemError",
+    "halo_extend", "pad_sites", "WindowShapeError", "WindowVmemError",
     # memory layout axis (SoA ↔ AoSoA)
     "LAYOUTS", "aosoa_nblocks", "aosoa_to_soa", "soa_to_aosoa",
     "register_executor", "unregister_executor", "get_executor",

@@ -38,6 +38,7 @@ from typing import Mapping, Sequence
 import jax
 import jax.numpy as jnp
 
+from .costmodel import vmem_limit_bytes
 from .lattice import Lattice, Stencil
 from .layout import aosoa_to_soa, soa_to_aosoa
 from .memory import BatchedConst, TargetConst
@@ -72,6 +73,18 @@ def _prod_shape(shape) -> int:
     for s in shape:
         out *= int(s)
     return out
+
+
+def _tiled_bytes(shape, itemsize: int) -> int:
+    """Bytes of a VMEM block of ``shape`` once Mosaic pads its minor dim
+    to 128 lanes and its second-minor dim to whole ``8·4/itemsize``
+    sublane rows."""
+    dims = [int(s) for s in shape]
+    dims[-1] = -(-dims[-1] // 128) * 128
+    if len(dims) > 1:
+        sub = 8 * 4 // itemsize
+        dims[-2] = -(-dims[-2] // sub) * sub
+    return _prod_shape(dims) * itemsize
 
 
 def gather_neighbors(x: jax.Array, shape: tuple[int, ...],
@@ -284,24 +297,62 @@ class LaunchPlan:
         ``(plane_block + 2·radius)``-plane window of the extended array —
         no ``noffsets`` factor (docs/stencil.md, "VMEM footprint rule").
         """
-        out_rows = sum(self.out_ncomp)
         if self.wants != "halo_extended":
+            out_rows = sum(self.out_ncomp)
             in_rows = sum((s.noffsets if s is not None else 1) * c
                           for c, s in self._fields())
             return (in_rows + out_rows) * self.vvl * itemsize
+        total = 3 * sum(b for _, b in self.window_blocks(itemsize))
+        p = int(self.target.tune("plane_block", 1))
+        if p > 1:
+            # the p rows of every offset are concatenated into a
+            # (noffsets, ncomp, p·V) chunk the compiler materialises
+            v = p * _prod_shape(self.shape[1:])
+            total += sum(_tiled_bytes((s.noffsets, c, v), itemsize)
+                         for c, s in self._fields() if s is not None)
+        return total
+
+    def window_blocks(self, itemsize: int = 4) -> list[tuple[int, int]]:
+        """The VMEM blocks of one windowed grid step, as ``(field index,
+        bytes)`` pairs (index ``-1`` for outputs): every window plane of
+        every stencil field, each pointwise block and each output block,
+        with its two minor dims padded to Mosaic's ``(8·4/itemsize, 128)``
+        tile.
+
+        :meth:`vmem_bytes_estimate` counts each block three times: the
+        pipeline's two buffers (the next step's DMA overlaps this step's
+        compute) and the value the kernel body loads.  At
+        ``plane_block=1`` the ``(noffsets, ncomp, V)`` chunk assembled
+        from the loaded planes is never materialised whole — the site
+        kernels read a few components per offset and the compiler keeps
+        only the slices read — so it adds nothing; above 1 the rows are
+        concatenated and the whole chunk is counted.  At 128² planes the
+        estimate bounds what Mosaic allocates for the fused LB kernels:
+        the compile-only tests in ``tests/test_tpu_compile.py`` compile
+        them with the estimate as the limit.
+        """
         if self.shape is None:
             raise ValueError("halo_extended estimates need a lattice shape")
         p = int(self.target.tune("plane_block", 1))
-        rest = _prod_shape(self.shape[1:]) if len(self.shape) > 1 else 1
-        total = out_rows * p * rest
-        for c, s in self._fields():
+        rest = tuple(self.shape[1:])
+        rest_n = _prod_shape(rest)
+        aosoa = self.layout == "aosoa"
+        vvl = int(self.vvl)
+        blocks = []
+        for i, (c, s) in enumerate(self._fields()):
             if s is None:
-                total += c * p * rest
-            else:
-                ext = self._ext_shape(s)
-                window = p + 2 * s.radius_per_dim()[0]
-                total += c * window * _prod_shape(ext[1:])
-        return total * itemsize
+                shape = ((p, rest_n // vvl, c, vvl) if aosoa
+                         else (c, p, *rest))
+                blocks.append((i, _tiled_bytes(shape, itemsize)))
+                continue
+            ext_rest = self._ext_shape(s)[1:]
+            shape = ((1, -(-_prod_shape(ext_rest) // vvl), c, vvl) if aosoa
+                     else (c, 1, *ext_rest))
+            window = p + 2 * s.radius_per_dim()[0]
+            blocks.append((i, window * _tiled_bytes(shape, itemsize)))
+        for c in self.out_ncomp:
+            blocks.append((-1, _tiled_bytes((c, p * rest_n), itemsize)))
+        return blocks
 
     def hbm_bytes_estimate(self, itemsize: int = 4) -> int:
         """Main-memory footprint of the executor's prepared operands plus
@@ -459,39 +510,55 @@ class WindowVmemError(ValueError):
     """
 
 
-def _vmem_cap() -> int:
-    # lazy: repro.core.costmodel is stdlib-at-import but keep the single
-    # authoritative constant there without risking an import cycle here
-    from .costmodel import DEFAULT_VMEM_LIMIT
-    return DEFAULT_VMEM_LIMIT
-
-
 def _check_window_vmem(plan: "LaunchPlan", spec: KernelSpec) -> None:
-    """Satellite guard: refuse to build a windowed launch whose VMEM
-    window exceeds the cap instead of letting Pallas lowering fail (or
-    silently thrash) deep inside the jitted launch."""
-    cap = _vmem_cap()
+    """Refuse to build a windowed launch whose VMEM window exceeds the
+    limit the compiler is given (:func:`costmodel.vmem_limit_bytes`)
+    instead of letting Mosaic fail deep inside the jitted launch."""
+    cap = vmem_limit_bytes(plan.interpret)
     total = plan.vmem_bytes_estimate()
     if total <= cap:
         return
     p = int(plan.target.tune("plane_block", 1))
-    worst_label, worst_bytes = "<output>", 0
-    for i, (fs, (c, s)) in enumerate(zip(spec.fields, plan._fields())):
-        if s is None:
-            b = c * p * _prod_shape(plan.shape[1:]) * 4
-        else:
-            ext = plan._ext_shape(s)
-            b = c * (p + 2 * s.radius_per_dim()[0]) * \
-                _prod_shape(ext[1:]) * 4
-        if b > worst_bytes:
-            worst_label, worst_bytes = fs.label(i), b
+    i, worst_bytes = max(plan.window_blocks(), key=lambda ib: ib[1])
+    worst_label = "<output>" if i < 0 else spec.fields[i].label(i)
     raise WindowVmemError(
         f"kernel {plan.name!r} under executor "
         f"{plan.target.executor!r}: the plane_block={p} window needs an "
-        f"estimated {total} bytes of VMEM (> cap {cap}); largest window "
-        f"is {worst_label} at {worst_bytes} bytes "
-        f"({p} + 2·radius x-planes of the extended grid) — shrink "
+        f"estimated {total} bytes of VMEM (> cap {cap}); largest block "
+        f"is {worst_label} at {worst_bytes} bytes per buffer — shrink "
         f"plane_block or the y/z extents")
+
+
+class WindowShapeError(ValueError):
+    """A compiled ``pallas_windowed`` launch whose block shapes Mosaic
+    cannot lower for the TPU.
+
+    The executor flattens each ``(ncomp, *rest)`` plane to ``(ncomp,
+    prod(rest))`` in-kernel; Mosaic lowers that shape cast only when the
+    minor lattice extent fills whole 128-lane vregs, and not at all for
+    the AoSoA unpack.  Raised at plan-build time for ``interpret=False``
+    targets, so Mosaic's ``unsupported shape cast`` never surfaces from
+    inside a jitted step; interpret mode runs every shape.
+    """
+
+
+def _validate_compiled_window(spec: KernelSpec, target: Target,
+                              lattice: Lattice | None) -> None:
+    if (target.executor != "pallas_windowed" or target.interpret
+            or lattice is None):
+        return
+    if target.layout == "aosoa":
+        raise WindowShapeError(
+            f"kernel {spec.name!r} under executor {target.executor!r}: "
+            f"layout='aosoa' does not compile for the TPU (Mosaic cannot "
+            f"lower the in-kernel AoSoA unpack); use layout='soa'")
+    shape = lattice.shape
+    if len(shape) >= 3 and shape[-1] % 128:
+        raise WindowShapeError(
+            f"kernel {spec.name!r} under executor {target.executor!r}: "
+            f"minor lattice extent {shape[-1]} (shape {shape}) is not a "
+            f"multiple of 128 lanes, so Mosaic cannot flatten the window "
+            f"planes; pad the minor dimension or use the 'xla' executor")
 
 
 def _validate_layout(spec: KernelSpec, target: Target,
@@ -643,6 +710,7 @@ def launch(spec: KernelSpec, target: Target | str | None = None, /,
     if entry.wants == "halo_extended":
         _validate_wrap_extents(spec, lattice, h)
     _validate_layout(spec, tgt, lattice, entry.wants)
+    _validate_compiled_window(spec, tgt, lattice)
     vvl = tgt.resolve_vvl()
     out_ncomp = spec.out if spec.out is not None else (int(arrays[0].shape[0]),)
     static_consts, dyn_consts = _split_consts(all_consts)
@@ -685,6 +753,7 @@ def launch_plan(spec: KernelSpec, target: Target | str | None = None, *,
     if entry.wants == "halo_extended":
         _validate_wrap_extents(spec, lattice, h)
     _validate_layout(spec, tgt, lattice, entry.wants)
+    _validate_compiled_window(spec, tgt, lattice)
     if spec.out is not None:
         out_ncomp = spec.out
     elif spec.fields[0].ncomp is not None:

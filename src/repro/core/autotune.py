@@ -78,7 +78,6 @@ import numpy as np
 from . import costmodel as _costmodel
 from .api import launch as _launch
 from .api import launch_plan as _launch_plan
-from .costmodel import DEFAULT_VMEM_LIMIT  # noqa: F401  (re-export)
 from .lattice import Lattice
 from .program import CompiledProgram, Program
 from .registry import (
@@ -263,20 +262,23 @@ def _vvl_values(n: int, *, lo: int = 8, hi: int = 8192,
 
 def plane_block_candidates(spec: KernelSpec, target: Target | str | None,
                            lattice: Lattice, *, halo=None, consts=None,
-                           vmem_limit: int = DEFAULT_VMEM_LIMIT):
+                           vmem_limit: int | None = None):
     """The ``plane_block`` axis for one ``wants="halo_extended"`` launch.
 
     Emits the divisors of the launch's x-plane count (``plan.shape[0]``
     — for a Program stage that is the *extended* plane count, interior +
     recompute ring) whose windowed-executor VMEM model fits
-    ``vmem_limit``.  Divisors, not every integer: the executor pads the
-    grid to a ``plane_block`` multiple, so non-divisors waste whole
-    padded planes per step.
+    ``vmem_limit`` (default: :func:`~repro.core.costmodel.vmem_limit_bytes`
+    for the target, the cap the compiler is given).  Divisors, not every
+    integer: the executor pads the grid to a ``plane_block`` multiple, so
+    non-divisors waste whole padded planes per step.
 
     Returns ``(feasible, pruned)`` — ``feasible`` the surviving
     ``plane_block`` values, ``pruned`` a list of ``(value, reason)``.
     """
     tgt = as_target(target)
+    if vmem_limit is None:
+        vmem_limit = _costmodel.vmem_limit_bytes(tgt.interpret)
     feasible: list[int] = []
     pruned: list[tuple[int, str]] = []
     base_plan = _launch_plan(spec, tgt, lattice=lattice, halo=halo,
@@ -305,7 +307,7 @@ def default_space(program_or_spec, target: Target | str | None = None, *,
                   grid_shape: Sequence[int] | None = None,
                   lattice: Lattice | None = None, halo=None, consts=None,
                   executors: Sequence[str] | None = None,
-                  vmem_limit: int = DEFAULT_VMEM_LIMIT,
+                  vmem_limit: int | None = None,
                   per_stage: bool = False,
                   site_count: int | None = None):
     """Derive the default candidate space for :func:`autotune`.
@@ -345,6 +347,8 @@ def default_space(program_or_spec, target: Target | str | None = None, *,
     ``(label, reason)`` for space points rejected before measurement.
     """
     base = as_target(target)
+    if vmem_limit is None:
+        vmem_limit = _costmodel.vmem_limit_bytes(base.interpret)
     is_program = isinstance(program_or_spec, Program)
     if is_program:
         has_stencil = any(st.spec.has_stencil
@@ -676,11 +680,6 @@ def _subject_digest(program_or_spec) -> tuple[str, str]:
     return program_or_spec.name, _spec_digest(program_or_spec)
 
 
-def _device_kind() -> str:
-    d = jax.devices()[0]
-    return f"{d.platform}:{getattr(d, 'device_kind', '?')}"
-
-
 def cache_key(program_or_spec, target: Target,
               grid: tuple[int, ...]) -> str:
     """The cache-key anatomy (docs/targetdp_api.md, "Autotuning"):
@@ -690,7 +689,7 @@ def cache_key(program_or_spec, target: Target,
     the answer."""
     name, digest = _subject_digest(program_or_spec)
     grid_s = "x".join(str(int(s)) for s in grid)
-    dev = _device_kind().replace(" ", "_").replace("/", "_")
+    dev = _costmodel._device_kind().replace(" ", "_").replace("/", "_")
     # Candidate.label spells interpret mode for every backend family
     # (Target.executor only does so for "pallas") — interpreter-measured
     # and compiled tuning runs must never share a cache entry.
@@ -820,7 +819,7 @@ def autotune(program_or_spec, target: Target | str | None = None,
              grid_shape: Sequence[int] | None = None,
              lattice: Lattice | None = None, halo=None, consts=None,
              executors: Sequence[str] | None = None,
-             vmem_limit: int = DEFAULT_VMEM_LIMIT,
+             vmem_limit: int | None = None,
              check_identical: bool = False,
              scorer: Callable[[Target], float | None] | None = None,
              top_k: int | None = None,
@@ -1036,7 +1035,7 @@ def autotune(program_or_spec, target: Target | str | None = None,
     best = min(results, key=lambda r: r.median_s).candidate
     report = TuneReport(
         name=_subject_digest(program_or_spec)[0], grid=grid,
-        device=_device_kind(), results=tuple(results),
+        device=_costmodel._device_kind(), results=tuple(results),
         pruned=tuple(pruned), best=best,
         default_median_s=float(default_median),
         cache_key=key, cache_hit=False, measure_steps=n_steps,

@@ -52,16 +52,17 @@ from collections import defaultdict
 from dataclasses import dataclass, field
 from typing import Mapping
 
-#: default per-launch VMEM feasibility budget — one TPU core's vector
-#: memory (the windowed executor's window must fit).  ``tdp.autotune``
-#: aliases this as its ``vmem_limit`` default.
-DEFAULT_VMEM_LIMIT = 16 * 2 ** 20
+#: VMEM budget of an interpret-mode (CPU) launch: the Pallas interpreter
+#: has no fast memory, so windowed plans are held to a fixed 16 MiB —
+#: the scoped-VMEM default Mosaic applies to a kernel that names no limit.
+INTERPRET_VMEM_LIMIT = 16 * 2 ** 20
 
 __all__ = [
     "MachineProfile", "CostEstimate", "predict", "roofline_seconds",
     "kernel_flops", "calibrate", "machine_profile", "load_profile",
     "store_profile", "profile_path", "analyze", "parse_module",
-    "collective_bytes", "dryrun_record_terms", "DEFAULT_VMEM_LIMIT",
+    "collective_bytes", "dryrun_record_terms", "DEVICE_PEAKS",
+    "INTERPRET_VMEM_LIMIT", "device_kind", "vmem_limit_bytes",
 ]
 
 
@@ -69,22 +70,65 @@ __all__ = [
 # machine profiles
 # ---------------------------------------------------------------------------
 
-#: default rates per platform family (the key is matched against the
-#: platform prefix of the device string).  The TPU row is the v5e
-#: roofline from ``benchmarks/roofline.py``'s original constants; the
-#: cpu row is a deliberately conservative laptop-class estimate; the
-#: interpret row derates everything to Pallas-interpreter throughput
-#: (the emulator runs the kernel body per site chunk in Python).
-_DEFAULT_RATES: dict[str, dict[str, float]] = {
-    "tpu": dict(peak_flops=197e12, hbm_bw=819e9, link_bw=50e9,
-                dcn_bw=25e9, hbm_bytes=16 * 2 ** 30),
-    "gpu": dict(peak_flops=60e12, hbm_bw=1500e9, link_bw=25e9,
-                dcn_bw=12.5e9, hbm_bytes=40 * 2 ** 30),
+#: Per-device rates, keyed by ``device_kind`` as JAX reports it
+#: (``jax.devices()[0].device_kind``).  A kind missing here is an error,
+#: never a default.
+#:
+#: * ``"TPU v5 lite"`` (TPU v5e): 197 TFLOP/s bf16, 16 GB of HBM at
+#:   819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect — Google Cloud
+#:   documentation, "TPU v5e".  128 MiB of VMEM per TensorCore — "How to
+#:   Think About TPUs" (JAX scaling book), TPU specs table.
+#:   ``vmem_limit`` is the scoped-VMEM limit a compiled Pallas kernel is
+#:   given (``pltpu.CompilerParams(vmem_limit_bytes=...)``): 100 MiB,
+#:   leaving 28 MiB to the compiler's internal scratch.  ``dcn_bw``
+#:   (cross-pod) has no published per-chip figure and is assumed; only
+#:   :func:`dryrun_record_terms` reads it.
+#: * ``"cpu"``: a deliberately conservative laptop-class estimate for
+#:   XLA's CPU backend; :func:`calibrate` replaces the two rates it can
+#:   measure, and ``results/tuning/machine-cpu:*.json`` caches them.
+DEVICE_PEAKS: dict[str, dict[str, float]] = {
+    "TPU v5 lite": dict(peak_flops=197e12, hbm_bw=819e9, link_bw=200e9,
+                        dcn_bw=25e9, hbm_bytes=16 * 10 ** 9, vmem_bytes=128 * 2 ** 20,
+                        vmem_limit=100 * 2 ** 20),
     "cpu": dict(peak_flops=1e11, hbm_bw=2e10, link_bw=1e10,
-                dcn_bw=1e10, hbm_bytes=8 * 2 ** 30),
-    "interpret": dict(peak_flops=5e7, hbm_bw=5e8, link_bw=5e8,
-                      dcn_bw=5e8, hbm_bytes=8 * 2 ** 30),
+                hbm_bytes=8 * 2 ** 30, vmem_bytes=INTERPRET_VMEM_LIMIT,
+                vmem_limit=INTERPRET_VMEM_LIMIT),
 }
+
+#: Interpret-mode rates: the Pallas interpreter runs the kernel body per
+#: site chunk in Python, so everything is derated to its throughput
+#: (placeholders until :func:`calibrate` ``(interpret=True)`` measures).
+_INTERPRET_RATES = dict(peak_flops=5e7, hbm_bw=5e8, link_bw=5e8,
+                        hbm_bytes=8 * 2 ** 30,
+                        vmem_bytes=INTERPRET_VMEM_LIMIT)
+
+
+def device_kind() -> str:
+    """``device_kind`` of the first device of the default backend."""
+    import jax
+    return jax.devices()[0].device_kind
+
+
+def device_peaks(kind: str) -> dict[str, float]:
+    """The :data:`DEVICE_PEAKS` row of ``kind``; unknown kinds raise."""
+    try:
+        return DEVICE_PEAKS[kind]
+    except KeyError:
+        raise KeyError(
+            f"device kind {kind!r} has no row in costmodel.DEVICE_PEAKS "
+            f"(known: {sorted(DEVICE_PEAKS)}); add its published peaks "
+            f"and VMEM size with their source") from None
+
+
+def vmem_limit_bytes(interpret: bool = False) -> int:
+    """The VMEM budget of one Pallas launch: the scoped-VMEM limit a
+    compiled kernel is given on this process's device (from
+    :data:`DEVICE_PEAKS`), or :data:`INTERPRET_VMEM_LIMIT` in interpret
+    mode.  The windowed executor passes it to Mosaic, and the plan-build
+    guard and ``tdp.autotune``'s pruning hold plans to it."""
+    if interpret:
+        return INTERPRET_VMEM_LIMIT
+    return int(device_peaks(device_kind())["vmem_limit"])
 
 
 @dataclass(frozen=True)
@@ -103,7 +147,7 @@ class MachineProfile:
     interpret: bool = False
     peak_flops: float = 1e11     # FLOP/s
     hbm_bw: float = 2e10         # bytes/s main-memory bandwidth
-    vmem_bytes: int = DEFAULT_VMEM_LIMIT   # fast-memory capacity
+    vmem_bytes: int = INTERPRET_VMEM_LIMIT  # fast-memory capacity
     hbm_bytes: int = 8 * 2 ** 30           # main-memory capacity
     link_bw: float = 1e10        # bytes/s inter-device (ICI) link
     dcn_bw: float = 1e10         # bytes/s cross-pod link
@@ -112,12 +156,19 @@ class MachineProfile:
     @classmethod
     def default(cls, device: str | None = None,
                 interpret: bool = False) -> "MachineProfile":
-        """The table profile for ``device`` (current device if None)."""
+        """The table profile for ``device`` (``"<platform>:<kind>"``;
+        the current device if None).  Interpret profiles take the
+        interpreter rates; compiled ones the :data:`DEVICE_PEAKS` row of
+        the kind, which must exist."""
         dev = device if device is not None else _device_kind()
-        key = "interpret" if interpret else dev.split(":", 1)[0]
-        rates = _DEFAULT_RATES.get(key, _DEFAULT_RATES["cpu"])
+        if interpret:
+            rates = _INTERPRET_RATES
+        else:
+            rates = {k: v for k, v in
+                     device_peaks(dev.split(":", 1)[-1]).items()
+                     if k != "vmem_limit"}
         return cls(device=dev, interpret=bool(interpret), source="default",
-                   vmem_bytes=DEFAULT_VMEM_LIMIT, **rates)
+                   **rates)
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -136,12 +187,9 @@ class MachineProfile:
 
 
 def _device_kind() -> str:
-    try:
-        import jax
-        d = jax.devices()[0]
-        return f"{d.platform}:{getattr(d, 'device_kind', '?')}"
-    except Exception:
-        return "unknown:?"
+    import jax
+    d = jax.devices()[0]
+    return f"{d.platform}:{d.device_kind}"
 
 
 def _best_seconds(fn, reps: int = 5) -> float:
@@ -223,14 +271,10 @@ def calibrate(device: str | None = None, interpret: bool = False, *,
     same shapes through Pallas interpret-mode launches instead, so the
     recorded rates are the interpreter's, never the hardware's.  VMEM
     and link numbers are not measurable from a single host and keep
-    their table defaults.  Falls back to :meth:`MachineProfile.default`
-    if the micro-benchmark cannot run (e.g. no Pallas)."""
+    their table defaults.  A micro-benchmark that cannot run raises."""
     base = MachineProfile.default(device, interpret)
-    try:
-        rates = (_calibrate_interpret(reps) if interpret
-                 else _calibrate_compiled(reps))
-    except Exception:
-        return base
+    rates = (_calibrate_interpret(reps) if interpret
+             else _calibrate_compiled(reps))
     return dataclasses.replace(base, source="calibrated", **rates)
 
 
@@ -1104,7 +1148,8 @@ def dryrun_record_terms(rec: Mapping, profile: MachineProfile | None = None
     ``benchmarks/roofline.py`` table row, computed here so the CLI is a
     thin view over the cost model).  ``profile`` defaults to the TPU
     table profile the dry-run targets."""
-    p = profile if profile is not None else MachineProfile.default("tpu:v5e")
+    p = (profile if profile is not None
+         else MachineProfile.default("tpu:TPU v5 lite"))
     ha = rec["hlo_analysis"]
     t_c = ha["flops"] / p.peak_flops
     t_m = ha["traffic_bytes"] / p.hbm_bw

@@ -74,7 +74,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
 
-from . import compat
 from .api import launch as _launch
 from .api import launch_plan as _launch_plan
 from .api import _normalize_halo
@@ -569,7 +568,7 @@ def _exchange_dim(arr: jax.Array, axis_name: str, width: int,
                   dim: int) -> jax.Array:
     """:func:`exchange_ghosts` under ``shard_map``: mesh axis
     ``axis_name`` shards grid dim ``dim``."""
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     return exchange_ghosts(
         arr, dim, width, n,
         lambda x, pairs: jax.lax.ppermute(x, axis_name, pairs))
@@ -888,10 +887,12 @@ class CompiledProgram:
 
             pspec = PartitionSpec(*((None,) + axes
                                     + (None,) * (ndim - len(axes))))
-            # pallas_call has no shard_map replication rule on jax 0.4.x:
-            # drop the check whenever any stage dispatches off-xla.
+            # The Pallas executors declare out_shape without a ``vma``
+            # (how each output varies over the mesh axes), which
+            # check_vma=True refuses: drop the check whenever any stage
+            # dispatches off-xla.
             check = all(t.executor == "xla" for t in self.stage_targets)
-            core = compat.shard_map(
+            core = jax.shard_map(
                 core_local, mesh=self.mesh,
                 in_specs=(pspec,) * len(fields)
                 + (PartitionSpec(),) * len(dyn_names),

@@ -95,15 +95,19 @@ def collision_site_kernel(f, g, phi, gradphi, del2phi, *,
     mu = -A * phi_ + B * phi_ * phi_ * phi_ - kappa * d2      # (V,)
     force = mu[None, :] * gradphi                              # (3, V)
 
+    # The velocity-set contractions run in full float32: on a TPU a
+    # float32 dot at default precision is one bfloat16 pass (~3 digits),
+    # which moves f by ~1e-4 in 20 steps.  On the CPU this changes nothing.
+    hi = jax.lax.Precision.HIGHEST
     rho = jnp.sum(f, axis=0)                                   # (V,)
-    mom = jnp.einsum("qd,qv->dv", c, f)                        # (3, V)
+    mom = jnp.einsum("qd,qv->dv", c, f, precision=hi)          # (3, V)
     u = (mom + 0.5 * force) / rho[None, :]                     # (3, V)
 
-    cu = jnp.einsum("qd,dv->qv", c, u)                         # (19, V)
+    cu = jnp.einsum("qd,dv->qv", c, u, precision=hi)           # (19, V)
     usq = jnp.sum(u * u, axis=0)                               # (V,)
     feq = w * rho[None, :] * (1.0 + 3.0 * cu + 4.5 * cu * cu - 1.5 * usq[None, :])
 
-    cf = jnp.einsum("qd,dv->qv", c, force)                     # (19, V)
+    cf = jnp.einsum("qd,dv->qv", c, force, precision=hi)       # (19, V)
     uf = jnp.sum(u * force, axis=0)                            # (V,)
     fterm = (1.0 - 0.5 / tau) * w * (3.0 * (cf - uf[None, :]) + 9.0 * cu * cf)
     f_out = f - (f - feq) / tau + fterm

@@ -39,13 +39,15 @@ def lb_collision_ref(f, g, phi, gradphi, del2phi, *,
     mu = -A * phi_ + B * phi_ * phi_ * phi_ - kappa * del2phi[0]
     force = mu[None, :] * gradphi
 
+    hi = jax.lax.Precision.HIGHEST         # float32 contractions on a TPU
     rho = f.sum(0)
-    u = (jnp.einsum("qd,qv->dv", c, f) + 0.5 * force) / rho[None, :]
-    cu = jnp.einsum("qd,dv->qv", c, u)
+    u = (jnp.einsum("qd,qv->dv", c, f, precision=hi)
+         + 0.5 * force) / rho[None, :]
+    cu = jnp.einsum("qd,dv->qv", c, u, precision=hi)
     usq = (u * u).sum(0)
     feq = w * rho[None, :] * (1.0 + 3.0 * cu + 4.5 * cu * cu
                               - 1.5 * usq[None, :])
-    cf = jnp.einsum("qd,dv->qv", c, force)
+    cf = jnp.einsum("qd,dv->qv", c, force, precision=hi)
     uf = (u * force).sum(0)
     fterm = (1.0 - 0.5 / tau) * w * (3.0 * (cf - uf[None, :])
                                      + 9.0 * cu * cf)

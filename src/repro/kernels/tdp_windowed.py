@@ -30,10 +30,17 @@ every grid step DMAs exactly its window into VMEM.
 Memory model (vs the gathered path, per ``LaunchPlan`` estimates):
 
   HBM   Σ_i ncomp_i · prod(shape_d + 2r_d)      [was noffsets_i × interior]
-  VMEM  Σ_i ncomp_i · (plane_block + 2r₀) · prod(ext_rest)   per grid step
+  VMEM  3 × Σ_i ncomp_i · (plane_block + 2r₀) · prod(ext_rest)   per grid
+        step, each plane padded to the (8, 128) tile
+        (:meth:`~repro.core.api.LaunchPlan.window_blocks`)
 
 — the ``noffsets×`` term is gone from both; large grids (≥64³) that OOM
-under the 57× fused gather fit comfortably.
+under the 57× fused gather fit comfortably.  Compiled (``interpret=False``)
+the kernel is given the device kind's VMEM limit
+(:func:`repro.core.costmodel.vmem_limit_bytes`), and plans Mosaic cannot
+lower — ``layout="aosoa"``, or a minor lattice extent that is not a
+multiple of 128 lanes — are refused at plan build
+(:class:`~repro.core.api.WindowShapeError`).
 
 Tuning (``Target.tuning``): ``plane_block`` — output x-planes per grid
 step (TLP chunk; window depth is ``plane_block + 2r₀``).  Default 1.
@@ -61,7 +68,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.costmodel import vmem_limit_bytes
 from repro.core.layout import plane_to_aosoa
 
 from .tdp_pointwise import _canonicalize_consts
@@ -168,10 +177,12 @@ def windowed_execute(plan, extended):
     in_specs += [pl.BlockSpec(c.shape, lambda i: (0, 0)) for c in const_vals]
 
     out_ncomp = tuple(plan.out_ncomp)
-    out_specs = [pl.BlockSpec((c, p, *rest),
-                              lambda i: (0, i, *([0] * (ndim - 1))))
+    # Outputs are written flat, (ncomp, p·V) lane-dense blocks: Mosaic
+    # cannot reshape a one-component (1, V) value back into (1, p, Y, Z)
+    # planes when Y is not a whole number of sublane tiles.
+    out_specs = [pl.BlockSpec((c, chunk), lambda i: (0, i))
                  for c in out_ncomp]
-    out_shape = [jax.ShapeDtypeStruct((c, X + x_pad, *rest), dtype)
+    out_shape = [jax.ShapeDtypeStruct((c, (X + x_pad) * rest_n), dtype)
                  for c in out_ncomp]
 
     def body(*refs):
@@ -231,8 +242,15 @@ def windowed_execute(plan, extended):
         vals = plan.kernel(*chunks, **kw)
         vals = (vals,) if not isinstance(vals, tuple) else vals
         for ref, v in zip(out_refs, vals):
-            ref[...] = v.reshape(ref.shape).astype(ref.dtype)
+            ref[...] = v.astype(ref.dtype)
 
+    # Mosaic's default scoped-VMEM limit (16 MiB) is far below what a
+    # 128² window needs; give the kernel the device's limit, the same
+    # cap the plan-build guard held the window estimate to.
+    compiler_params = None
+    if not plan.interpret:
+        compiler_params = pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes(interpret=False))
     outs = pl.pallas_call(
         body,
         grid=(nwin,),
@@ -240,8 +258,9 @@ def windowed_execute(plan, extended):
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=plan.interpret,
+        compiler_params=compiler_params,
         name=f"tdp_windowed_{plan.name}_p{p}_{plan.layout}",
     )(*operands, *const_vals)
 
     n = X * rest_n
-    return tuple(o.reshape(o.shape[0], -1)[:, :n] for o in outs)
+    return tuple(o[:, :n] for o in outs)

@@ -85,8 +85,6 @@ def run_cell(arch: str, shape: str, mesh_name: str, variant, out_dir: str,
         compiled = lowered.compile()
         rec["compile_s"] = time.monotonic() - t1
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):   # older jax: one dict per program
-            ca = ca[0] if ca else {}
         rec["cost_analysis"] = {k: float(v) for k, v in ca.items()
                                 if isinstance(v, (int, float)) and
                                 not k.startswith(("utilization",

@@ -12,17 +12,20 @@ Topology (TPU v5e pods):
 """
 from __future__ import annotations
 
-from jax.sharding import Mesh
+import jax
+from jax.sharding import AxisType, Mesh
 
-from repro.core import compat
+
+def _auto_mesh(shape, axes) -> Mesh:
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")) -> Mesh:
     """Small mesh over however many (fake) devices a test process has."""
-    return compat.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)

@@ -37,15 +37,16 @@ def collide_aos(f, g, phi, gradphi, del2phi, params: LBParams):
     mu = -A * phi + B * phi ** 3 - kappa * del2phi       # (...)
     force = mu[..., None] * gradphi                      # (..., 3)
 
+    hi = jax.lax.Precision.HIGHEST         # float32 contractions on a TPU
     rho = f.sum(-1)                                      # (...)
-    mom = jnp.einsum("...q,qd->...d", f, c)              # (..., 3)
+    mom = jnp.einsum("...q,qd->...d", f, c, precision=hi)  # (..., 3)
     u = (mom + 0.5 * force) / rho[..., None]             # (..., 3)
 
-    cu = jnp.einsum("...d,qd->...q", u, c)               # (..., 19)
+    cu = jnp.einsum("...d,qd->...q", u, c, precision=hi)  # (..., 19)
     usq = (u * u).sum(-1)                                # (...)
     feq = w * rho[..., None] * (1 + 3 * cu + 4.5 * cu ** 2
                                 - 1.5 * usq[..., None])
-    cf = jnp.einsum("...d,qd->...q", force, c)           # (..., 19)
+    cf = jnp.einsum("...d,qd->...q", force, c, precision=hi)  # (..., 19)
     uf = (u * force).sum(-1)                             # (...)
     fterm = (1 - 0.5 / tau) * w * (3 * (cf - uf[..., None]) + 9 * cu * cf)
     f_out = f - (f - feq) / tau + fterm

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compat
 from repro.kernels import ops
 from .config import AttnConfig, ModelConfig
 from .context import ExecContext
@@ -88,7 +87,7 @@ def _seq_parallel_attention(qT, kT, vT, a: AttnConfig, ctx: ExecContext, *,
             block_q=min(ctx.attn_block_q, s_local),
             impl="chunked", q_offset=(axis, s_local))
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=ctx.shard_map_mesh,
         in_specs=(P(bspec, None, axis, None),
                   P(bspec, None, None, None),
@@ -283,6 +282,6 @@ def _seq_sharded_decode(q, k, v, a: AttnConfig, ctx: ExecContext, length,
                 P(bspec, None, axis, None),
                 P())
     out_spec = P(bspec, None, None, None)
-    fn = compat.shard_map(body, mesh=ctx.shard_map_mesh, in_specs=in_specs,
+    fn = jax.shard_map(body, mesh=ctx.shard_map_mesh, in_specs=in_specs,
                        out_specs=out_spec, check_vma=False)
     return fn(q, k, v, jnp.asarray(length))

@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compat
 from repro.kernels import ops
 from .config import MLAConfig, ModelConfig
 from .context import ExecContext
@@ -154,7 +153,7 @@ def _mla_seq_sharded(q_abs, q_rope, c_kv, k_rope, ctx: ExecContext, length,
         den = pt.sum(-1)[..., None]
         return jax.lax.psum(num, axis) / jnp.maximum(jax.lax.psum(den, axis), 1e-30)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=ctx.shard_map_mesh,
         in_specs=(P(bspec, None, None), P(bspec, None, None),
                   P(bspec, axis, None), P(bspec, axis, None), P()),

@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compat
 from repro.kernels import ops
 from .config import ModelConfig
 from .context import ExecContext
@@ -52,22 +51,10 @@ def _gm_fwd(xs, w, gs):
 def _gm_bwd(res, dy):
     xs, w, gs = res
     dxs = jax.lax.ragged_dot(dy, w.transpose(0, 2, 1), gs)
-    if hasattr(jax.lax, "RaggedDotDimensionNumbers"):
-        dn = jax.lax.RaggedDotDimensionNumbers(
-            dot_dimension_numbers=(((0,), (0,)), ((), ())),
-            lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
-        dw = jax.lax.ragged_dot_general(xs, dy, gs, dn)
-    else:
-        # 0.4.x fallback: dw[e] = xs_e^T @ dy_e as a one-hot contraction
-        # (rows past sum(gs) get group id E → zero one-hot → no
-        # contribution, matching ragged_dot's out-of-group treatment).
-        # ~E× the ragged dot's dw FLOPs — acceptable only as compat.
-        n_exp = w.shape[0]
-        starts = jnp.cumsum(gs)
-        seg = jnp.searchsorted(starts, jnp.arange(xs.shape[0]), side="right")
-        onehot = jax.nn.one_hot(seg, n_exp, dtype=jnp.float32)
-        dw = jnp.einsum("te,td,tf->edf", onehot,
-                        xs.astype(jnp.float32), dy.astype(jnp.float32))
+    dn = jax.lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(((0,), (0,)), ((), ())),
+        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+    dw = jax.lax.ragged_dot_general(xs, dy, gs, dn)
     return dxs.astype(xs.dtype), dw.astype(w.dtype), None
 
 
@@ -222,7 +209,7 @@ def moe_mlp(p, x, cfg: ModelConfig, ctx: ExecContext):
     if shared_p is not None and "w_gate" not in shared_p:
         shared_spec = {"w_up": P(None, axis), "w_down": P(axis, None)}
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=ctx.shard_map_mesh,
         in_specs=(P(bspec, None), P(None, None),
                   P(None, None, axis),
@@ -320,7 +307,7 @@ def moe_a2a(p, x, cfg: ModelConfig, ctx: ExecContext, *, capacity_factor=1.25):
     if shared_p is not None:
         shared_spec = {k2: P(None, axis) if k2 != "w_down" else P(axis, None)
                        for k2 in shared_p}
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=ctx.shard_map_mesh,
         in_specs=(P(bspec, None), P(None, None),
                   P(axis, None, None),

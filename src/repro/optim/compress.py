@@ -26,8 +26,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core import compat
-
 
 def compress_init(grads):
     """Zero error-feedback buffers, twin to the grad tree (fp32)."""
@@ -50,7 +48,7 @@ def compressed_psum_mean(grads, error, axis: str, *, block: int = 1024):
 
     Returns ``(mean fp32 grads, new error buffers)``.
     """
-    npods = compat.axis_size(axis)
+    npods = jax.lax.axis_size(axis)
 
     def one(g, e):
         x = g.astype(jnp.float32) + e

@@ -10,9 +10,8 @@
   with the following iteration — the standard accumulate-overlap trick).
 * **remat** — ``ctx.remat="block"`` checkpoints each layer-program unit.
 * **cross-pod gradient compression** — optional: gradients are computed
-  *pod-locally* under a partial-manual ``shard_map`` (manual over ``pod``,
-  auto over ``data``/``model``), then EF-int8 reduced over the pod (DCN)
-  axis (:mod:`repro.optim.compress`).
+  *pod-locally* under a ``shard_map`` manual over every mesh axis, then
+  EF-int8 reduced over the pod (DCN) axis (:mod:`repro.optim.compress`).
 
 ``build_serve_steps`` returns (prefill_fn, decode_fn) with KV-cache
 handling, greedy/temperature sampling, and flash-decoding sequence-sharded
@@ -28,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compat
 from repro.models import lm
 from repro.models.config import ModelConfig
 from repro.models.context import ExecContext
@@ -120,23 +118,15 @@ def build_train_step(cfg: ModelConfig, ctx: ExecContext,
         return train_step
 
     # --- compressed cross-pod variant -----------------------------------
+    # The body is manual over *every* mesh axis: the batch is sharded over
+    # pod+data explicitly, model compute is replicated shard-locally
+    # (mesh=None strips nested constraints/shard_maps) and the in-pod data
+    # reduction is an explicit pmean.  (A body manual over ``pod`` alone,
+    # auto over data/model, trips an XLA SPMD-partitioner CHECK.)
     axis = hp.pod_axis
     ef_dtype = jnp.dtype(hp.ef_dtype)
-    # inside the pod-manual shard_map, 'pod' is a manual axis: the inner
-    # model code (sharding constraints, nested shard_maps) must not name
-    # it — rebuild the grad closure with it stripped from batch_axes
     data_axes = tuple(a for a in ctx.batch_axes if a != axis)
-    partial_manual = compat.supports_partial_manual()
-    if partial_manual:
-        inner_ctx = ctx.with_(batch_axes=data_axes)
-    else:
-        # Fully-manual fallback: old XLA CHECK-crashes on partial-manual
-        # regions, so the body goes manual over *every* mesh axis — batch
-        # sharded over pod+data explicitly, model compute replicated
-        # shard-locally (mesh=None strips nested constraints/shard_maps) and
-        # the in-pod data reduction done with explicit pmeans.
-        inner_ctx = ctx.with_(mesh=None, batch_axes=())
-    grads_of_inner = _grads_of(cfg, inner_ctx, hp)
+    grads_of_inner = _grads_of(cfg, ctx.with_(mesh=None, batch_axes=()), hp)
 
     def train_step(params, opt_state, batch, ef):
         if ctx.mesh is None or axis not in ctx.mesh.axis_names:
@@ -144,26 +134,22 @@ def build_train_step(cfg: ModelConfig, ctx: ExecContext,
 
         def pod_body(p, b, e):
             loss, grads = grads_of_inner(p, b)
-            if not partial_manual:
-                for a in data_axes:        # exact in-pod (ICI) mean
-                    loss = jax.lax.pmean(loss, a)
-                    grads = jax.tree.map(
-                        lambda g, a=a: jax.lax.pmean(g, a), grads)
+            for a in data_axes:        # exact in-pod (ICI) mean
+                loss = jax.lax.pmean(loss, a)
+                grads = jax.tree.map(
+                    lambda g, a=a: jax.lax.pmean(g, a), grads)
             e32 = jax.tree.map(lambda x: x.astype(jnp.float32), e)
             grads, e32 = compressed_psum_mean(grads, e32, axis)
             new_e = jax.tree.map(lambda x: x.astype(ef_dtype), e32)
             return jax.lax.pmean(loss, axis), grads, new_e
 
         pspec = jax.tree.map(lambda _: P(), params)
-        batch_spec = P(axis) if partial_manual else P((axis,) + data_axes)
-        bspec = {k: batch_spec for k in batch}
+        bspec = {k: P((axis,) + data_axes) for k in batch}
         espec = jax.tree.map(lambda _: P(), ef)
         gspec = jax.tree.map(lambda _: P(), params)
-        fn = compat.shard_map(pod_body, mesh=ctx.mesh,
+        fn = jax.shard_map(pod_body, mesh=ctx.mesh,
                            in_specs=(pspec, bspec, espec),
-                           out_specs=(P(), gspec, espec),
-                           axis_names={axis} if partial_manual else None,
-                           check_vma=False)
+                           out_specs=(P(), gspec, espec), check_vma=False)
         loss, grads, ef = fn(params, batch, ef)
         lr = schedule(opt_state["step"])
         params, opt_state, om = adamw_update(params, grads, opt_state,

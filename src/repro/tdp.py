@@ -68,6 +68,7 @@ from repro.core.registry import (  # noqa: F401
 )
 from repro.core.api import (  # noqa: F401
     LaunchPlan,
+    WindowShapeError,
     WindowVmemError,
     gather_neighbors,
     halo_extend,
@@ -151,7 +152,8 @@ __all__ = [
     "get_executor_entry", "executor_wants", "list_executors",
     "registry_version",
     "launch", "launch_plan", "LaunchPlan", "xla_executor",
-    "gather_neighbors", "halo_extend", "pad_sites", "WindowVmemError",
+    "gather_neighbors", "halo_extend", "pad_sites", "WindowShapeError",
+    "WindowVmemError",
     "LAYOUTS", "aosoa_nblocks", "aosoa_to_soa", "soa_to_aosoa",
     "Program", "CompiledProgram", "ProgramPlan", "Stage", "program",
     "exchange_ghosts", "exchange_stats",

@@ -163,10 +163,28 @@ class TestMachineProfile:
         assert machine_profile(dev, cache_dir=str(tmp_path)) is got
 
     def test_default_table_without_calibration(self, tmp_path):
-        got = machine_profile("nosuch-dev", cache_dir=str(tmp_path),
+        got = machine_profile("cpu:cpu", cache_dir=str(tmp_path),
                               calibrate_if_missing=False)
         assert got.source == "default"
+        assert got.peak_flops == cm.DEVICE_PEAKS["cpu"]["peak_flops"]
         assert not os.listdir(tmp_path)    # store=False never writes
+        # a device kind with no table row is an error, never a default
+        with pytest.raises(KeyError, match="nosuch-dev"):
+            machine_profile("tpu:nosuch-dev", cache_dir=str(tmp_path),
+                            calibrate_if_missing=False)
+
+    def test_vmem_limit_follows_device_kind(self, monkeypatch):
+        """The VMEM budget a compiled launch is given comes from its
+        device kind's table row; interpret mode keeps a fixed cap, and a
+        kind with no row raises."""
+        assert cm.vmem_limit_bytes(interpret=True) == cm.INTERPRET_VMEM_LIMIT
+        assert cm.vmem_limit_bytes() == cm.DEVICE_PEAKS["cpu"]["vmem_limit"]
+        monkeypatch.setattr(cm, "device_kind", lambda: "TPU v5 lite")
+        assert cm.vmem_limit_bytes() == 100 * 2 ** 20
+        monkeypatch.setattr(cm, "device_kind", lambda: "TPU v99")
+        with pytest.raises(KeyError, match="TPU v99"):
+            cm.vmem_limit_bytes()
+        assert cm.vmem_limit_bytes(interpret=True) == cm.INTERPRET_VMEM_LIMIT
 
     def test_honest_profile_rule(self):
         prog = fused_prog("one_launch")
@@ -287,7 +305,7 @@ class TestAbsorbedAnalysis:
                "memory_analysis": {"argument_size_in_bytes": 2 ** 30,
                                    "temp_size_in_bytes": 2 ** 30}}
         t = cm.dryrun_record_terms(rec)
-        tpu = MachineProfile.default("tpu:v5e")
+        tpu = MachineProfile.default("tpu:TPU v5 lite")
         assert t["t_compute"] == pytest.approx(1e15 / tpu.peak_flops)
         assert t["t_memory"] == pytest.approx(1e12 / tpu.hbm_bw)
         assert t["dominant"] == "compute"

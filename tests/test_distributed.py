@@ -136,10 +136,9 @@ def body(g_l, e_l):
     red, new_e = compressed_psum_mean({"w": g_l["w"]}, {"w": e_l["w"]}, "pod")
     return red["w"], new_e["w"]
 
-from repro.core import compat
-fn = compat.shard_map(body, mesh=mesh,
-                      in_specs=({"w": P("pod")}, {"w": P()}),
-                      out_specs=(P(), P()), check_vma=False)
+fn = jax.shard_map(body, mesh=mesh,
+                   in_specs=({"w": P("pod")}, {"w": P()}),
+                   out_specs=(P(), P()), check_vma=False)
 red, err = jax.jit(fn)(g, e)
 exact = g["w"].mean(0)
 rel = float(jnp.abs(red - exact).max() / jnp.abs(exact).max())

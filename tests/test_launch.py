@@ -94,3 +94,23 @@ for shape in ((8, 1), (1, 1)):
     assert tr2.step == 7
 print("ELASTIC_OK")
 """)
+
+
+def test_compile_cache_dir_follows_env_or_checkout(monkeypatch):
+    """``enable_compile_cache`` leaves JAX's own ``JAX_COMPILATION_CACHE_DIR``
+    alone when it is set, and otherwise points the cache at one fixed
+    directory inside the checkout."""
+    from pathlib import Path
+
+    from repro.launch import cache
+
+    calls = []
+    monkeypatch.setattr(cache.jax.config, "update",
+                        lambda name, value: calls.append((name, value)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert cache.enable_compile_cache() == "/elsewhere"
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    want = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+    assert cache.enable_compile_cache() == want
+    assert calls == [("jax_compilation_cache_dir", want)]

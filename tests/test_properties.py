@@ -1,4 +1,6 @@
 """Hypothesis property tests on the system's invariants."""
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -242,6 +244,15 @@ class TestAutotuneProperties:
                            if nplanes % d == 0}
         for v, why in pruned:
             assert "vmem estimate" in why
+        # the estimate never undercounts: at least two pipeline buffers
+        # plus the loaded value of the raw window and output bytes
+        ext_rest = math.prod(d + 2 * radius for d in shape[1:])
+        for p in emitted:
+            plan = tdp.launch_plan(spec, tgt.with_tuning(plane_block=p),
+                                   lattice=lat)
+            raw = ((p + 2 * radius) * ext_rest
+                   + p * math.prod(shape[1:])) * 4
+            assert plan.vmem_bytes_estimate() >= 3 * raw
 
     @SET
     @given(st.dictionaries(

@@ -295,6 +295,18 @@ class TestMemoryEstimates:
             WINDOWED.with_(tuning={"plane_block": 4}), lattice=lat)
         # window depth grows p + 2r: 3 planes → 6 planes of input
         assert w4.vmem_bytes_estimate() > w1.vmem_bytes_estimate()
+        # Each block is padded to Mosaic's (8, 128) f32 tile and counted
+        # three times (two pipeline buffers + the loaded value): an
+        # 18×18 extended plane of 19 components is 19·24·128 words, the
+        # p·256-site output block 24·(p·256) words.
+        plane, out1 = 19 * 24 * 128 * 4, 24 * 256 * 4
+        assert w1.window_blocks() == [(0, 3 * plane), (-1, out1)]
+        assert w1.vmem_bytes_estimate() == 3 * (3 * plane + out1)
+        # above plane_block=1 the p rows of all 19 offsets are also
+        # concatenated into one (19, 19, p·256) chunk
+        chunk4 = 19 * 24 * (4 * 256) * 4
+        assert w4.vmem_bytes_estimate() == \
+            3 * (6 * plane + 4 * out1) + chunk4
 
     def test_estimates_need_geometry(self):
         plan = launch_plan(tdp.KernelSpec(lambda x: x,
