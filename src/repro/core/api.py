@@ -87,6 +87,7 @@ def _tiled_bytes(shape, itemsize: int) -> int:
     return _prod_shape(dims) * itemsize
 
 
+@jax.named_scope("tdp.gather")
 def gather_neighbors(x: jax.Array, shape: tuple[int, ...],
                      halo: tuple[int, ...], stencil: Stencil) -> jax.Array:
     """``(ncomp, nsites_ext)`` → ``(noffsets, ncomp, nsites)`` neighbour
@@ -113,6 +114,7 @@ def gather_neighbors(x: jax.Array, shape: tuple[int, ...],
     return jnp.stack(planes)
 
 
+@jax.named_scope("tdp.halo_extend")
 def halo_extend(x: jax.Array, shape: tuple[int, ...],
                 halo: tuple[int, ...], stencil: Stencil) -> jax.Array:
     """``(ncomp, nsites_ext)`` → halo-extended grid ``(ncomp, *ext)`` with

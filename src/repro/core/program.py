@@ -67,6 +67,7 @@ plan cache.
 """
 from __future__ import annotations
 
+import re
 from dataclasses import dataclass, field as dc_field
 from typing import Any, Mapping, Sequence
 
@@ -190,6 +191,15 @@ def _grid_trim(arr: jax.Array, shape: tuple[int, ...],
             arr = jax.lax.slice_in_dim(arr, e - w, e + w + shape[d],
                                        axis=d + 1)
     return arr
+
+
+def _trim_fields(env: dict, fields: Sequence[str],
+                 shape: tuple[int, ...]) -> dict:
+    """Each field's grid in ``env`` trimmed to the interior ``shape``."""
+    zeros = (0,) * len(shape)
+    with jax.named_scope("tdp.trim"):
+        return {f: _grid_trim(env[f][0], shape, env[f][1], zeros)
+                for f in fields}
 
 
 def resolve_stage_target(target: Target | str | None,
@@ -406,27 +416,33 @@ class Program:
         stages binding a :class:`BatchedConst` launch with the dynamic
         value instead of the baked sweep."""
         for st, tgt, (e_out, h) in zip(self.stages, stage_targets, geo):
-            lat_shape = tuple(s + 2 * e for s, e in zip(shape, e_out))
-            lat = Lattice(lat_shape)
-            arrays = []
+            with jax.named_scope(f"tdp.stage.{st.name}"):
+                self._run_stage(st, tgt, shape, e_out, h, env, dyn)
+        return env
+
+    @staticmethod
+    def _run_stage(st: Stage, tgt: Target, shape, e_out, h, env: dict,
+                   dyn) -> None:
+        lat_shape = tuple(s + 2 * e for s, e in zip(shape, e_out))
+        arrays = []
+        with jax.named_scope("tdp.operands"):
             for rname, s in zip(st.reads, st.spec.stencils):
                 arr, ext = env[rname]
                 want = (e_out if s is None
                         else tuple(e + hh for e, hh in zip(e_out, h)))
                 arr = _grid_trim(arr, shape, ext, want)
                 arrays.append(arr.reshape(arr.shape[0], -1))
-            consts = st.consts_dict()
-            if dyn:
-                for k, v in consts.items():
-                    if isinstance(v, BatchedConst) and k in dyn:
-                        consts[k] = dyn[k]
-            outs = _launch(st.spec, tgt, *arrays, lattice=lat,
-                           halo=h if any(h) else None,
-                           consts=consts)
-            outs = (outs,) if not isinstance(outs, tuple) else outs
+        consts = st.consts_dict()
+        if dyn:
+            for k, v in consts.items():
+                if isinstance(v, BatchedConst) and k in dyn:
+                    consts[k] = dyn[k]
+        outs = _launch(st.spec, tgt, *arrays, lattice=Lattice(lat_shape),
+                       halo=h if any(h) else None, consts=consts)
+        outs = (outs,) if not isinstance(outs, tuple) else outs
+        with jax.named_scope("tdp.outputs"):
             for w, o in zip(st.writes, outs):
                 env[w] = (o.reshape(o.shape[0], *lat_shape), e_out)
-        return env
 
     # -- eager execution with caller-managed ghosts ------------------------
 
@@ -464,9 +480,7 @@ class Program:
                     f"halo={h0} (short in dim(s) {short})")
             env[f] = (state[f], h0)
         env = self._run_stages(stage_targets, shape, geo, env)
-        zeros = (0,) * ndim
-        return {f: _grid_trim(env[f][0], shape, env[f][1], zeros)
-                for f in self.fields}
+        return _trim_fields(env, self.fields, shape)
 
     # -- lowering ----------------------------------------------------------
 
@@ -656,7 +670,7 @@ def _overlap_regions(local: Sequence[int], W: Sequence[int],
 def _run_region(program: Program, stage_targets, geo, widths, fields,
                 sources: Mapping[str, tuple[jax.Array, tuple[int, ...]]],
                 start: tuple[int, ...], shape: tuple[int, ...],
-                zeros: tuple[int, ...], dyn=None) -> dict:
+                dyn=None) -> dict:
     """Run the whole stage pipeline over one region of the local domain.
 
     ``sources[f] = (array, src_ext)`` covers interior coordinates
@@ -678,8 +692,7 @@ def _run_region(program: Program, stage_targets, geo, widths, fields,
             a = jax.lax.slice_in_dim(a, lo, lo + ln, axis=d + 1)
         env[f] = (a, w)
     env = program._run_stages(stage_targets, shape, geo, env, dyn=dyn)
-    return {f: _grid_trim(env[f][0], shape, env[f][1], zeros)
-            for f in fields}
+    return _trim_fields(env, fields, shape)
 
 
 def _validate_decomposition(program: Program, grid_shape, open_mask):
@@ -839,6 +852,7 @@ class CompiledProgram:
             self.overlap = bool(overlap) and can_overlap
             self._interior_shape = i_shape if self.overlap else local
 
+            @jax.named_scope("tdp.exchange")
             def _exchange_all(arrays):
                 """Ordered per-dim sweep: dim 1 transfers the already-
                 dim-0-extended planes, so corner ghosts arrive via the
@@ -859,8 +873,8 @@ class CompiledProgram:
                     env = {f: (ex[f], widths[f]) for f in fields}
                     env = program._run_stages(self.stage_targets, local,
                                               geo, env, dyn=dyn)
-                    return tuple(_grid_trim(env[f][0], local, env[f][1],
-                                            zeros) for f in fields)
+                    out = _trim_fields(env, fields, local)
+                    return tuple(out[f] for f in fields)
             else:
                 def core_local(*args):
                     arrays, dyn = _split(args)
@@ -870,16 +884,16 @@ class CompiledProgram:
                     raw = {f: (a, zeros) for f, a in zip(fields, arrays)}
                     out = _run_region(program, self.stage_targets, geo,
                                       widths, fields, raw, i_start,
-                                      i_shape, zeros, dyn=dyn)
+                                      i_shape, dyn=dyn)
                     ex = _exchange_all(arrays)
                     exd = {f: (ex[f], widths[f]) for f in fields}
                     for d, lo, hi in reversed(bounds):
                         o_lo = _run_region(program, self.stage_targets,
                                            geo, widths, fields, exd,
-                                           *lo, zeros, dyn=dyn)
+                                           *lo, dyn=dyn)
                         o_hi = _run_region(program, self.stage_targets,
                                            geo, widths, fields, exd,
-                                           *hi, zeros, dyn=dyn)
+                                           *hi, dyn=dyn)
                         out = {f: jnp.concatenate(
                                    [o_lo[f], out[f], o_hi[f]], axis=d + 1)
                                for f in fields}
@@ -898,9 +912,15 @@ class CompiledProgram:
                 + (PartitionSpec(),) * len(dyn_names),
                 out_specs=(pspec,) * len(fields), check_vma=check)
 
+        # Stable names for the trace: the jitted modules are
+        # ``jit_tdp_<program>_step`` / ``_run``, the host spans
+        # ``tdp.<program>.step`` / ``.run`` / ``.compile``.
+        self._tag = re.sub(r"\W", "_", program.name)
+        core.__name__ = core.__qualname__ = f"tdp_{self._tag}_step"
         self._core = core
         self._jit_step = jax.jit(core)
         self._run_cache: dict = {}
+        self._dispatched: set = set()   # signatures seen, see `_span`
 
     # -- running -----------------------------------------------------------
 
@@ -938,11 +958,26 @@ class CompiledProgram:
                 f"{what} has no ensemble axis — compile a fleet with "
                 f".vmap(batch) (tdp.fleet) instead")
 
+    def _span(self, what: str, arrays, *key) -> jax.profiler.TraceAnnotation:
+        """The host span around one dispatch of ``what`` (``step`` or
+        ``run``): ``tdp.<program>.compile`` the first time jit sees this
+        signature (each array's shape, dtype, weak type and sharding,
+        plus ``key``), so it compiles or loads from the compile cache;
+        ``tdp.<program>.<what>`` after that."""
+        sig = (what, *key, *((a.shape, a.dtype, getattr(a, "weak_type", False),
+                              getattr(a, "sharding", None)) for a in arrays))
+        if sig not in self._dispatched:
+            self._dispatched.add(sig)
+            what = "compile"
+        return jax.profiler.TraceAnnotation(f"tdp.{self.program.name}.{what}")
+
     def step(self, state: Mapping[str, jax.Array]):
         """One step: field mapping in (dict or
         :class:`~repro.core.state.ProgramState`), same kind out."""
         self._require_unbatched("CompiledProgram.step")
-        outs = self._jit_step(*self._as_tuple(state))
+        arrays = self._as_tuple(state)
+        with self._span("step", arrays):
+            outs = self._jit_step(*arrays)
         return self._wrap(state, outs)
 
     def run(self, state: Mapping[str, jax.Array], nsteps: int, *,
@@ -982,9 +1017,12 @@ class CompiledProgram:
                 out, _ = jax.lax.scan(body, arrays, None, length=n)
                 return out
 
+            many.__name__ = many.__qualname__ = f"tdp_{self._tag}_run"
             fn = jax.jit(many, donate_argnums=(0,) if donate else ())
             self._run_cache[key] = fn
-        outs = fn(self._as_tuple(state))
+        arrays = self._as_tuple(state)
+        with self._span("run", arrays, *key):
+            outs = fn(arrays)
         return self._wrap(state, outs)
 
     def _run_guarded(self, state, nsteps: int, health, *,

@@ -43,6 +43,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import Target, executor_wants
@@ -196,12 +197,13 @@ class BinaryFluidSim:
         if nsteps <= 0:
             return state
         s = {"f": state.f, "g": state.g}
-        if self.fused:
-            s = self.programs["collide"].step(s)
-            s = self.programs["fused"].run(s, nsteps - 1, donate=donate)
-            s = self.programs["stream"].step(s)
-        else:
-            s = self.programs["step"].run(s, nsteps, donate=donate)
+        with TraceAnnotation("tdp.sim.run"):
+            if self.fused:
+                s = self.programs["collide"].step(s)
+                s = self.programs["fused"].run(s, nsteps - 1, donate=donate)
+                s = self.programs["stream"].step(s)
+            else:
+                s = self.programs["step"].run(s, nsteps, donate=donate)
         return LBState(s["f"], s["g"], state.step + nsteps)
 
     def run_scanned(self, state: LBState, nsteps: int) -> LBState:
@@ -212,15 +214,23 @@ class BinaryFluidSim:
     # -- observables ---------------------------------------------------------
 
     def observables(self, state: LBState) -> dict:
+        """The run's statistics, read to the host one at a time; each
+        read is the host span ``tdp.observables.<name>``."""
         f, g = state.f, state.g
-        phi = g.sum(0)
-        rho = f.sum(0)
-        return {
-            "mass": float(rho.sum()),
-            "phi_total": float(phi.sum()),
-            "phi_min": float(phi.min()),
-            "phi_max": float(phi.max()),
-            "phi_var": float(phi.var()),
-            "rho_min": float(rho.min()),
-            "nan": bool(jnp.isnan(f).any() | jnp.isnan(g).any()),
-        }
+        with TraceAnnotation("tdp.sim.observables"):
+            phi = g.sum(0)
+            rho = f.sum(0)
+            reads = {
+                "mass": lambda: float(rho.sum()),
+                "phi_total": lambda: float(phi.sum()),
+                "phi_min": lambda: float(phi.min()),
+                "phi_max": lambda: float(phi.max()),
+                "phi_var": lambda: float(phi.var()),
+                "rho_min": lambda: float(rho.min()),
+                "nan": lambda: bool(jnp.isnan(f).any() | jnp.isnan(g).any()),
+            }
+            out = {}
+            for name, read in reads.items():
+                with TraceAnnotation(f"tdp.observables.{name}"):
+                    out[name] = read()
+        return out
