@@ -20,15 +20,18 @@ single shared path every launch takes:
    ghost-window gather into ``(noffsets, ncomp, nsites)`` stacks;
    executors declaring ``wants="halo_extended"`` get each stencil field
    **once**, as a halo-extended ``(ncomp, *ext_shape)`` grid
-   (:func:`halo_extend`) — no ``noffsets×`` re-materialisation in HBM;
+   (:func:`halo_extend`) — no ``noffsets×`` re-materialisation in HBM —
+   and those that also declare ``wraps_periodic=True`` get their
+   periodic dimensions unpadded, to wrap in-kernel;
 5. **dispatch** — through the executor registry
    (:mod:`repro.core.registry`).
 
 Built-in executors registered here: ``"xla"`` (vmap over VVL chunks — the
 paper's C build), ``"pallas"`` and ``"pallas_interpret"`` (explicit VMEM
 tiling — the CUDA build), and ``"pallas_windowed"`` (gather-free x-plane
-windowed VMEM loads — ROADMAP stencil-memory stage (b); Pallas modules
-imported lazily so the core stays importable without Pallas).
+windowed VMEM loads that wrap periodic dimensions in-kernel — ROADMAP
+stencil-memory stage (b); Pallas modules imported lazily so the core
+stays importable without Pallas).
 """
 from __future__ import annotations
 
@@ -43,6 +46,7 @@ from .lattice import Lattice, Stencil
 from .layout import aosoa_to_soa, soa_to_aosoa
 from .memory import BatchedConst, TargetConst
 from .registry import (
+    ExecutorEntry,
     get_executor_entry,
     register_executor,
     registry_version,
@@ -114,6 +118,20 @@ def gather_neighbors(x: jax.Array, shape: tuple[int, ...],
     return jnp.stack(planes)
 
 
+def _trim_ghosts(x: jax.Array, shape: tuple[int, ...],
+                 halo: tuple[int, ...], stencil: Stencil) -> jax.Array:
+    """``(ncomp, nsites_ext)`` → ``(ncomp, *ext_in)`` grid with every
+    caller ghost ring (``halo[d] > 0``) trimmed to the stencil radius;
+    periodic dimensions keep their interior extent."""
+    r = stencil.radius_per_dim()
+    ext_in = tuple(s + 2 * h for s, h in zip(shape, halo))
+    g = x.reshape(x.shape[0], *ext_in)
+    for d, (h, rd, s) in enumerate(zip(halo, r, shape)):
+        if h > rd:           # caller ghost wider than needed: trim
+            g = jax.lax.slice_in_dim(g, h - rd, h + rd + s, axis=d + 1)
+    return g
+
+
 @jax.named_scope("tdp.halo_extend")
 def halo_extend(x: jax.Array, shape: tuple[int, ...],
                 halo: tuple[int, ...], stencil: Stencil) -> jax.Array:
@@ -127,15 +145,13 @@ def halo_extend(x: jax.Array, shape: tuple[int, ...],
     shift.  Dimensions with ``halo[d] == 0`` wrap periodically
     (``jnp.pad(mode="wrap")``); dimensions with ``halo[d] > 0`` reuse the
     caller-supplied ghost planes, trimmed down to the stencil radius.
+    Executors registered with ``wraps_periodic=True`` get only the ghost
+    trim: their periodic dimensions arrive at the interior extent.
     """
     r = stencil.radius_per_dim()
-    ext_in = tuple(s + 2 * h for s, h in zip(shape, halo))
-    g = x.reshape(x.shape[0], *ext_in)
     widths = [(0, 0)]
     for d, (h, rd, s) in enumerate(zip(halo, r, shape)):
         if h:
-            if h > rd:       # caller ghost wider than needed: trim
-                g = jax.lax.slice_in_dim(g, h - rd, h + rd + s, axis=d + 1)
             widths.append((0, 0))
         else:
             if rd > s:
@@ -146,6 +162,7 @@ def halo_extend(x: jax.Array, shape: tuple[int, ...],
                     f">= {rd} exchanged ghost planes in dim {d} "
                     f"(halo > 0) or enlarge the dimension")
             widths.append((rd, rd))
+    g = _trim_ghosts(x, shape, halo, stencil)
     if any(w != (0, 0) for w in widths):
         g = jnp.pad(g, widths, mode="wrap")
     return g
@@ -227,15 +244,21 @@ class LaunchPlan:
     executors can resolve neighbour offsets themselves and so the
     :meth:`vmem_bytes_estimate` / :meth:`hbm_bytes_estimate` memory
     models are derivable from the plan alone (see docs/stencil.md).
+
+    ``wrap_dims`` holds, per field, the lattice dimensions the executor
+    wraps in-kernel (those with ``halo[d] == 0`` under a
+    ``wraps_periodic`` executor; ``()`` otherwise): such a stencil field
+    arrives at its interior extent there, with no ghost layers.
     """
 
     __slots__ = ("kernel", "name", "vvl", "out_ncomp", "consts",
                  "with_site_index", "interpret", "target", "shape", "halo",
-                 "stencils", "field_ncomp", "wants")
+                 "stencils", "field_ncomp", "wants", "wrap_dims")
 
     def __init__(self, *, kernel, name, vvl, out_ncomp, consts,
                  with_site_index, interpret, target, shape=None, halo=None,
-                 stencils=None, field_ncomp=None, wants="gathered"):
+                 stencils=None, field_ncomp=None, wants="gathered",
+                 wrap_dims=None):
         self.kernel = kernel
         self.name = name
         self.vvl = vvl
@@ -250,6 +273,9 @@ class LaunchPlan:
         self.field_ncomp = (tuple(field_ncomp)
                             if field_ncomp is not None else None)
         self.wants = wants
+        self.wrap_dims = (tuple(tuple(w) for w in wrap_dims)
+                          if wrap_dims is not None
+                          else ((),) * len(self.field_ncomp or ()))
 
     @property
     def layout(self) -> str:
@@ -277,7 +303,8 @@ class LaunchPlan:
     # rows (the HBM-materialised neighbour stack), a halo-extended one
     # ncomp rows over the (slightly larger) extended extent — the
     # ``noffsets×`` factor is exactly what ``wants="halo_extended"``
-    # eliminates.  Fields with undeclared ncomp count as 1.
+    # eliminates; dimensions in ``wrap_dims`` carry no ghosts at all.
+    # Fields with undeclared ncomp count as 1.
 
     def _fields(self):
         if self.field_ncomp is None:
@@ -285,11 +312,14 @@ class LaunchPlan:
                 f"plan {self.name!r} carries no field metadata; build it "
                 f"through tdp.launch / tdp.launch_plan")
         stencils = self.stencils or (None,) * len(self.field_ncomp)
-        return tuple(zip(self.field_ncomp, stencils))
+        return tuple(zip(self.field_ncomp, stencils, self.wrap_dims))
 
-    def _ext_shape(self, stencil):
+    def _ext_shape(self, stencil, wrap):
+        """Extent of a prepared stencil field: the interior plus the
+        stencil radius on both sides, except in the wrapped dims."""
         r = stencil.radius_per_dim()
-        return tuple(s + 2 * rd for s, rd in zip(self.shape, r))
+        return tuple(s if d in wrap else s + 2 * rd
+                     for d, (s, rd) in enumerate(zip(self.shape, r)))
 
     def vmem_bytes_estimate(self, itemsize: int = 4) -> int:
         """Fast-memory footprint of one grid step (inputs + outputs).
@@ -302,7 +332,7 @@ class LaunchPlan:
         if self.wants != "halo_extended":
             out_rows = sum(self.out_ncomp)
             in_rows = sum((s.noffsets if s is not None else 1) * c
-                          for c, s in self._fields())
+                          for c, s, _ in self._fields())
             return (in_rows + out_rows) * self.vvl * itemsize
         total = 3 * sum(b for _, b in self.window_blocks(itemsize))
         p = int(self.target.tune("plane_block", 1))
@@ -311,7 +341,7 @@ class LaunchPlan:
             # (noffsets, ncomp, p·V) chunk the compiler materialises
             v = p * _prod_shape(self.shape[1:])
             total += sum(_tiled_bytes((s.noffsets, c, v), itemsize)
-                         for c, s in self._fields() if s is not None)
+                         for c, s, _ in self._fields() if s is not None)
         return total
 
     def window_blocks(self, itemsize: int = 4) -> list[tuple[int, int]]:
@@ -319,7 +349,8 @@ class LaunchPlan:
         bytes)`` pairs (index ``-1`` for outputs): every window plane of
         every stencil field, each pointwise block and each output block,
         with its two minor dims padded to Mosaic's ``(8·4/itemsize, 128)``
-        tile.
+        tile.  A window plane spans the prepared extent: interior in the
+        wrapped dims, interior plus the radius on both sides elsewhere.
 
         :meth:`vmem_bytes_estimate` counts each block three times: the
         pipeline's two buffers (the next step's DMA overlaps this step's
@@ -341,13 +372,13 @@ class LaunchPlan:
         aosoa = self.layout == "aosoa"
         vvl = int(self.vvl)
         blocks = []
-        for i, (c, s) in enumerate(self._fields()):
+        for i, (c, s, wrap) in enumerate(self._fields()):
             if s is None:
                 shape = ((p, rest_n // vvl, c, vvl) if aosoa
                          else (c, p, *rest))
                 blocks.append((i, _tiled_bytes(shape, itemsize)))
                 continue
-            ext_rest = self._ext_shape(s)[1:]
+            ext_rest = self._ext_shape(s, wrap)[1:]
             shape = ((1, -(-_prod_shape(ext_rest) // vvl), c, vvl) if aosoa
                      else (c, 1, *ext_rest))
             window = p + 2 * s.radius_per_dim()[0]
@@ -364,7 +395,9 @@ class LaunchPlan:
         stencil field (the ~noffsets× amplification this framework's
         windowed executor exists to remove); the halo-extended path pays
         only the ghost-layer overhead ``prod(shape + 2·radius) /
-        prod(shape)`` — independent of ``noffsets``.
+        prod(shape)`` — independent of ``noffsets`` — and none in the
+        dims it wraps in-kernel (``wrap_dims``): on a fully periodic
+        lattice its operands are the caller's arrays, reshaped.
 
         ``layout="aosoa"`` doubles the estimate: the SoA↔AoSoA boundary
         transforms re-materialise every prepared operand and output once
@@ -375,11 +408,11 @@ class LaunchPlan:
             raise ValueError("hbm_bytes_estimate needs a lattice shape")
         n = _prod_shape(self.shape)
         total = sum(self.out_ncomp) * n
-        for c, s in self._fields():
+        for c, s, wrap in self._fields():
             if s is None:
                 total += c * n
             elif self.wants == "halo_extended":
-                total += c * _prod_shape(self._ext_shape(s))
+                total += c * _prod_shape(self._ext_shape(s, wrap))
             else:
                 total += c * s.noffsets * n
         if self.layout == "aosoa":
@@ -389,7 +422,7 @@ class LaunchPlan:
     def __repr__(self):
         return (f"LaunchPlan({self.name!r}, executor={self.target.executor!r}"
                 f", vvl={self.vvl}, out={self.out_ncomp}, "
-                f"wants={self.wants!r})")
+                f"wants={self.wants!r}, wrap_dims={self.wrap_dims})")
 
 
 # ---------------------------------------------------------------------------
@@ -474,9 +507,10 @@ def _validate_arrays(spec: KernelSpec, arrays, lattice, halo):
 
 
 def _validate_wrap_extents(spec: KernelSpec, lattice, halo):
-    """Plan-build guard for :func:`halo_extend`'s periodic path: a
-    ``wants="halo_extended"`` launch wrap-pads every dimension whose halo
-    is 0 by the stencil radius, which this framework refuses when the
+    """Plan-build guard for the periodic path of ``wants="halo_extended"``
+    launches: every dimension whose halo is 0 is wrapped by the stencil
+    radius (padded by :func:`halo_extend`, or in-kernel under a
+    ``wraps_periodic`` executor), which this framework refuses when the
     radius exceeds the extent (e.g. a radius-2 stencil meeting a 1-plane
     pencil).  Raising here names the dim/radius/extent *before* tracing,
     instead of surfacing deep inside the jitted launch."""
@@ -492,9 +526,9 @@ def _validate_wrap_extents(spec: KernelSpec, lattice, halo):
                 raise ValueError(
                     f"{fs.label(i)} of kernel {spec.name!r}: stencil "
                     f"{s.name!r} radius {r} in dim {d} exceeds the "
-                    f"periodic extent {lattice.shape[d]} (halo_extend "
-                    f"cannot wrap-pad a dimension thinner than the "
-                    f"stencil radius); supply >= {r} ghost planes in "
+                    f"periodic extent {lattice.shape[d]} (a windowed "
+                    f"launch cannot wrap-pad a dimension thinner than "
+                    f"the stencil radius); supply >= {r} ghost planes in "
                     f"dim {d} or enlarge it")
 
 
@@ -596,7 +630,9 @@ def _validate_layout(spec: KernelSpec, target: Target,
 def _make_plan(spec: KernelSpec, target: Target, vvl: int,
                out_ncomp: tuple[int, ...], lattice: Lattice | None,
                halo: tuple[int, ...] | None, consts: dict,
-               wants: str) -> LaunchPlan:
+               entry: ExecutorEntry) -> LaunchPlan:
+    periodic = (tuple(d for d, h in enumerate(halo) if h == 0)
+                if entry.wraps_periodic and halo is not None else ())
     return LaunchPlan(
         kernel=spec.fn, name=spec.name, vvl=vvl, out_ncomp=out_ncomp,
         consts=consts, with_site_index=spec.site_index,
@@ -605,7 +641,9 @@ def _make_plan(spec: KernelSpec, target: Target, vvl: int,
         stencils=spec.stencils,
         field_ncomp=tuple(fs.ncomp if fs.ncomp is not None else 1
                           for fs in spec.fields),
-        wants=wants)
+        wants=entry.wants,
+        wrap_dims=tuple(periodic if fs.stencil is not None else ()
+                        for fs in spec.fields))
 
 
 @functools.lru_cache(maxsize=4096)
@@ -618,7 +656,7 @@ def _build_plan(spec: KernelSpec, target: Target, vvl: int,
     entry = get_executor_entry(target.executor)
     executor = entry.fn
     plan = _make_plan(spec, target, vvl, out_ncomp, lattice, halo, consts,
-                      entry.wants)
+                      entry)
     if entry.wants == "halo_extended":
         _check_window_vmem(plan, spec)
     stencils = spec.stencils
@@ -626,7 +664,15 @@ def _build_plan(spec: KernelSpec, target: Target, vvl: int,
     n_out = len(out_ncomp)
     nf = len(spec.fields)
 
-    if entry.wants == "halo_extended":
+    if entry.wraps_periodic:
+        # The executor wraps periodic dims itself: only caller ghosts are
+        # trimmed, and a fully periodic field is passed as a reshape.
+        def prepare(x, s):
+            if s is None:
+                return x
+            with jax.named_scope("tdp.halo_extend"):
+                return _trim_ghosts(x, shape, halo, s)
+    elif entry.wants == "halo_extended":
         # Capability-aware prologue: pad each stencil field once instead
         # of rolling out one HBM copy per offset.
         def prepare(x, s):
@@ -769,7 +815,7 @@ def launch_plan(spec: KernelSpec, target: Target | str | None = None, *,
             f"launch_plan cannot build a faithful plan")
     return _make_plan(spec, tgt, tgt.resolve_vvl(), tuple(out_ncomp),
                       lattice, h, _unwrap_consts(dict(consts or {})),
-                      entry.wants)
+                      entry)
 
 
 # ---------------------------------------------------------------------------
@@ -845,4 +891,5 @@ register_executor("pallas", _pallas_executor, tunables=_PALLAS_TUNABLES)
 register_executor("pallas_interpret", _pallas_executor,
                   tunables=_PALLAS_TUNABLES)
 register_executor("pallas_windowed", _pallas_windowed_executor,
-                  wants="halo_extended", tunables=("plane_block",))
+                  wants="halo_extended", tunables=("plane_block",),
+                  wraps_periodic=True)

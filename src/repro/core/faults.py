@@ -95,7 +95,8 @@ def register_failing_executor(name: str, *, base: str = "xla",
     entry = get_executor_entry(base)
     handle = _FailingExecutor(name, entry.fn, fail_on, times)
     register_executor(name, handle, overwrite=True, wants=entry.wants,
-                      tunables=entry.tunables)
+                      tunables=entry.tunables,
+                      wraps_periodic=entry.wraps_periodic)
     return handle
 
 

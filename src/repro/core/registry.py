@@ -21,6 +21,9 @@ an executor only maps a prepared plan over prepared site arrays:
         #               dimension (periodic dims wrap-padded, sharded
         #               dims trimmed from the caller's ghost planes);
         #               the executor resolves offsets itself, in-kernel.
+        #               With wraps_periodic=True the periodic dims are
+        #               not padded either: they arrive at their interior
+        #               extent and the executor wraps them itself.
         # returns:  tuple of (ncomp_o, nsites) outputs, one per
         #           plan.out_ncomp entry (a bare array is accepted for
         #           single-output kernels)
@@ -28,6 +31,8 @@ an executor only maps a prepared plan over prepared site arrays:
 
     register_executor("my_backend", my_executor)                 # gathered
     register_executor("my_windowed", my_win, wants="halo_extended")
+    register_executor("my_wrapping", my_wrap, wants="halo_extended",
+                      wraps_periodic=True)
     tdp.launch(spec, Target("my_backend"), *arrays)
 
 Registering a new architecture is *one* ``register_executor`` call — the
@@ -47,12 +52,14 @@ EXECUTOR_WANTS = ("gathered", "halo_extended")
 
 class ExecutorEntry(NamedTuple):
     """One registry row: the executor callable plus its declared input
-    capability (see ``EXECUTOR_WANTS``) and the ``Target.tuning`` keys it
-    consults (``tunables`` — the sweep/autotune surface)."""
+    capability (see ``EXECUTOR_WANTS``), the ``Target.tuning`` keys it
+    consults (``tunables`` — the sweep/autotune surface) and whether it
+    wraps periodic dimensions itself (``wraps_periodic``)."""
 
     fn: Callable
     wants: str
     tunables: tuple[str, ...] = ()
+    wraps_periodic: bool = False
 
 
 _EXECUTORS: dict[str, ExecutorEntry] = {}
@@ -61,7 +68,8 @@ _VERSION = 0
 
 def register_executor(name: str, fn: Callable, *, overwrite: bool = False,
                       wants: str = "gathered",
-                      tunables: tuple[str, ...] = ()) -> None:
+                      tunables: tuple[str, ...] = (),
+                      wraps_periodic: bool = False) -> None:
     """Register ``fn`` as the executor behind ``Target(backend=name)``.
 
     ``wants`` declares the input capability: ``"gathered"`` (default)
@@ -75,6 +83,15 @@ def register_executor(name: str, fn: Callable, *, overwrite: bool = False,
     candidate spaces from; sweeping a key outside this set is rejected up
     front instead of silently measuring a no-op.
 
+    ``wraps_periodic`` (``"halo_extended"`` executors only) declares that
+    the executor wraps periodic dimensions (launch ``halo[d] == 0``)
+    itself: the prologue then pads nothing there, and each stencil field
+    arrives at its interior extent in those dimensions — on a fully
+    periodic lattice as the plain ``(ncomp, *shape)`` reshape, with no
+    copy.  Dimensions with caller ghosts keep the ``halo_extended``
+    contract (ghost planes trimmed to the stencil radius).  The plan
+    records the wrapped dimensions per field (``LaunchPlan.wrap_dims``).
+
     Raises ``ValueError`` on duplicate names unless ``overwrite=True``.
     """
     global _VERSION
@@ -86,12 +103,17 @@ def register_executor(name: str, fn: Callable, *, overwrite: bool = False,
     if wants not in EXECUTOR_WANTS:
         raise ValueError(f"executor capability must be one of "
                          f"{EXECUTOR_WANTS}, got {wants!r}")
+    if wraps_periodic and wants != "halo_extended":
+        raise ValueError(f"wraps_periodic needs wants='halo_extended' (a "
+                         f"gathered executor receives no grid to wrap), "
+                         f"got wants={wants!r}")
     tunables = tuple(str(t) for t in tunables)
     if name in _EXECUTORS and not overwrite:
         raise ValueError(
             f"executor {name!r} is already registered; pass overwrite=True "
             f"to replace it")
-    _EXECUTORS[name] = ExecutorEntry(fn, wants, tunables)
+    _EXECUTORS[name] = ExecutorEntry(fn, wants, tunables,
+                                     bool(wraps_periodic))
     _VERSION += 1
 
 

@@ -7,36 +7,48 @@ streaming, 57× for the fused LB g-neighbourhood) — the amplification the
 paper's follow-up (arXiv:1609.01479) and Alpaka (arXiv:1602.08477) avoid
 by serving stencil neighbourhoods from on-chip memory.
 
-This executor declares ``wants="halo_extended"`` in the registry, so the
-launch prologue hands it each stencil field **once**, as a halo-extended
-grid ``(ncomp, X+2r₀, Y+2r₁, ...)`` (periodic dims wrap-padded, sharded
-dims reusing the caller's ghost planes).  Execution is an **x-plane
-grid**: step *i* computes ``plane_block`` output planes, and for each
-stencil field loads only the ``plane_block + 2·r₀`` x-planes its stencil
-can reach into VMEM.  Neighbour offsets are resolved *in-kernel* from the
-:class:`~repro.core.lattice.Stencil` descriptor by static plane selection
-(the x component) and static y/z slices of the extended planes — the
-``(noffsets, ncomp, V)`` chunk every site kernel already expects is
-assembled in fast memory and never exists in HBM.  Site kernels stay
-single-source; bit-identity with the ``"xla"`` executor is pinned by
-``tests/test_windowed.py``.
+This executor declares ``wants="halo_extended"`` and
+``wraps_periodic=True`` in the registry, so the launch prologue hands it
+each stencil field **once**, as a grid ``(ncomp, *ext)``: a dimension
+with caller ghosts (a sharded one) carries exactly the stencil radius
+``r_d`` of them, and a periodic one arrives at its interior extent — on
+one chip the field is the plain ``(ncomp, X, Y, Z)`` reshape, with no
+copy in HBM.  Execution is an **x-plane grid**: step *i* computes
+``plane_block`` output planes, and for each stencil field loads only the
+``plane_block + 2·r₀`` x-planes its stencil can reach into VMEM.
+Neighbour offsets are resolved *in-kernel* from the
+:class:`~repro.core.lattice.Stencil` descriptor: the x component by
+static window-slot selection, each y/z component by a static slice of
+the ghost-extended plane (ghost dims) or a rotation of the loaded plane
+by ``−off mod extent`` (periodic dims, ``pltpu.roll``).  Each rotated
+plane is made once per distinct (window slot, dy, dz) and shared by the
+offsets that read it; the compiler keeps only the components a site
+kernel reads.  The ``(noffsets, ncomp, V)`` chunk every site kernel
+already expects is assembled in fast memory and never exists in HBM.
+Site kernels stay single-source; the values moved are the same either
+way, so a periodic launch is bit-identical to the same launch given
+wrap-filled ghosts (``tests/test_windowed.py``).
 
 Mechanically, the window is expressed through Pallas block indexing with
-no overlap tricks: the extended array is passed once per window plane
+no overlap tricks: the prepared array is passed once per window slot
 (operands alias one HBM buffer — XLA sees one value used W times), each
-with a depth-1 BlockSpec ``lambda i: (0, i·plane_block + j, 0, ...)``, so
-every grid step DMAs exactly its window into VMEM.
+with a depth-1 BlockSpec ``lambda i: (0, i·plane_block + j, 0, ...)``
+into the ghost-extended array, or ``(0, (i·plane_block + j − r₀ + X) mod
+X, 0, ...)`` when x is periodic — so every grid step DMAs exactly its
+window into VMEM, and a ``plane_block`` that does not divide X only
+feeds output rows that are sliced away.
 
 Memory model (vs the gathered path, per ``LaunchPlan`` estimates):
 
-  HBM   Σ_i ncomp_i · prod(shape_d + 2r_d)      [was noffsets_i × interior]
+  HBM   Σ_i ncomp_i · prod(ext_d),  ext_d = shape_d (periodic) or
+        shape_d + 2r_d (ghosts)          [was noffsets_i × interior]
   VMEM  3 × Σ_i ncomp_i · (plane_block + 2r₀) · prod(ext_rest)   per grid
         step, each plane padded to the (8, 128) tile
         (:meth:`~repro.core.api.LaunchPlan.window_blocks`)
 
-— the ``noffsets×`` term is gone from both; large grids (≥64³) that OOM
-under the 57× fused gather fit comfortably.  Compiled (``interpret=False``)
-the kernel is given the device kind's VMEM limit
+— the ``noffsets×`` term is gone from both, and on a periodic lattice the
+planes keep their unpadded (e.g. 128-lane) extent.  Compiled
+(``interpret=False``) the kernel is given the device kind's VMEM limit
 (:func:`repro.core.costmodel.vmem_limit_bytes`), and plans Mosaic cannot
 lower — ``layout="aosoa"``, or a minor lattice extent that is not a
 multiple of 128 lanes — are refused at plan build
@@ -59,7 +71,7 @@ per vvl — trading a dense output store for broken bit-identity.  With
 SoA output blocks every layout×vvl point is bit-identical to the SoA
 path (pinned by ``tests/test_layout.py``).  ``vvl`` must divide the
 *interior* plane site count exactly — validated at plan-build time by
-:func:`repro.core.api.launch`; the halo-extended stencil operand planes
+:func:`repro.core.api.launch`; ghost-extended stencil operand planes
 are zero-padded to a vvl multiple here and the pad lanes sliced away
 in-kernel.
 """
@@ -87,10 +99,10 @@ def windowed_execute(plan, extended):
     """Registry executor entry (``wants="halo_extended"`` — see
     :mod:`repro.core.registry`).
 
-    ``extended``: one array per field — ``(ncomp, *ext_shape)`` halo-
-    extended grids for stencil fields (ghost width = the stencil's
-    per-dim radius, prepared by :func:`repro.core.api.halo_extend`),
-    ``(ncomp, nsites)`` for pointwise fields.
+    ``extended``: one array per field — ``(ncomp, *ext_shape)`` grids for
+    stencil fields (ghost width = the stencil's per-dim radius in ghost
+    dims, none in the dims of ``plan.wrap_dims``, which are wrapped
+    here), ``(ncomp, nsites)`` for pointwise fields.
     """
     shape = plan.shape
     if shape is None:
@@ -112,7 +124,7 @@ def windowed_execute(plan, extended):
     vvl = int(plan.vvl)
 
     operands, in_specs, field_meta = [], [], []
-    for x, s in zip(extended, stencils):
+    for x, s, wrap in zip(extended, stencils, plan.wrap_dims):
         ncomp = int(x.shape[0])
         if s is None:
             grid_x = x.reshape(ncomp, X, *rest)
@@ -130,16 +142,18 @@ def windowed_execute(plan, extended):
                 in_specs.append(pl.BlockSpec(
                     (ncomp, p, *rest),
                     lambda i: (0, i, *([0] * (ndim - 1)))))
-            field_meta.append(("pointwise", ncomp, None, None))
+            field_meta.append(("pointwise", ncomp, None, None, None, None))
         else:
             r = s.radius_per_dim()
-            ext = tuple(sd + 2 * rd for sd, rd in zip(shape, r))
+            ext = tuple(sd if d in wrap else sd + 2 * rd
+                        for d, (sd, rd) in enumerate(zip(shape, r)))
             if x.shape[1:] != ext:
                 raise ValueError(
-                    f"stencil field of kernel {plan.name!r} is not halo-"
-                    f"extended to radius {r}: got {tuple(x.shape[1:])}, "
-                    f"want {ext}")
-            if x_pad:
+                    f"stencil field of kernel {plan.name!r} is not "
+                    f"prepared to radius {r} with dims {wrap} wrapped "
+                    f"in-kernel: got {tuple(x.shape[1:])}, want {ext}")
+            x_wraps = 0 in wrap
+            if x_pad and not x_wraps:
                 x = jnp.pad(x, [(0, 0), (0, x_pad)]
                             + [(0, 0)] * (ndim - 1))
             window = p + 2 * r[0]
@@ -155,21 +169,29 @@ def windowed_execute(plan, extended):
                 x = plane_to_aosoa(xf, vvl)  # (Xext, nblk_e, ncomp, vvl)
                 nblk_e = int(x.shape[1])
             # One depth-1 plane ref per window slot: operand j of this
-            # field is the extended array blocked at x-plane i·p + j.
-            # All window operands alias one HBM value — the only copies
-            # are the per-step HBM→VMEM window loads.
+            # field is blocked at x-plane i·p + j of the ghost-extended
+            # array, or, with x periodic, at plane i·p + j − r₀ modulo X
+            # of the interior one (kept non-negative: scalar rem
+            # truncates).  All window operands alias one HBM value — the
+            # only copies are the per-step HBM→VMEM window loads.
             for j in range(window):
                 operands.append(x)
+                if x_wraps:
+                    def xplane(i, j=j, r0=r[0]):
+                        return jax.lax.rem(i * p + (j - r0 + X), X)
+                else:
+                    def xplane(i, j=j):
+                        return i * p + j
                 if aosoa:
                     in_specs.append(pl.BlockSpec(
                         (1, nblk_e, ncomp, vvl),
-                        lambda i, j=j: (i * p + j, 0, 0, 0)))
+                        lambda i, xplane=xplane: (xplane(i), 0, 0, 0)))
                 else:
                     in_specs.append(pl.BlockSpec(
                         (ncomp, 1, *ext[1:]),
-                        lambda i, j=j: (0, i * p + j,
-                                        *([0] * (ndim - 1)))))
-            field_meta.append(("stencil", ncomp, s, r))
+                        lambda i, xplane=xplane: (0, xplane(i),
+                                                  *([0] * (ndim - 1)))))
+            field_meta.append(("stencil", ncomp, s, r, ext, wrap))
 
     scalar_consts, array_consts = _canonicalize_consts(plan.consts)
     const_names = list(array_consts)
@@ -204,29 +226,49 @@ def windowed_execute(plan, extended):
             return y.reshape(ncomp, npl, *rest_shape)
 
         chunks = []
-        for kind, ncomp, s, r in field_meta:
+        for kind, ncomp, s, r, ext, wrap in field_meta:
             if kind == "pointwise":
                 blk = next(it)[...]
                 if aosoa:
                     blk = unpack_plane(blk, ncomp, rest)
                 chunks.append(blk.reshape(ncomp, chunk))
                 continue
-            ext_rest = tuple(sd + 2 * rd
-                             for sd, rd in zip(shape[1:], r[1:]))
-            planes = [next(it)[...] for _ in range(p + 2 * r[0])]
+            slots = [next(it) for _ in range(p + 2 * r[0])]
             if aosoa:
-                planes = [unpack_plane(pp, ncomp, ext_rest)
-                          for pp in planes]
+                slots = [unpack_plane(ref[...], ncomp, ext[1:])
+                         for ref in slots]
+            moved = {}
+
+            def neighbour(slot, rest_off, slots=slots, moved=moved, r=r,
+                          wrap=wrap):
+                # window plane `slot`, (ncomp, *rest), moved by the y/z...
+                # offsets rest_off: a caller-ghost dim is a static slice
+                # of the extended plane, a periodic one a rotation by
+                # −off mod extent.  Memoised on every prefix of rest_off,
+                # so each rotation is made once and shared by the offsets
+                # (and plane_block rows) that need it; the compiler keeps
+                # only the components a site kernel reads.
+                key = (slot, rest_off)
+                if key not in moved:
+                    if not rest_off:
+                        v = slots[slot][:, 0]
+                    else:
+                        v = neighbour(slot, rest_off[:-1])
+                        d, o = len(rest_off), rest_off[-1]
+                        if d not in wrap:
+                            v = jax.lax.slice_in_dim(
+                                v, r[d] + o, r[d] + o + shape[d], axis=d)
+                        elif o % shape[d]:
+                            v = pltpu.roll(v, (-o) % shape[d], d)
+                    moved[key] = v
+                return moved[key]
+
             nb = []
             for off in s.offsets:
                 rows = []
                 for xl in range(p):
                     # plane (local x = xl) + offset: window slot is static
-                    sl = planes[xl + r[0] + off[0]][:, 0]
-                    for d in range(1, ndim):
-                        start = r[d] + off[d]
-                        sl = jax.lax.slice_in_dim(
-                            sl, start, start + shape[d], axis=d)
+                    sl = neighbour(xl + r[0] + off[0], tuple(off[1:]))
                     rows.append(sl.reshape(ncomp, rest_n))
                 nb.append(rows[0] if p == 1
                           else jnp.concatenate(rows, axis=-1))
@@ -239,6 +281,12 @@ def windowed_execute(plan, extended):
         for cname, cref in zip(const_names, const_refs):
             orig_shape, _ = array_consts[cname]
             kw[cname] = cref[...].reshape(orig_shape)
+        if plan.interpret:
+            # XLA's CPU backend contracts multiply-adds differently
+            # depending on which data movement it fuses into the site
+            # arithmetic; behind a barrier the arithmetic compiles the
+            # same whether its chunk came from slices or rotations.
+            chunks = jax.lax.optimization_barrier(chunks)
         vals = plan.kernel(*chunks, **kw)
         vals = (vals,) if not isinstance(vals, tuple) else vals
         for ref, v in zip(out_refs, vals):

@@ -602,6 +602,27 @@ class TestProgramPlan:
         assert w.hbm_bytes_estimate() < 100 * 2 ** 20
         assert all(r["wants"] == "halo_extended" for r in w.per_stage())
 
+    @pytest.mark.parametrize("decomposition, wrapped", [
+        ("one_chip", (0, 1, 2)), ("pencil", (2,))])
+    def test_per_stage_reports_in_kernel_wrap(self, decomposition,
+                                              wrapped):
+        """The counter that the in-kernel wrap engaged: every stencil
+        field of both fused windowed launches wraps all three dims on
+        one chip, and only the unsharded z on a 2×2 pencil (x and y
+        carry exchanged ghosts)."""
+        from repro.core.program import (_build_program_plan,
+                                        resolve_stage_target)
+        prog = lbp.fused_program("two_launch", self.consts())
+        open_dims = (decomposition == "pencil",) * 2 + (False,)
+        widths, geo = prog.schedule(3, open_dims)
+        targets = tuple(resolve_stage_target(WINDOWED, st.spec, st.name)
+                        for st in prog.stages)
+        plan = _build_program_plan(prog, targets, GRID, geo, widths)
+        rows = plan.per_stage()
+        assert [r["stage"] for r in rows] == ["phi_stream", "fused_two"]
+        assert [r["wrap_dims"] for r in rows] == [(wrapped,),
+                                                  (wrapped,) * 3]
+
     def test_plan_routes_pointwise_stages(self):
         plan = lbp.collide_program(self.consts()).plan(WINDOWED,
                                                        grid_shape=GRID)

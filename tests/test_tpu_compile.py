@@ -14,6 +14,7 @@ in this one file, so a single worker loads it.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -59,14 +60,20 @@ def on_v5e(monkeypatch):
     return set_limit
 
 
-def _compile(spec, target, sharding):
+def _compile(spec, target, sharding, halo=None):
     lat = Lattice(GRID)
-    args = [jax.ShapeDtypeStruct((f.ncomp, lat.nsites), jnp.float32,
-                                 sharding=sharding) for f in spec.fields]
+    n_ext = int(np.prod([s + 2 * h for s, h in zip(GRID, halo or (0,) * 3)]))
+    args = [jax.ShapeDtypeStruct(
+        (f.ncomp, n_ext if f.stencil is not None else lat.nsites),
+        jnp.float32, sharding=sharding) for f in spec.fields]
     consts = {k: CONSTS[k] for k in spec.consts or ()}
     fn = jax.jit(lambda *a: tdp.launch(spec, target, *a, lattice=lat,
-                                       consts=consts))
+                                       halo=halo, consts=consts))
     return fn.lower(*args).compile()
+
+
+def _has_pad(hlo: str) -> bool:
+    return re.search(r"\bpad\(", hlo) is not None
 
 
 #: the windowed kernels of each fused mode (lb.programs.fused_program)
@@ -82,7 +89,8 @@ def test_windowed_fused_compiles_within_its_estimate(mode, one_chip,
     """Each windowed kernel compiles when Mosaic's scoped-VMEM limit is
     the plan's own ``vmem_bytes_estimate()``: the estimate is at least
     what the compiler allocates, so the plan-build guard and autotune's
-    pruning hold plans to a true bound."""
+    pruning hold plans to a true bound.  On one chip every dimension is
+    periodic and wrapped inside the kernel, so the launch holds no pad."""
     target = tdp.Target("pallas_windowed")
     limit = cm.DEVICE_PEAKS[KIND]["vmem_limit"]
     for spec in WINDOWED_KERNELS[mode]:
@@ -90,8 +98,26 @@ def test_windowed_fused_compiles_within_its_estimate(mode, one_chip,
                               lattice=Lattice(GRID)).vmem_bytes_estimate()
         assert est <= limit
         on_v5e(est)
-        compiled = _compile(spec, target, one_chip)
-        assert "tpu_custom_call" in compiled.as_text()
+        hlo = _compile(spec, target, one_chip).as_text()
+        assert "tpu_custom_call" in hlo
+        assert not _has_pad(hlo)
+
+
+def test_windowed_pencil_compiles_within_its_estimate(one_chip, on_v5e):
+    """The pencil's launch geometry: x and y carry one exchanged ghost
+    plane, z is periodic and wrapped inside the kernel.  Both two_launch
+    kernels compile within their estimate, with no pad in the launch."""
+    target = tdp.Target("pallas_windowed")
+    halo = (1, 1, 0)
+    for spec in WINDOWED_KERNELS["two_launch"]:
+        plan = tdp.launch_plan(spec, target, lattice=Lattice(GRID),
+                               halo=halo)
+        assert all(w == (2,) for w, f in zip(plan.wrap_dims, spec.fields)
+                   if f.stencil is not None)
+        on_v5e(plan.vmem_bytes_estimate())
+        hlo = _compile(spec, target, one_chip, halo=halo).as_text()
+        assert "tpu_custom_call" in hlo
+        assert not _has_pad(hlo)
 
 
 def test_pallas_pointwise_collision_compiles(one_chip):

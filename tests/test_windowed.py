@@ -12,8 +12,13 @@ ROADMAP stencil-memory stage (b), pinned here (docs/stencil.md):
   edits, and feeding one a pointwise spec fails fast;
 * the ``LaunchPlan`` memory models show the ``noffsets×`` HBM term gone:
   the windowed estimate depends only on the stencil *radius*, never on
-  its offset count.
+  its offset count;
+* periodic dimensions are wrapped inside the kernel
+  (``wraps_periodic=True``): such a launch is **bit-identical** to the
+  same launch given wrap-filled caller ghosts, and no pad runs outside
+  the kernel.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -23,10 +28,12 @@ from repro.core import (
     Lattice,
     STENCIL_GRAD_6PT,
     STENCIL_GRAD_19PT,
+    Stencil,
     halo_extend,
     launch_plan,
 )
 from repro.kernels.lb_collision import NVEL
+from repro.lb import programs as lbp
 from repro.lb import stencil as lbst
 from repro.lb.params import LBParams
 from repro.lb.sim import BinaryFluidSim
@@ -124,6 +131,118 @@ class TestWindowedParity:
         np.testing.assert_array_equal(np.asarray(ua.g), np.asarray(ub.g))
 
 
+#: a 2-D nine-point neighbourhood (radius 1) and a kernel reading all of it
+_STENCIL_2D = Stencil("box_2d", tuple((dx, dy) for dx in (-1, 0, 1)
+                                      for dy in (-1, 0, 1)))
+_BOX2D_SPEC = tdp.KernelSpec(
+    lambda nb: sum((i + 1.0) * nb[i] for i in range(9)),
+    fields=(tdp.field(2, stencil=_STENCIL_2D),), out=2, name="box_2d")
+
+_FUSED_CONSTS = {k: v for k, v in lbp.collision_consts(
+    **LBParams(A=0.125, B=0.125, kappa=0.02).as_kwargs()).items()
+    if k in lbst.FUSED_SPEC.consts}
+
+
+def _with_ghosts(x, shape, halo):
+    """``(ncomp, nsites)`` → the same field carrying ``halo[d]`` wrap-filled
+    ghost layers per dimension, flattened: what a caller with exchanged
+    ghost planes would pass."""
+    grid = x.reshape(x.shape[0], *shape)
+    ext = jnp.pad(grid, [(0, 0)] + [(h, h) for h in halo], mode="wrap")
+    return ext.reshape(x.shape[0], -1)
+
+
+def _launch_inputs(spec, shape, rng):
+    n = int(np.prod(shape))
+    return [jnp.asarray(0.05 * rng.normal(size=(fs.ncomp, n)) + 1 / 19.,
+                        jnp.float32) for fs in spec.fields]
+
+
+def _pads_outside_kernel(jaxpr) -> int:
+    """Equations of a launch's jaxpr that can build a padded copy (``pad``,
+    or the ``concatenate`` that ``jnp.pad(mode="wrap")`` becomes), not
+    counting the Pallas kernel body."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("pad", "concatenate"):
+            n += 1
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _pads_outside_kernel(sub)
+    return n
+
+
+class TestPeriodicInKernel:
+    """``pallas_windowed`` wraps periodic dimensions inside the kernel:
+    x through the window's BlockSpec index modulo X, y and z by rotating
+    the loaded planes.  Pure data movement, so a launch is bit-identical
+    to the same launch handed wrap-filled ghost planes in every
+    dimension (nothing wrapped in-kernel)."""
+
+    CASES = {
+        # name: (spec, shape, in-kernel-wrapping launch halo, tuning, layout)
+        "3d-r1": (lbst.STREAM_SPEC, (5, 4, 6), (0, 0, 0), {}, "soa"),
+        "3d-r2-fused": (lbst.FUSED_SPEC, (4, 4, 4), (0, 0, 0), {}, "soa"),
+        "pencil-r1": (lbst.STREAM_SPEC, (5, 4, 6), (1, 2, 0), {}, "soa"),
+        "pencil-r2-fused": (lbst.FUSED_SPEC, (4, 4, 4), (2, 3, 0), {},
+                            "soa"),
+        "2d": (_BOX2D_SPEC, (6, 5), (0, 0), {}, "soa"),
+        "plane-block-1": (lbst.GRAD6_SPEC, (7, 4, 5), (0, 0, 0),
+                          {"plane_block": 1}, "soa"),
+        "plane-block-3-of-7": (lbst.STREAM_SPEC, (7, 4, 5), (0, 0, 0),
+                               {"plane_block": 3}, "soa"),
+        "thinnest-r2": (lbst.FUSED_SPEC, (2, 2, 2), (0, 0, 0), {}, "soa"),
+        "aosoa": (lbst.STREAM_SPEC, (4, 4, 8), (0, 0, 0), {}, "aosoa"),
+    }
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_bit_identical_to_caller_ghosts(self, rng, case):
+        spec, shape, halo, tuning, layout = self.CASES[case]
+        lat = Lattice(shape)
+        target = tdp.Target("pallas_windowed", interpret=True,
+                            layout=layout, vvl=16, tuning=tuning)
+        consts = _FUSED_CONSTS if spec.consts else {}
+        r = spec.max_radius_per_dim()
+        xs = _launch_inputs(spec, shape, rng)
+
+        def launch(h):
+            args = [_with_ghosts(x, shape, h) if fs.stencil is not None
+                    else x for x, fs in zip(xs, spec.fields)]
+            out = tdp.launch(spec, target, *args, lattice=lat,
+                             halo=h if any(h) else None, consts=consts)
+            return out if isinstance(out, tuple) else (out,)
+
+        wraps = tdp.launch_plan(spec, target, lattice=lat,
+                                halo=halo if any(halo) else None)
+        assert all(w == tuple(d for d, h in enumerate(halo) if h == 0)
+                   for w, fs in zip(wraps.wrap_dims, spec.fields)
+                   if fs.stencil is not None)
+        got, want = launch(halo), launch(r)
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("halo", [(0, 0, 0), (1, 1, 0)])
+    def test_no_pad_outside_the_kernel(self, rng, halo):
+        """A periodic lattice reaches the kernel as a reshape, a pencil
+        as its ghost planes trimmed to the radius: no ``pad`` runs in
+        the launch outside the kernel (the wrap pad of
+        :func:`halo_extend` is gone)."""
+        shape = (4, 4, 6)
+        lat = Lattice(shape)
+        f = _with_ghosts(_launch_inputs(lbst.STREAM_SPEC, shape, rng)[0],
+                         shape, halo)
+        h = halo if any(halo) else None
+        jaxpr = jax.make_jaxpr(lambda x: tdp.launch(
+            lbst.STREAM_SPEC, WINDOWED, x, lattice=lat, halo=h))(f)
+        assert _pads_outside_kernel(jaxpr.jaxpr) == 0
+        # the padding prologue a plain halo_extended executor gets
+        # copies the field to wrap its periodic dims
+        jaxpr = jax.make_jaxpr(lambda x: halo_extend(
+            x, shape, halo, lbst.STENCIL_D3Q19_PULL))(f)
+        assert _pads_outside_kernel(jaxpr.jaxpr) > 0
+
+
 class TestHaloExtend:
     def test_periodic_matches_roll(self, rng):
         shape = (4, 5, 6)
@@ -156,6 +275,42 @@ class TestCapabilitySurface:
         assert tdp.executor_wants("xla") == "gathered"
         assert tdp.get_executor_entry("pallas_windowed").wants == \
             "halo_extended"
+        assert tdp.get_executor_entry("pallas_windowed").wraps_periodic
+        assert not tdp.get_executor_entry("xla").wraps_periodic
+
+    def test_wraps_periodic_needs_a_grid(self):
+        """Only a halo_extended executor receives a grid it could wrap."""
+        with pytest.raises(ValueError, match="wraps_periodic"):
+            tdp.register_executor("bad_wrap", lambda plan, g: g,
+                                  wraps_periodic=True)
+        assert "bad_wrap" not in tdp.list_executors()
+
+    @pytest.mark.parametrize("wraps", [False, True])
+    def test_prologue_honours_wraps_periodic(self, rng, wraps):
+        """The capability alone decides what the prologue hands over: a
+        wrap-padded grid, or the interior extent in every periodic dim
+        (ghost dims trimmed to the radius either way)."""
+        seen = []
+
+        def spy(plan, prepared):
+            seen.append((plan.wrap_dims, tuple(prepared[0].shape)))
+            return (jnp.zeros((1, 120), jnp.float32),)
+
+        tdp.register_executor("spy_windowed", spy, wants="halo_extended",
+                              wraps_periodic=wraps)
+        try:
+            shape, halo = (4, 5, 6), (2, 0, 0)      # ghosts in x only
+            x = jnp.ones((1, 8 * 5 * 6), jnp.float32)
+            spec = tdp.KernelSpec(
+                lambda p: p[0], out=1, name="star",
+                fields=(tdp.field(1, stencil=STENCIL_GRAD_6PT),))
+            tdp.launch(spec, tdp.Target("spy_windowed"), x,
+                       lattice=Lattice(shape), halo=halo)
+        finally:
+            tdp.unregister_executor("spy_windowed")
+        want = (((1, 2),), (1, 6, 5, 6)) if wraps else (((),),
+                                                         (1, 6, 7, 8))
+        assert seen == [want]
 
     def test_windowed_interpret_spelling_canonicalises(self):
         t = tdp.Target("pallas_windowed_interpret")
@@ -296,10 +451,11 @@ class TestMemoryEstimates:
         # window depth grows p + 2r: 3 planes → 6 planes of input
         assert w4.vmem_bytes_estimate() > w1.vmem_bytes_estimate()
         # Each block is padded to Mosaic's (8, 128) f32 tile and counted
-        # three times (two pipeline buffers + the loaded value): an
-        # 18×18 extended plane of 19 components is 19·24·128 words, the
-        # p·256-site output block 24·(p·256) words.
-        plane, out1 = 19 * 24 * 128 * 4, 24 * 256 * 4
+        # three times (two pipeline buffers + the loaded value): a
+        # periodic 16×16 plane of 19 components, wrapped in-kernel with
+        # no ghosts, is 19·16·128 words, the p·256-site output block
+        # 24·(p·256) words.
+        plane, out1 = 19 * 16 * 128 * 4, 24 * 256 * 4
         assert w1.window_blocks() == [(0, 3 * plane), (-1, out1)]
         assert w1.vmem_bytes_estimate() == 3 * (3 * plane + out1)
         # above plane_block=1 the p rows of all 19 offsets are also
@@ -307,6 +463,32 @@ class TestMemoryEstimates:
         chunk4 = 19 * 24 * (4 * 256) * 4
         assert w4.vmem_bytes_estimate() == \
             3 * (6 * plane + 4 * out1) + chunk4
+
+    def test_wrapped_dims_are_counted_unpadded(self):
+        """A dim wrapped in-kernel carries no ghost layers in the
+        estimates; a dim with caller ghosts carries the stencil radius
+        of them (128³ fused step: f radius 1, g radius 2)."""
+        lat = Lattice((128, 128, 128))
+        n = lat.nsites
+        target = tdp.Target("pallas_windowed")
+        one = launch_plan(lbst.FUSED_SPEC, target, lattice=lat)
+        assert one.wrap_dims == ((0, 1, 2), (0, 1, 2))
+        # operands are the caller's arrays, reshaped: f, g in, f', g' out
+        assert one.hbm_bytes_estimate() == 4 * NVEL * n * 4
+        pencil = launch_plan(lbst.FUSED_SPEC, target, lattice=lat,
+                             halo=(2, 2, 0))
+        assert pencil.wrap_dims == ((2,), (2,))
+        assert pencil.hbm_bytes_estimate() == 4 * NVEL * (
+            2 * n + 130 * 130 * 128 + 132 * 132 * 128)
+        # window planes: (19, 1, 128, 128) periodic, (19, 1, 130|132,
+        # 128) on the pencil (rows tiled to 136); the kernel's output
+        # blocks are (19, 128²), rows tiled to 24
+        tile, out = 19 * 128 * 128 * 4, 24 * 128 * 128 * 4
+        assert one.window_blocks() == [(0, 3 * tile), (1, 5 * tile),
+                                       (-1, out), (-1, out)]
+        assert pencil.window_blocks() == [
+            (0, 3 * 19 * 136 * 128 * 4), (1, 5 * 19 * 136 * 128 * 4),
+            (-1, out), (-1, out)]
 
     def test_estimates_need_geometry(self):
         plan = launch_plan(tdp.KernelSpec(lambda x: x,
