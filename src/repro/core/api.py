@@ -36,6 +36,7 @@ stays importable without Pallas).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Mapping, Sequence
 
 import jax
@@ -249,11 +250,17 @@ class LaunchPlan:
     wraps in-kernel (those with ``halo[d] == 0`` under a
     ``wraps_periodic`` executor; ``()`` otherwise): such a stencil field
     arrives at its interior extent there, with no ghost layers.
+
+    ``y_block`` is ``None`` when one grid step's window spans the whole
+    y–z plane, or the number of y rows an output block spans when that
+    window does not fit the VMEM limit (a ``blocks_y`` executor, 3-D
+    SoA launches; see :func:`_y_block_for`).  Each block then reads
+    :meth:`y_halo_rows` more rows on either side.
     """
 
     __slots__ = ("kernel", "name", "vvl", "out_ncomp", "consts",
                  "with_site_index", "interpret", "target", "shape", "halo",
-                 "stencils", "field_ncomp", "wants", "wrap_dims")
+                 "stencils", "field_ncomp", "wants", "wrap_dims", "y_block")
 
     def __init__(self, *, kernel, name, vvl, out_ncomp, consts,
                  with_site_index, interpret, target, shape=None, halo=None,
@@ -276,6 +283,7 @@ class LaunchPlan:
         self.wrap_dims = (tuple(tuple(w) for w in wrap_dims)
                           if wrap_dims is not None
                           else ((),) * len(self.field_ncomp or ()))
+        self.y_block = None
 
     @property
     def layout(self) -> str:
@@ -287,15 +295,24 @@ class LaunchPlan:
         """
         return self.target.layout
 
+    def _replace(self, **changes) -> "LaunchPlan":
+        p = LaunchPlan.__new__(LaunchPlan)
+        for s in LaunchPlan.__slots__:
+            setattr(p, s, changes.get(s, getattr(self, s)))
+        return p
+
     def with_consts(self, consts: Mapping[str, object]) -> "LaunchPlan":
         """Shallow copy with ``consts`` replaced — the per-call plan the
         dynamic-const path hands to the executor (same kernel, geometry
         and tuning; traced const values merged in)."""
-        p = LaunchPlan.__new__(LaunchPlan)
-        for s in LaunchPlan.__slots__:
-            setattr(p, s, getattr(self, s))
-        p.consts = dict(consts)
-        return p
+        return self._replace(consts=dict(consts))
+
+    @staticmethod
+    def y_halo_rows(stencil) -> int:
+        """Rows a y-blocked window reads beyond its block on either side:
+        the stencil's y radius rounded up to Mosaic's 8-row sublane tile
+        (0 for a stencil that does not reach along y)."""
+        return -(-stencil.radius_per_dim()[1] // 8) * 8
 
     # -- memory models ----------------------------------------------------
     #
@@ -321,13 +338,23 @@ class LaunchPlan:
         return tuple(s if d in wrap else s + 2 * rd
                      for d, (s, rd) in enumerate(zip(self.shape, r)))
 
+    def _block_sites(self) -> int:
+        """Sites of one x-plane that one grid step computes: the whole
+        y–z plane, or ``y_block`` rows of it."""
+        rest = tuple(self.shape[1:])
+        if self.y_block is None:
+            return _prod_shape(rest)
+        return self.y_block * _prod_shape(rest[1:])
+
     def vmem_bytes_estimate(self, itemsize: int = 4) -> int:
         """Fast-memory footprint of one grid step (inputs + outputs).
 
         ``"gathered"`` executors hold ``noffsets_i · ncomp_i · VVL`` input
         rows per stencil field; ``"halo_extended"`` ones hold a
         ``(plane_block + 2·radius)``-plane window of the extended array —
-        no ``noffsets`` factor (docs/stencil.md, "VMEM footprint rule").
+        no ``noffsets`` factor — cut to ``y_block + 2·y_halo_rows`` rows
+        when the plan is y-blocked (docs/stencil.md, "VMEM footprint
+        rule").
         """
         if self.wants != "halo_extended":
             out_rows = sum(self.out_ncomp)
@@ -339,7 +366,7 @@ class LaunchPlan:
         if p > 1:
             # the p rows of every offset are concatenated into a
             # (noffsets, ncomp, p·V) chunk the compiler materialises
-            v = p * _prod_shape(self.shape[1:])
+            v = p * self._block_sites()
             total += sum(_tiled_bytes((s.noffsets, c, v), itemsize)
                          for c, s, _ in self._fields() if s is not None)
         return total
@@ -351,6 +378,9 @@ class LaunchPlan:
         with its two minor dims padded to Mosaic's ``(8·4/itemsize, 128)``
         tile.  A window plane spans the prepared extent: interior in the
         wrapped dims, interior plus the radius on both sides elsewhere.
+        A y-blocked plan's window plane spans ``y_block`` rows plus
+        :meth:`y_halo_rows` on either side, and its pointwise and output
+        blocks ``y_block`` rows.
 
         :meth:`vmem_bytes_estimate` counts each block three times: the
         pipeline's two buffers (the next step's DMA overlaps this step's
@@ -367,7 +397,10 @@ class LaunchPlan:
         if self.shape is None:
             raise ValueError("halo_extended estimates need a lattice shape")
         p = int(self.target.tune("plane_block", 1))
+        yb = self.y_block
         rest = tuple(self.shape[1:])
+        if yb is not None:
+            rest = (yb, *rest[1:])
         rest_n = _prod_shape(rest)
         aosoa = self.layout == "aosoa"
         vvl = int(self.vvl)
@@ -379,6 +412,8 @@ class LaunchPlan:
                 blocks.append((i, _tiled_bytes(shape, itemsize)))
                 continue
             ext_rest = self._ext_shape(s, wrap)[1:]
+            if yb is not None:
+                ext_rest = (yb + 2 * self.y_halo_rows(s), *ext_rest[1:])
             shape = ((1, -(-_prod_shape(ext_rest) // vvl), c, vvl) if aosoa
                      else (c, 1, *ext_rest))
             window = p + 2 * s.radius_per_dim()[0]
@@ -397,7 +432,10 @@ class LaunchPlan:
         only the ghost-layer overhead ``prod(shape + 2·radius) /
         prod(shape)`` — independent of ``noffsets`` — and none in the
         dims it wraps in-kernel (``wrap_dims``): on a fully periodic
-        lattice its operands are the caller's arrays, reshaped.
+        lattice its operands are the caller's arrays, reshaped.  A
+        y-blocked plan counts each stencil field over the rows its blocks
+        read, halo rows included (``y_halo_rows`` of them re-read on
+        either side of every block).
 
         ``layout="aosoa"`` doubles the estimate: the SoA↔AoSoA boundary
         transforms re-materialise every prepared operand and output once
@@ -412,7 +450,13 @@ class LaunchPlan:
             if s is None:
                 total += c * n
             elif self.wants == "halo_extended":
-                total += c * _prod_shape(self._ext_shape(s, wrap))
+                ext = self._ext_shape(s, wrap)
+                if self.y_block is not None:
+                    nyb = -(-self.shape[1] // self.y_block)
+                    ext = (ext[0], nyb * (self.y_block
+                                          + 2 * self.y_halo_rows(s)),
+                           *ext[2:])
+                total += c * _prod_shape(ext)
             else:
                 total += c * s.noffsets * n
         if self.layout == "aosoa":
@@ -422,7 +466,8 @@ class LaunchPlan:
     def __repr__(self):
         return (f"LaunchPlan({self.name!r}, executor={self.target.executor!r}"
                 f", vvl={self.vvl}, out={self.out_ncomp}, "
-                f"wants={self.wants!r}, wrap_dims={self.wrap_dims})")
+                f"wants={self.wants!r}, wrap_dims={self.wrap_dims}, "
+                f"y_block={self.y_block})")
 
 
 # ---------------------------------------------------------------------------
@@ -536,33 +581,65 @@ class WindowVmemError(ValueError):
     """A ``pallas_windowed`` launch whose VMEM window cannot fit.
 
     Raised at plan-build time (before any tracing) when
-    :meth:`LaunchPlan.vmem_bytes_estimate` exceeds the fast-memory cap:
-    the ``plane_block + 2·radius`` slab of some field is too large for
-    one grid step.  The message names the worst field, its window bytes,
-    and the cap.  ``tdp.autotune`` *prunes* candidates that raise this
-    (the base target excepted — an unrunnable base is a caller error);
-    shrinking ``plane_block`` or the y/z extents is the fix (y/z window
-    blocking is a carried follow-up, see ROADMAP).
+    :meth:`LaunchPlan.vmem_bytes_estimate` exceeds the fast-memory cap
+    even at the smallest window a plan can be built with: the
+    smallest y-block (the 8-row tile of
+    :meth:`LaunchPlan.y_halo_rows`), or the whole y–z plane where the
+    launch cannot be blocked (2-D, ``layout="aosoa"``, or a y extent of
+    one tile).  The message names that block, the worst field, its
+    window bytes, and the cap.  ``tdp.autotune`` *prunes* candidates
+    that raise this (the base target excepted — an unrunnable base is a
+    caller error); shrinking ``plane_block`` or the z extent is the fix.
     """
 
 
-def _check_window_vmem(plan: "LaunchPlan", spec: KernelSpec) -> None:
-    """Refuse to build a windowed launch whose VMEM window exceeds the
-    limit the compiler is given (:func:`costmodel.vmem_limit_bytes`)
+def _y_block_for(plan: "LaunchPlan", cap: int) -> int | None:
+    """The y-block of a windowed launch under VMEM limit ``cap``.
+
+    ``None`` (the whole y–z plane) when that window fits or the launch
+    cannot be blocked; else the largest multiple of the 8-row tile (and
+    of every field's :meth:`~LaunchPlan.y_halo_rows`) that divides Y and
+    fits, or, when no divisor fits, the largest such multiple below Y
+    that does (the last block then runs past Y).  When nothing fits, the
+    smallest block, which the plan-build guard refuses by name."""
+    shape = plan.shape
+    if (shape is None or len(shape) != 3 or plan.layout != "soa"
+            or plan.vmem_bytes_estimate() <= cap):
+        return None
+    unit = math.lcm(8, *(LaunchPlan.y_halo_rows(s)
+                         for s in plan.stencils if s is not None))
+    sizes = list(range((shape[1] - 1) // unit * unit, 0, -unit))
+    if not sizes:
+        return None
+    for yb in [b for b in sizes if shape[1] % b == 0] + sizes:
+        if plan._replace(y_block=yb).vmem_bytes_estimate() <= cap:
+            return yb
+    return sizes[-1]
+
+
+def _check_window_vmem(plan: "LaunchPlan", spec: KernelSpec,
+                       cap: int) -> None:
+    """Refuse to build a windowed launch whose VMEM window exceeds
+    ``cap``, the limit the compiler is given
+    (:func:`costmodel.vmem_limit_bytes`), even at the plan's y-block,
     instead of letting Mosaic fail deep inside the jitted launch."""
-    cap = vmem_limit_bytes(plan.interpret)
     total = plan.vmem_bytes_estimate()
     if total <= cap:
         return
     p = int(plan.target.tune("plane_block", 1))
     i, worst_bytes = max(plan.window_blocks(), key=lambda ib: ib[1])
     worst_label = "<output>" if i < 0 else spec.fields[i].label(i)
+    if plan.y_block is None:
+        window, fix = f"plane_block={p}", "plane_block or the y/z extents"
+    else:
+        window = f"plane_block={p}, y_block={plan.y_block} (smallest)"
+        fix = "plane_block or the z extent"
     raise WindowVmemError(
         f"kernel {plan.name!r} under executor "
-        f"{plan.target.executor!r}: the plane_block={p} window needs an "
+        f"{plan.target.executor!r}: the {window} window needs an "
         f"estimated {total} bytes of VMEM (> cap {cap}); largest block "
         f"is {worst_label} at {worst_bytes} bytes per buffer — shrink "
-        f"plane_block or the y/z extents")
+        f"{fix}")
 
 
 class WindowShapeError(ValueError):
@@ -630,10 +707,10 @@ def _validate_layout(spec: KernelSpec, target: Target,
 def _make_plan(spec: KernelSpec, target: Target, vvl: int,
                out_ncomp: tuple[int, ...], lattice: Lattice | None,
                halo: tuple[int, ...] | None, consts: dict,
-               entry: ExecutorEntry) -> LaunchPlan:
+               entry: ExecutorEntry, vmem_limit: int | None) -> LaunchPlan:
     periodic = (tuple(d for d, h in enumerate(halo) if h == 0)
                 if entry.wraps_periodic and halo is not None else ())
-    return LaunchPlan(
+    plan = LaunchPlan(
         kernel=spec.fn, name=spec.name, vvl=vvl, out_ncomp=out_ncomp,
         consts=consts, with_site_index=spec.site_index,
         interpret=target.interpret, target=target,
@@ -644,21 +721,26 @@ def _make_plan(spec: KernelSpec, target: Target, vvl: int,
         wants=entry.wants,
         wrap_dims=tuple(periodic if fs.stencil is not None else ()
                         for fs in spec.fields))
+    if entry.blocks_y and lattice is not None:
+        if vmem_limit is None:
+            vmem_limit = vmem_limit_bytes(target.interpret)
+        plan.y_block = _y_block_for(plan, vmem_limit)
+    return plan
 
 
 @functools.lru_cache(maxsize=4096)
 def _build_plan(spec: KernelSpec, target: Target, vvl: int,
                 out_ncomp: tuple[int, ...], lattice: Lattice | None,
                 halo: tuple[int, ...] | None, const_key, dyn_sig,
-                _registry_version):
+                _registry_version, vmem_limit: int | None):
     consts = _unwrap_consts(dict(const_key))
     dyn_names = tuple(k for k, _, _ in dyn_sig)
     entry = get_executor_entry(target.executor)
     executor = entry.fn
     plan = _make_plan(spec, target, vvl, out_ncomp, lattice, halo, consts,
-                      entry)
+                      entry, vmem_limit)
     if entry.wants == "halo_extended":
-        _check_window_vmem(plan, spec)
+        _check_window_vmem(plan, spec, vmem_limit)
     stencils = spec.stencils
     shape = lattice.shape if lattice is not None else None
     n_out = len(out_ncomp)
@@ -766,23 +848,31 @@ def launch(spec: KernelSpec, target: Target | str | None = None, /,
     dyn_names = tuple(sorted(dyn_consts))
     dyn_sig = tuple((k, tuple(int(s) for s in dyn_consts[k].shape),
                      str(dyn_consts[k].dtype)) for k in dyn_names)
+    # The windowed geometry depends on the device's VMEM limit, so the
+    # limit is part of the plan's cache key.
+    cap = (vmem_limit_bytes(tgt.interpret)
+           if entry.wants == "halo_extended" else None)
     fn = _build_plan(spec, tgt, vvl, out_ncomp, lattice, h, key, dyn_sig,
-                     registry_version())
+                     registry_version(), cap)
     return fn(*arrays, *(dyn_consts[k] for k in dyn_names))
 
 
 def launch_plan(spec: KernelSpec, target: Target | str | None = None, *,
                 lattice: Lattice | None = None,
                 halo: int | Sequence[int] | None = None,
-                consts: Mapping[str, object] | None = None) -> LaunchPlan:
+                consts: Mapping[str, object] | None = None,
+                vmem_limit: int | None = None) -> LaunchPlan:
     """Build (without compiling or launching) the :class:`LaunchPlan` a
     launch of ``spec`` under ``target`` would dispatch with — the
     introspection surface for the :meth:`LaunchPlan.vmem_bytes_estimate`
     and :meth:`LaunchPlan.hbm_bytes_estimate` memory models.
 
     Mirrors :func:`launch`'s resolution (executor capability, VVL,
-    normalised halo) but takes no arrays; geometry checks that need them
-    are skipped.
+    normalised halo, the y-block chosen against the VMEM limit) but
+    takes no arrays; geometry checks that need them are skipped, and so
+    is the VMEM guard.  ``vmem_limit`` replaces the device's limit
+    (:func:`costmodel.vmem_limit_bytes`) in the choice of the y-block:
+    the plan a device with that limit would run.
     """
     if not isinstance(spec, KernelSpec):
         raise TypeError(f"launch_plan expects a KernelSpec, got "
@@ -815,7 +905,7 @@ def launch_plan(spec: KernelSpec, target: Target | str | None = None, *,
             f"launch_plan cannot build a faithful plan")
     return _make_plan(spec, tgt, tgt.resolve_vvl(), tuple(out_ncomp),
                       lattice, h, _unwrap_consts(dict(consts or {})),
-                      entry)
+                      entry, vmem_limit)
 
 
 # ---------------------------------------------------------------------------
@@ -892,4 +982,4 @@ register_executor("pallas_interpret", _pallas_executor,
                   tunables=_PALLAS_TUNABLES)
 register_executor("pallas_windowed", _pallas_windowed_executor,
                   wants="halo_extended", tunables=("plane_block",),
-                  wraps_periodic=True)
+                  wraps_periodic=True, blocks_y=True)

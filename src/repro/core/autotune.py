@@ -271,7 +271,10 @@ def plane_block_candidates(spec: KernelSpec, target: Target | str | None,
     ``vmem_limit`` (default: :func:`~repro.core.costmodel.vmem_limit_bytes`
     for the target, the cap the compiler is given).  Divisors, not every
     integer: the executor pads the grid to a ``plane_block`` multiple, so
-    non-divisors waste whole padded planes per step.
+    non-divisors waste whole padded planes per step.  Each estimate is of
+    the plan a launch would build: y-blocked
+    (:attr:`~repro.core.api.LaunchPlan.y_block`) where the whole-plane
+    window exceeds the device's limit.
 
     Returns ``(feasible, pruned)`` — ``feasible`` the surviving
     ``plane_block`` values, ``pruned`` a list of ``(value, reason)``.

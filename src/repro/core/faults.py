@@ -96,7 +96,8 @@ def register_failing_executor(name: str, *, base: str = "xla",
     handle = _FailingExecutor(name, entry.fn, fail_on, times)
     register_executor(name, handle, overwrite=True, wants=entry.wants,
                       tunables=entry.tunables,
-                      wraps_periodic=entry.wraps_periodic)
+                      wraps_periodic=entry.wraps_periodic,
+                      blocks_y=entry.blocks_y)
     return handle
 
 

@@ -1139,9 +1139,11 @@ class ProgramPlan:
 
     def per_stage(self, itemsize: int = 4) -> list[dict]:
         """One row per stage — the stage table (executor, capability,
-        the dims each field wraps in-kernel, memory models)."""
+        the dims each field wraps in-kernel, the y-block, memory
+        models)."""
         return [{"stage": name, "executor": p.target.executor,
                  "wants": p.wants, "wrap_dims": p.wrap_dims,
+                 "y_block": p.y_block,
                  "hbm_bytes_estimate": p.hbm_bytes_estimate(itemsize),
                  "vmem_bytes_estimate": p.vmem_bytes_estimate(itemsize)}
                 for name, p in self.stages]

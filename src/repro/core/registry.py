@@ -24,6 +24,9 @@ an executor only maps a prepared plan over prepared site arrays:
         #               With wraps_periodic=True the periodic dims are
         #               not padded either: they arrive at their interior
         #               extent and the executor wraps them itself.
+        #               With blocks_y=True the plan may also carry a
+        #               y_block: the executor then windows y in blocks
+        #               of that many rows (LaunchPlan.y_block).
         # returns:  tuple of (ncomp_o, nsites) outputs, one per
         #           plan.out_ncomp entry (a bare array is accepted for
         #           single-output kernels)
@@ -53,13 +56,15 @@ EXECUTOR_WANTS = ("gathered", "halo_extended")
 class ExecutorEntry(NamedTuple):
     """One registry row: the executor callable plus its declared input
     capability (see ``EXECUTOR_WANTS``), the ``Target.tuning`` keys it
-    consults (``tunables`` — the sweep/autotune surface) and whether it
-    wraps periodic dimensions itself (``wraps_periodic``)."""
+    consults (``tunables`` — the sweep/autotune surface), whether it
+    wraps periodic dimensions itself (``wraps_periodic``) and whether it
+    runs plans whose window is blocked in y (``blocks_y``)."""
 
     fn: Callable
     wants: str
     tunables: tuple[str, ...] = ()
     wraps_periodic: bool = False
+    blocks_y: bool = False
 
 
 _EXECUTORS: dict[str, ExecutorEntry] = {}
@@ -69,7 +74,8 @@ _VERSION = 0
 def register_executor(name: str, fn: Callable, *, overwrite: bool = False,
                       wants: str = "gathered",
                       tunables: tuple[str, ...] = (),
-                      wraps_periodic: bool = False) -> None:
+                      wraps_periodic: bool = False,
+                      blocks_y: bool = False) -> None:
     """Register ``fn`` as the executor behind ``Target(backend=name)``.
 
     ``wants`` declares the input capability: ``"gathered"`` (default)
@@ -92,6 +98,13 @@ def register_executor(name: str, fn: Callable, *, overwrite: bool = False,
     contract (ghost planes trimmed to the stencil radius).  The plan
     records the wrapped dimensions per field (``LaunchPlan.wrap_dims``).
 
+    ``blocks_y`` (``"halo_extended"`` executors only) declares that the
+    executor runs plans whose y-z window is cut into blocks of
+    ``LaunchPlan.y_block`` rows: plan build then picks the block
+    from the VMEM estimate against the device's limit whenever the
+    whole-plane window does not fit.  Without it ``y_block`` stays
+    ``None`` and such a launch is refused (``WindowVmemError``).
+
     Raises ``ValueError`` on duplicate names unless ``overwrite=True``.
     """
     global _VERSION
@@ -107,13 +120,17 @@ def register_executor(name: str, fn: Callable, *, overwrite: bool = False,
         raise ValueError(f"wraps_periodic needs wants='halo_extended' (a "
                          f"gathered executor receives no grid to wrap), "
                          f"got wants={wants!r}")
+    if blocks_y and wants != "halo_extended":
+        raise ValueError(f"blocks_y needs wants='halo_extended' (a "
+                         f"gathered executor has no window to block), "
+                         f"got wants={wants!r}")
     tunables = tuple(str(t) for t in tunables)
     if name in _EXECUTORS and not overwrite:
         raise ValueError(
             f"executor {name!r} is already registered; pass overwrite=True "
             f"to replace it")
     _EXECUTORS[name] = ExecutorEntry(fn, wants, tunables,
-                                     bool(wraps_periodic))
+                                     bool(wraps_periodic), bool(blocks_y))
     _VERSION += 1
 
 

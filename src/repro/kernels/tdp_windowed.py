@@ -43,7 +43,8 @@ Memory model (vs the gathered path, per ``LaunchPlan`` estimates):
   HBM   Σ_i ncomp_i · prod(ext_d),  ext_d = shape_d (periodic) or
         shape_d + 2r_d (ghosts)          [was noffsets_i × interior]
   VMEM  3 × Σ_i ncomp_i · (plane_block + 2r₀) · prod(ext_rest)   per grid
-        step, each plane padded to the (8, 128) tile
+        step, each plane padded to the (8, 128) tile, ext_rest's y cut
+        to y_block + 2h in a y-blocked plan
         (:meth:`~repro.core.api.LaunchPlan.window_blocks`)
 
 — the ``noffsets×`` term is gone from both, and on a periodic lattice the
@@ -53,6 +54,14 @@ planes keep their unpadded (e.g. 128-lane) extent.  Compiled
 lower — ``layout="aosoa"``, or a minor lattice extent that is not a
 multiple of 128 lanes — are refused at plan build
 (:class:`~repro.core.api.WindowShapeError`).
+
+When that window does not fit the VMEM limit, the plan carries a
+``y_block`` (:func:`repro.core.api._y_block_for`) and the launch runs a
+second grid axis over y-blocks (:func:`_y_blocked_execute`): each window
+slot then loads ``y_block`` rows plus ``h`` halo rows on either side, the
+halo blocks' index wrapping modulo Y as x wraps in its slot index, and the
+kernel is named ``..._p<plane_block>_yb<y_block>_<layout>``.  A plan
+whose window fits keeps the one-axis grid below.
 
 Tuning (``Target.tuning``): ``plane_block`` — output x-planes per grid
 step (TLP chunk; window depth is ``plane_block + 2r₀``).  Default 1.
@@ -114,6 +123,8 @@ def windowed_execute(plan, extended):
     p = int(plan.target.tune("plane_block", 1))
     if p <= 0:
         raise ValueError(f"plane_block must be positive, got {p}")
+    if plan.y_block is not None:
+        return _y_blocked_execute(plan, extended, p)
     X, rest = shape[0], tuple(shape[1:])
     rest_n = _prod(rest)
     nwin = -(-X // p)
@@ -144,14 +155,7 @@ def windowed_execute(plan, extended):
                     lambda i: (0, i, *([0] * (ndim - 1)))))
             field_meta.append(("pointwise", ncomp, None, None, None, None))
         else:
-            r = s.radius_per_dim()
-            ext = tuple(sd if d in wrap else sd + 2 * rd
-                        for d, (sd, rd) in enumerate(zip(shape, r)))
-            if x.shape[1:] != ext:
-                raise ValueError(
-                    f"stencil field of kernel {plan.name!r} is not "
-                    f"prepared to radius {r} with dims {wrap} wrapped "
-                    f"in-kernel: got {tuple(x.shape[1:])}, want {ext}")
+            r, ext = _prepared_extent(plan, x, s, wrap)
             x_wraps = 0 in wrap
             if x_pad and not x_wraps:
                 x = jnp.pad(x, [(0, 0), (0, x_pad)]
@@ -168,20 +172,12 @@ def windowed_execute(plan, extended):
                     xf = jnp.pad(xf, [(0, 0), (0, 0), (0, pad)])
                 x = plane_to_aosoa(xf, vvl)  # (Xext, nblk_e, ncomp, vvl)
                 nblk_e = int(x.shape[1])
-            # One depth-1 plane ref per window slot: operand j of this
-            # field is blocked at x-plane i·p + j of the ghost-extended
-            # array, or, with x periodic, at plane i·p + j − r₀ modulo X
-            # of the interior one (kept non-negative: scalar rem
-            # truncates).  All window operands alias one HBM value — the
-            # only copies are the per-step HBM→VMEM window loads.
+            # One depth-1 plane ref per window slot, all aliasing one HBM
+            # value — the only copies are the per-step HBM→VMEM window
+            # loads.
             for j in range(window):
                 operands.append(x)
-                if x_wraps:
-                    def xplane(i, j=j, r0=r[0]):
-                        return jax.lax.rem(i * p + (j - r0 + X), X)
-                else:
-                    def xplane(i, j=j):
-                        return i * p + j
+                xplane = _x_plane(j, p, r[0], X, x_wraps)
                 if aosoa:
                     in_specs.append(pl.BlockSpec(
                         (1, nblk_e, ncomp, vvl),
@@ -193,10 +189,9 @@ def windowed_execute(plan, extended):
                                                   *([0] * (ndim - 1)))))
             field_meta.append(("stencil", ncomp, s, r, ext, wrap))
 
-    scalar_consts, array_consts = _canonicalize_consts(plan.consts)
-    const_names = list(array_consts)
-    const_vals = [array_consts[k][1] for k in const_names]
-    in_specs += [pl.BlockSpec(c.shape, lambda i: (0, 0)) for c in const_vals]
+    consts = _Consts(plan)
+    in_specs += [pl.BlockSpec(c.shape, lambda i: (0, 0))
+                 for c in consts.vals]
 
     out_ncomp = tuple(plan.out_ncomp)
     # Outputs are written flat, (ncomp, p·V) lane-dense blocks: Mosaic
@@ -210,8 +205,8 @@ def windowed_execute(plan, extended):
     def body(*refs):
         it = iter(refs[:len(operands)])
         cref0 = len(operands)
-        const_refs = refs[cref0:cref0 + len(const_names)]
-        out_refs = refs[cref0 + len(const_names):]
+        const_refs = refs[cref0:cref0 + len(consts.names)]
+        out_refs = refs[cref0 + len(consts.names):]
 
         def unpack_plane(blk, ncomp, rest_shape):
             # (nplanes, nblk, ncomp, vvl) AoSoA tile → SoA planes
@@ -277,28 +272,8 @@ def windowed_execute(plan, extended):
         if plan.with_site_index:
             base = pl.program_id(0) * chunk
             chunks.append(base + jax.lax.iota(jnp.int32, chunk))
-        kw = dict(scalar_consts)
-        for cname, cref in zip(const_names, const_refs):
-            orig_shape, _ = array_consts[cname]
-            kw[cname] = cref[...].reshape(orig_shape)
-        if plan.interpret:
-            # XLA's CPU backend contracts multiply-adds differently
-            # depending on which data movement it fuses into the site
-            # arithmetic; behind a barrier the arithmetic compiles the
-            # same whether its chunk came from slices or rotations.
-            chunks = jax.lax.optimization_barrier(chunks)
-        vals = plan.kernel(*chunks, **kw)
-        vals = (vals,) if not isinstance(vals, tuple) else vals
-        for ref, v in zip(out_refs, vals):
-            ref[...] = v.astype(ref.dtype)
+        _site_kernel(plan, chunks, consts, const_refs, out_refs)
 
-    # Mosaic's default scoped-VMEM limit (16 MiB) is far below what a
-    # 128² window needs; give the kernel the device's limit, the same
-    # cap the plan-build guard held the window estimate to.
-    compiler_params = None
-    if not plan.interpret:
-        compiler_params = pltpu.CompilerParams(
-            vmem_limit_bytes=vmem_limit_bytes(interpret=False))
     outs = pl.pallas_call(
         body,
         grid=(nwin,),
@@ -306,9 +281,261 @@ def windowed_execute(plan, extended):
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=plan.interpret,
-        compiler_params=compiler_params,
+        compiler_params=_compiler_params(plan),
         name=f"tdp_windowed_{plan.name}_p{p}_{plan.layout}",
-    )(*operands, *const_vals)
+    )(*operands, *consts.vals)
 
     n = X * rest_n
     return tuple(o[:, :n] for o in outs)
+
+
+def _prepared_extent(plan, x, s, wrap):
+    """The stencil radius of a field and the extent it must arrive at:
+    the interior in the dims of ``wrap``, plus the radius elsewhere."""
+    r = s.radius_per_dim()
+    ext = tuple(sd if d in wrap else sd + 2 * rd
+                for d, (sd, rd) in enumerate(zip(plan.shape, r)))
+    if x.shape[1:] != ext:
+        raise ValueError(
+            f"stencil field of kernel {plan.name!r} is not "
+            f"prepared to radius {r} with dims {wrap} wrapped "
+            f"in-kernel: got {tuple(x.shape[1:])}, want {ext}")
+    return r, ext
+
+
+def _x_plane(j, p, r0, X, wraps):
+    """The x-plane index of window slot ``j`` at grid step ``i``: plane
+    ``i·p + j`` of the ghost-extended array, or, with x periodic, plane
+    ``i·p + j − r₀`` modulo X of the interior one (kept non-negative:
+    scalar rem truncates)."""
+    if wraps:
+        return lambda i: jax.lax.rem(i * p + (j - r0 + X), X)
+    return lambda i: i * p + j
+
+
+class _Consts:
+    """A plan's TARGET_CONSTs: scalars closed over, arrays passed as
+    full-block operands (``names``/``vals``) and reshaped back in-kernel."""
+
+    def __init__(self, plan):
+        self.scalars, self.arrays = _canonicalize_consts(plan.consts)
+        self.names = list(self.arrays)
+        self.vals = [self.arrays[k][1] for k in self.names]
+
+    def kwargs(self, refs) -> dict:
+        kw = dict(self.scalars)
+        for cname, cref in zip(self.names, refs):
+            kw[cname] = cref[...].reshape(self.arrays[cname][0])
+        return kw
+
+
+def _site_kernel(plan, chunks, consts, const_refs, out_refs):
+    """Run the site kernel on the assembled chunks and store its outputs."""
+    kw = consts.kwargs(const_refs)
+    if plan.interpret:
+        # XLA's CPU backend contracts multiply-adds differently
+        # depending on which data movement it fuses into the site
+        # arithmetic; behind a barrier the arithmetic compiles the
+        # same whether its chunk came from slices or rotations.
+        chunks = jax.lax.optimization_barrier(chunks)
+    vals = plan.kernel(*chunks, **kw)
+    vals = (vals,) if not isinstance(vals, tuple) else vals
+    for ref, v in zip(out_refs, vals):
+        ref[...] = v.astype(ref.dtype)
+
+
+def _compiler_params(plan):
+    # Mosaic's default scoped-VMEM limit (16 MiB) is far below what a
+    # 128² window needs; give the kernel the device's limit, the same
+    # cap the plan-build guard held the window estimate to.
+    if plan.interpret:
+        return None
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=vmem_limit_bytes(interpret=False))
+
+
+def _y_rows(x, ry, h, Y, Yp, wrap_y):
+    """A stencil field's operand for y-blocked windows, and the number of
+    ``h``-row blocks along its y dim.
+
+    Block ``b`` reads its window as three blocks: the ``h`` rows before
+    its own, its own ``yb`` rows, and the ``h`` rows after, the halo
+    blocks indexed modulo the returned count.  A periodic y that the
+    blocks tile exactly (``Yp == Y``) is read as it is, wrapping in that
+    index.  Otherwise (caller ghosts in y, or a last block that runs past
+    Y to ``Yp``) the rows are laid out cyclically in one copy: the
+    interior, the ``ry`` rows that follow it (wrapped rows or the
+    caller's ghosts), zeros up to row ``Yp + h``, then ``h`` rows that end
+    in the ``ry`` rows preceding the interior.
+    """
+    if wrap_y and Yp == Y:
+        return x, Y // h
+    if wrap_y:
+        lo, body, hi = x[:, :, Y - ry:], x, x[:, :, :ry]
+    else:
+        lo, body, hi = x[:, :, :ry], x[:, :, ry:ry + Y], x[:, :, ry + Y:]
+
+    def zeros(n):
+        return jnp.zeros((*x.shape[:2], n, *x.shape[3:]), x.dtype)
+    rows = [body, hi, zeros(Yp + h - Y - ry), zeros(h - ry), lo]
+    return jnp.concatenate(rows, axis=2), Yp // h + 2
+
+
+def _y_blocked_execute(plan, extended, p):
+    """The windowed launch over a ``(x windows, y-blocks)`` grid, for
+    3-D SoA plans whose whole-plane window does not fit VMEM
+    (``plan.y_block`` rows per block; :func:`repro.core.api._y_block_for`).
+
+    Grid step ``(i, b)`` computes x-planes ``i·p … i·p + p − 1``, rows
+    ``b·yb … b·yb + yb − 1``.  Per x slot a stencil field loads ``yb``
+    rows plus ``h`` halo rows on either side (``h`` =
+    ``LaunchPlan.y_halo_rows``), as three blocks whose halo block indices
+    wrap modulo the y extent (:func:`_y_rows`), the way x wraps in its
+    slot index; a y offset is a rotation of that window followed by an
+    aligned slice of its ``yb`` middle rows.  z is resolved as in the
+    whole-plane window: a rotation when periodic, a slice of the caller's
+    ghosts otherwise.  Outputs are written as ``(ncomp, p·yb·Z)`` blocks
+    at flat block ``i·nyb + b`` and put back in lattice order after the
+    call (a reshape when ``p = 1`` and the blocks tile Y).
+    """
+    shape = plan.shape
+    if len(shape) != 3 or plan.layout != "soa":
+        raise ValueError(
+            f"kernel {plan.name!r}: a y-blocked window needs a 3-D lattice "
+            f"and layout='soa', got shape {shape}, layout {plan.layout!r}")
+    X, Y, Z = (int(d) for d in shape)
+    yb = int(plan.y_block)
+    nwin, nyb = -(-X // p), -(-Y // yb)
+    Yp = nyb * yb
+    x_pad = nwin * p - X
+    bs = yb * Z                       # sites of one x-plane in a block
+    chunk = p * bs
+    dtype = extended[0].dtype
+
+    operands, in_specs, field_meta = [], [], []
+    for x, s, wrap in zip(extended, plan.stencils, plan.wrap_dims):
+        ncomp = int(x.shape[0])
+        if s is None:
+            grid_x = x.reshape(ncomp, X, Y, Z)
+            if x_pad:
+                grid_x = jnp.pad(grid_x, [(0, 0), (0, x_pad), (0, 0), (0, 0)])
+            operands.append(grid_x)
+            in_specs.append(pl.BlockSpec((ncomp, p, yb, Z),
+                                         lambda i, b: (0, i, b, 0)))
+            field_meta.append(("pointwise", ncomp, None, None, None, None))
+            continue
+        r, ext = _prepared_extent(plan, x, s, wrap)
+        x_wraps = 0 in wrap
+        if x_pad and not x_wraps:
+            x = jnp.pad(x, [(0, 0), (0, x_pad), (0, 0), (0, 0)])
+        h = plan.y_halo_rows(s)
+        Ze = ext[2]
+        if h:
+            x, nh = _y_rows(x, r[1], h, Y, Yp, 1 in wrap)
+            k = yb // h
+        for j in range(p + 2 * r[0]):
+            xplane = _x_plane(j, p, r[0], X, x_wraps)
+            if h:
+                operands.append(x)
+                in_specs.append(pl.BlockSpec(
+                    (ncomp, 1, h, Ze),
+                    lambda i, b, xplane=xplane, k=k, nh=nh: (
+                        0, xplane(i), jax.lax.rem(b * k - 1 + nh, nh), 0)))
+            operands.append(x)
+            in_specs.append(pl.BlockSpec(
+                (ncomp, 1, yb, Ze),
+                lambda i, b, xplane=xplane: (0, xplane(i), b, 0)))
+            if h:
+                operands.append(x)
+                in_specs.append(pl.BlockSpec(
+                    (ncomp, 1, h, Ze),
+                    lambda i, b, xplane=xplane, k=k, nh=nh: (
+                        0, xplane(i), jax.lax.rem((b + 1) * k, nh), 0)))
+        field_meta.append(("stencil", ncomp, s, r, h, wrap))
+
+    consts = _Consts(plan)
+    in_specs += [pl.BlockSpec(c.shape, lambda i, b: (0, 0))
+                 for c in consts.vals]
+    out_specs = [pl.BlockSpec((c, chunk), lambda i, b: (0, i * nyb + b))
+                 for c in plan.out_ncomp]
+    out_shape = [jax.ShapeDtypeStruct((c, nwin * nyb * chunk), dtype)
+                 for c in plan.out_ncomp]
+
+    def body(*refs):
+        it = iter(refs[:len(operands)])
+        cref0 = len(operands)
+        const_refs = refs[cref0:cref0 + len(consts.names)]
+        out_refs = refs[cref0 + len(consts.names):]
+
+        chunks = []
+        for kind, ncomp, s, r, h, wrap in field_meta:
+            if kind == "pointwise":
+                chunks.append(next(it)[...].reshape(ncomp, chunk))
+                continue
+            windows = []
+            for _ in range(p + 2 * r[0]):
+                parts = [next(it) for _ in range(3 if h else 1)]
+                windows.append(jnp.concatenate([ref[:, 0] for ref in parts],
+                                               axis=1) if h else parts[0][:, 0])
+            rows = yb + 2 * h
+            moved = {}
+
+            def neighbour(slot, rest_off, windows=windows, moved=moved,
+                          r=r, h=h, wrap=wrap):
+                # window `slot`, (ncomp, yb + 2h, Ze), moved by the y/z
+                # offsets rest_off (memoised on every prefix): y by a
+                # rotation of the window and a slice of its middle yb
+                # rows, z as in the whole-plane window.
+                key = (slot, rest_off)
+                if key not in moved:
+                    if not rest_off:
+                        v = windows[slot]
+                    else:
+                        v = neighbour(slot, rest_off[:-1])
+                        d, o = len(rest_off), rest_off[-1]
+                        if d == 1:
+                            if o:
+                                v = pltpu.roll(v, (-o) % rows, 1)
+                            v = jax.lax.slice_in_dim(v, h, h + yb, axis=1)
+                        elif d not in wrap:
+                            v = jax.lax.slice_in_dim(
+                                v, r[d] + o, r[d] + o + Z, axis=d)
+                        elif o % Z:
+                            v = pltpu.roll(v, (-o) % Z, d)
+                    moved[key] = v
+                return moved[key]
+
+            nb = []
+            for off in s.offsets:
+                blk = [neighbour(xl + r[0] + off[0], tuple(off[1:]))
+                       .reshape(ncomp, bs) for xl in range(p)]
+                nb.append(blk[0] if p == 1
+                          else jnp.concatenate(blk, axis=-1))
+            chunks.append(jnp.stack(nb))          # (noffsets, ncomp, V)
+
+        if plan.with_site_index:
+            k = jax.lax.iota(jnp.int32, chunk)
+            base = pl.program_id(0) * (p * Y * Z) + pl.program_id(1) * bs
+            if p > 1:
+                k = (k // bs) * (Y * Z) + k % bs
+            chunks.append(base + k)
+        _site_kernel(plan, chunks, consts, const_refs, out_refs)
+
+    outs = pl.pallas_call(
+        body,
+        grid=(nwin, nyb),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=out_shape,
+        interpret=plan.interpret,
+        compiler_params=_compiler_params(plan),
+        name=f"tdp_windowed_{plan.name}_p{p}_yb{yb}_{plan.layout}",
+    )(*operands, *consts.vals)
+
+    def lattice_order(o):
+        c = o.shape[0]
+        if p == 1 and Yp == Y:
+            return o
+        o = o.reshape(c, nwin, nyb, p, yb, Z).transpose(0, 1, 3, 2, 4, 5)
+        return o.reshape(c, nwin * p, Yp, Z)[:, :X, :Y].reshape(c, -1)
+    return tuple(lattice_order(o) for o in outs)

@@ -296,9 +296,10 @@ class TestValidation:
 
     def test_window_vmem_overflow_named_error(self):
         """Satellite 2: a plane_block window that exceeds the VMEM cap
-        fails at plan build, naming the worst field and the byte count —
-        not deep inside Mosaic."""
-        lat = Lattice((4, 512, 512))
+        even at the smallest y-block (8 rows; a 16-row plane of 2**16
+        lanes) fails at plan build, naming the worst field, the block and
+        the byte count — not deep inside Mosaic."""
+        lat = Lattice((4, 16, 2 ** 16))
         spec = _mixed_spec()
         f = jnp.zeros((2, lat.nsites), jnp.float32)
         r = jnp.zeros((1, lat.nsites), jnp.float32)
@@ -309,16 +310,18 @@ class TestValidation:
                    consts={"alpha": 1.0, "w": jnp.ones((7,))})
         msg = str(ei.value)
         assert "mixed_layout" in msg and "plane_block=4" in msg
+        assert "y_block=8" in msg
         assert "f" in msg and "VMEM" not in msg.split()[:1]  # named error
 
     def test_launch_plan_skips_vmem_guard(self):
         """launch_plan must stay buildable over the cap so autotune can
         estimate-and-prune instead of crashing."""
-        lat = Lattice((4, 512, 512))
+        lat = Lattice((4, 16, 2 ** 16))
         spec = _mixed_spec()
         t = Target("pallas_windowed", interpret=True,
                    tuning={"plane_block": 4})
         plan = launch_plan(spec, t, lattice=lat)
+        assert plan.y_block == 8
         assert plan.vmem_bytes_estimate() > 16 * 2 ** 20
 
     def test_aosoa_hbm_estimate_doubles(self):

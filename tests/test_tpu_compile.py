@@ -1,5 +1,6 @@
 """Ahead-of-time compiles of the LB main path's Pallas kernels for a TPU
-v5e, at the upstream per-device size (128³).
+v5e, at the upstream per-device size (128³), and at a 256² cross-section
+whose window VMEM holds only in y-blocks.
 
 Nothing runs: each case lowers a launch for a described ``v5e:2x2``
 topology and compiles it with the TPU compiler installed alongside JAX,
@@ -60,9 +61,9 @@ def on_v5e(monkeypatch):
     return set_limit
 
 
-def _compile(spec, target, sharding, halo=None):
-    lat = Lattice(GRID)
-    n_ext = int(np.prod([s + 2 * h for s, h in zip(GRID, halo or (0,) * 3)]))
+def _compile(spec, target, sharding, halo=None, grid=GRID):
+    lat = Lattice(grid)
+    n_ext = int(np.prod([s + 2 * h for s, h in zip(grid, halo or (0,) * 3)]))
     args = [jax.ShapeDtypeStruct(
         (f.ncomp, n_ext if f.stencil is not None else lat.nsites),
         jnp.float32, sharding=sharding) for f in spec.fields]
@@ -94,13 +95,35 @@ def test_windowed_fused_compiles_within_its_estimate(mode, one_chip,
     target = tdp.Target("pallas_windowed")
     limit = cm.DEVICE_PEAKS[KIND]["vmem_limit"]
     for spec in WINDOWED_KERNELS[mode]:
-        est = tdp.launch_plan(spec, target,
-                              lattice=Lattice(GRID)).vmem_bytes_estimate()
+        est = tdp.launch_plan(spec, target, lattice=Lattice(GRID),
+                              vmem_limit=limit).vmem_bytes_estimate()
         assert est <= limit
         on_v5e(est)
         hlo = _compile(spec, target, one_chip).as_text()
         assert "tpu_custom_call" in hlo
         assert not _has_pad(hlo)
+        assert "_yb" not in hlo
+
+
+def test_windowed_fused_y_blocked_compiles_within_its_estimate(one_chip,
+                                                               on_v5e):
+    """128×256×256: the whole-plane one_launch window (157 MB) exceeds
+    the v5e limit, so the plan blocks y at the largest divisor of Y whose
+    window fits (128 rows), and the blocked kernel compiles with its own
+    estimate as Mosaic's limit, with no pad or copy of f and g outside
+    the kernel."""
+    grid = (128, 256, 256)
+    target = tdp.Target("pallas_windowed")
+    limit = cm.DEVICE_PEAKS[KIND]["vmem_limit"]
+    plan = tdp.launch_plan(lbst.FUSED_SPEC, target, lattice=Lattice(grid))
+    assert plan.y_block == 128
+    whole = plan._replace(y_block=None).vmem_bytes_estimate()
+    est = plan.vmem_bytes_estimate()
+    assert est <= limit < whole
+    on_v5e(est)
+    hlo = _compile(lbst.FUSED_SPEC, target, one_chip, grid=grid).as_text()
+    assert "tdp_windowed_fused_site_kernel_p1_yb128_soa" in hlo
+    assert not _has_pad(hlo)
 
 
 def test_windowed_pencil_compiles_within_its_estimate(one_chip, on_v5e):
@@ -108,16 +131,19 @@ def test_windowed_pencil_compiles_within_its_estimate(one_chip, on_v5e):
     plane, z is periodic and wrapped inside the kernel.  Both two_launch
     kernels compile within their estimate, with no pad in the launch."""
     target = tdp.Target("pallas_windowed")
+    limit = cm.DEVICE_PEAKS[KIND]["vmem_limit"]
     halo = (1, 1, 0)
     for spec in WINDOWED_KERNELS["two_launch"]:
         plan = tdp.launch_plan(spec, target, lattice=Lattice(GRID),
-                               halo=halo)
+                               halo=halo, vmem_limit=limit)
+        assert plan.y_block is None
         assert all(w == (2,) for w, f in zip(plan.wrap_dims, spec.fields)
                    if f.stencil is not None)
         on_v5e(plan.vmem_bytes_estimate())
         hlo = _compile(spec, target, one_chip, halo=halo).as_text()
         assert "tpu_custom_call" in hlo
         assert not _has_pad(hlo)
+        assert "_yb" not in hlo
 
 
 def test_pallas_pointwise_collision_compiles(one_chip):

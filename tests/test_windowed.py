@@ -16,7 +16,9 @@ ROADMAP stencil-memory stage (b), pinned here (docs/stencil.md):
 * periodic dimensions are wrapped inside the kernel
   (``wraps_periodic=True``): such a launch is **bit-identical** to the
   same launch given wrap-filled caller ghosts, and no pad runs outside
-  the kernel.
+  the kernel;
+* a launch whose whole-plane window does not fit the VMEM limit is
+  blocked in y (``LaunchPlan.y_block``), and runs the same numbers.
 """
 import jax
 import jax.numpy as jnp
@@ -32,13 +34,15 @@ from repro.core import (
     halo_extend,
     launch_plan,
 )
-from repro.kernels.lb_collision import NVEL
+from repro.kernels.lb_collision import CV, NVEL
+from repro.kernels.tdp_windowed import windowed_execute
 from repro.lb import programs as lbp
 from repro.lb import stencil as lbst
 from repro.lb.params import LBParams
 from repro.lb.sim import BinaryFluidSim
 
 WINDOWED = tdp.Target("pallas_windowed", interpret=True)
+V5E_VMEM_LIMIT = 100 * 2 ** 20
 
 
 def _rand_f(rng, n):
@@ -143,6 +147,18 @@ _FUSED_CONSTS = {k: v for k, v in lbp.collision_consts(
     if k in lbst.FUSED_SPEC.consts}
 
 
+def _mixed_body(f_nb, rho, idx, *, alpha, w):
+    acc = (f_nb * w.reshape(-1, 1, 1)).sum(axis=0)
+    return alpha * acc + rho + (idx % 5).astype(acc.dtype), acc[:1] - rho
+
+
+#: a stencil field, a pointwise field, array consts and the site index
+_MIXED_SPEC = tdp.KernelSpec(
+    _mixed_body, fields=(tdp.field(2, stencil=STENCIL_GRAD_6PT),
+                         tdp.field(1)),
+    out=(2, 1), site_index=True, consts=("alpha", "w"), name="mixed_yb")
+
+
 def _with_ghosts(x, shape, halo):
     """``(ncomp, nsites)`` → the same field carrying ``halo[d]`` wrap-filled
     ghost layers per dimension, flattened: what a caller with exchanged
@@ -241,6 +257,162 @@ class TestPeriodicInKernel:
         jaxpr = jax.make_jaxpr(lambda x: halo_extend(
             x, shape, halo, lbst.STENCIL_D3Q19_PULL))(f)
         assert _pads_outside_kernel(jaxpr.jaxpr) > 0
+
+
+#: Gap allowed between the y-blocked launch and another executor or the
+#: whole-plane launch.  The values moved are the same, but XLA's CPU
+#: backend may contract the site arithmetic's multiply-adds into FMAs
+#: differently for a chunk of another shape, which moves the last bit of
+#: a population (ROADMAP D1); a wrong neighbour moves it by ~1e-2.
+BLOCKED_ATOL = 1e-6
+
+
+def _blocked_plan(spec, shape, y_block, halo=None, consts=None,
+                  tuning=None):
+    """The plan a device whose VMEM limit just holds a ``y_block``-row
+    window would build: the limit is passed to ``launch_plan``."""
+    target = WINDOWED.with_(tuning=tuning or {})
+    lat = Lattice(shape)
+    whole = tdp.launch_plan(spec, target, lattice=lat, halo=halo,
+                            consts=consts)
+    assert whole.y_block is None
+    cap = whole._replace(y_block=y_block).vmem_bytes_estimate()
+    assert cap < whole.vmem_bytes_estimate()
+    plan = tdp.launch_plan(spec, target, lattice=lat, halo=halo,
+                           consts=consts, vmem_limit=cap)
+    assert plan.y_block == y_block
+    return plan
+
+
+def _run_plan(plan, *prepared):
+    return jax.jit(lambda *a: windowed_execute(plan, a))(*prepared)
+
+
+def _fg(rng, shape):
+    n = int(np.prod(shape))
+    return _rand_f(rng, n), _rand_g(rng, n)
+
+
+def _assert_close(got, want):
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0,
+                                   atol=BLOCKED_ATOL)
+
+
+class TestYBlockedWindow:
+    """A window that does not fit the VMEM limit is cut into y-blocks of
+    ``y_block`` rows (plus 8 halo rows on either side), each a step of a
+    second grid axis; periodic y wraps in the halo blocks' index."""
+
+    SHAPE = (3, 48, 8)
+
+    def test_fused_one_launch_step_matches_xla(self, rng):
+        plan = _blocked_plan(lbst.FUSED_SPEC, self.SHAPE, 16,
+                             consts=_FUSED_CONSTS)
+        f, g = _fg(rng, self.SHAPE)
+        got = _run_plan(plan, f.reshape(NVEL, *self.SHAPE),
+                        g.reshape(NVEL, *self.SHAPE))
+        want = tdp.launch(lbst.FUSED_SPEC, tdp.Target("xla", vvl=64), f, g,
+                          lattice=Lattice(self.SHAPE), consts=_FUSED_CONSTS)
+        _assert_close(got, want)
+
+    def test_fused_five_step_trajectory_matches_xla(self, rng):
+        plan = _blocked_plan(lbst.FUSED_SPEC, self.SHAPE, 8,
+                             consts=_FUSED_CONSTS)
+        lat = Lattice(self.SHAPE)
+        step = jax.jit(lambda f, g: windowed_execute(
+            plan, (f.reshape(NVEL, *self.SHAPE),
+                   g.reshape(NVEL, *self.SHAPE))))
+        ref = jax.jit(lambda f, g: tdp.launch(
+            lbst.FUSED_SPEC, tdp.Target("xla", vvl=64), f, g, lattice=lat,
+            consts=_FUSED_CONSTS))
+        a = b = _fg(rng, self.SHAPE)
+        for _ in range(5):
+            a, b = step(*a), ref(*b)
+        _assert_close(a, b)
+
+    @pytest.mark.parametrize("case", ["fused", "pointwise-site-index-p2"])
+    def test_y_block_not_dividing_y(self, rng, case):
+        """Y = 44 has no divisor that is a multiple of 8: the blocks run
+        past Y to 48, the rows laid out once so the halo blocks still
+        wrap, and the rows past Y are cut from the output."""
+        shape = (3, 44, 8)
+        lat = Lattice(shape)
+        if case == "fused":
+            spec, consts, tuning = lbst.FUSED_SPEC, _FUSED_CONSTS, {}
+        else:
+            spec, tuning = _MIXED_SPEC, {"plane_block": 2}
+            consts = {"alpha": 1.5,
+                      "w": jnp.asarray(rng.normal(size=7), jnp.float32)}
+        plan = _blocked_plan(spec, shape, 16, consts=consts, tuning=tuning)
+        xs = _launch_inputs(spec, shape, rng)
+        prepared = [x.reshape(x.shape[0], *shape) if fs.stencil is not None
+                    else x for x, fs in zip(xs, spec.fields)]
+        got = _run_plan(plan, *prepared)
+        want = tdp.launch(spec, tdp.Target("xla", vvl=64), *xs, lattice=lat,
+                          consts=consts)
+        want = want if isinstance(want, tuple) else (want,)
+        _assert_close(got, want)
+
+    def test_y_wraps_across_first_and_last_block(self, rng):
+        """Streaming moves data only, so it is exact: row 0 of the first
+        block pulls from row Y−1 of the last, and row Y−1 from row 0."""
+        shape = (2, 32, 8)
+        plan = _blocked_plan(lbst.STREAM_SPEC, shape, 8)
+        f = np.asarray(_rand_f(rng, int(np.prod(shape))))
+        (got,) = _run_plan(plan, jnp.asarray(f).reshape(NVEL, *shape))
+        got = np.asarray(got).reshape(NVEL, *shape)
+        fg = f.reshape(NVEL, *shape)
+        want = np.stack([np.roll(fg[q], tuple(int(c) for c in CV[q]),
+                                 axis=(0, 1, 2)) for q in range(NVEL)])
+        np.testing.assert_array_equal(got, want)
+        for q in range(NVEL):
+            cy = int(CV[q][1])
+            if cy:
+                edge = 0 if cy > 0 else shape[1] - 1
+                src = fg[q][:, (edge - cy) % shape[1]]
+                np.testing.assert_array_equal(
+                    got[q, :, edge],
+                    np.roll(src, (int(CV[q][0]), int(CV[q][2])), axis=(0, 1)))
+
+    @pytest.mark.parametrize("shape", [(3, 48, 8), (3, 44, 8)])
+    def test_ghost_y_matches_unblocked_launch(self, rng, shape):
+        """The pencil's geometry (exchanged ghosts in x and y, z wrapped
+        in-kernel): the blocked window reads the caller's ghost rows,
+        and agrees with the whole-plane launch of the same arrays."""
+        halo = (2, 2, 0)
+        plan = _blocked_plan(lbst.FUSED_SPEC, shape, 16, halo=halo,
+                             consts=_FUSED_CONSTS)
+        assert plan.wrap_dims == ((2,), (2,))
+        f, g = _fg(rng, shape)
+        fe, ge = (_with_ghosts(x, shape, halo) for x in (f, g))
+        want = tdp.launch(lbst.FUSED_SPEC, WINDOWED, fe, ge,
+                          lattice=Lattice(shape), halo=halo,
+                          consts=_FUSED_CONSTS)
+
+        def prepared(x, s):
+            r = s.radius_per_dim()
+            grid = x.reshape(NVEL, *(n + 2 * h for n, h in zip(shape, halo)))
+            return grid[:, halo[0] - r[0]:grid.shape[1] - halo[0] + r[0],
+                        halo[1] - r[1]:grid.shape[2] - halo[1] + r[1],
+                        halo[2]:grid.shape[3] - halo[2]]
+        got = _run_plan(plan, *(prepared(x, fs.stencil) for x, fs in
+                                zip((fe, ge), lbst.FUSED_SPEC.fields)))
+        _assert_close(got, want)
+
+    def test_refused_when_the_smallest_block_does_not_fit(self, rng):
+        """A 512-lane z extent overflows the 16 MiB interpret limit even
+        in 8-row blocks: the launch is refused at plan build, naming the
+        block."""
+        shape = (2, 16, 512)
+        lat = Lattice(shape)
+        plan = tdp.launch_plan(lbst.FUSED_SPEC, WINDOWED, lattice=lat,
+                               consts=_FUSED_CONSTS)
+        assert plan.y_block == 8
+        f, g = _fg(rng, shape)
+        with pytest.raises(tdp.WindowVmemError, match="y_block=8"):
+            tdp.launch(lbst.FUSED_SPEC, WINDOWED, f, g, lattice=lat,
+                       consts=_FUSED_CONSTS)
 
 
 class TestHaloExtend:
@@ -471,12 +643,13 @@ class TestMemoryEstimates:
         lat = Lattice((128, 128, 128))
         n = lat.nsites
         target = tdp.Target("pallas_windowed")
-        one = launch_plan(lbst.FUSED_SPEC, target, lattice=lat)
+        one = launch_plan(lbst.FUSED_SPEC, target, lattice=lat,
+                          vmem_limit=V5E_VMEM_LIMIT)
         assert one.wrap_dims == ((0, 1, 2), (0, 1, 2))
         # operands are the caller's arrays, reshaped: f, g in, f', g' out
         assert one.hbm_bytes_estimate() == 4 * NVEL * n * 4
         pencil = launch_plan(lbst.FUSED_SPEC, target, lattice=lat,
-                             halo=(2, 2, 0))
+                             halo=(2, 2, 0), vmem_limit=V5E_VMEM_LIMIT)
         assert pencil.wrap_dims == ((2,), (2,))
         assert pencil.hbm_bytes_estimate() == 4 * NVEL * (
             2 * n + 130 * 130 * 128 + 132 * 132 * 128)
@@ -489,6 +662,23 @@ class TestMemoryEstimates:
         assert pencil.window_blocks() == [
             (0, 3 * 19 * 136 * 128 * 4), (1, 5 * 19 * 136 * 128 * 4),
             (-1, out), (-1, out)]
+
+    def test_y_blocked_window_counts_its_halo_rows(self):
+        """128×256×256 on a v5e: the whole-plane window (19 × 256² planes)
+        exceeds the limit, the plan blocks y at 128 rows, and each window
+        plane spans 128 + 2·8 rows; in HBM each block re-reads 16 rows."""
+        lat = Lattice((128, 256, 256))
+        plan = launch_plan(lbst.FUSED_SPEC, tdp.Target("pallas_windowed"),
+                           lattice=lat, vmem_limit=V5E_VMEM_LIMIT)
+        assert plan.y_block == 128
+        tile, out = 19 * 144 * 256 * 4, 24 * 128 * 256 * 4
+        assert plan.window_blocks() == [(0, 3 * tile), (1, 5 * tile),
+                                        (-1, out), (-1, out)]
+        assert plan.vmem_bytes_estimate() == 3 * (8 * tile + 2 * out)
+        assert plan.vmem_bytes_estimate() <= V5E_VMEM_LIMIT < \
+            plan._replace(y_block=None).vmem_bytes_estimate()
+        read = 2 * NVEL * 128 * (2 * 144) * 256
+        assert plan.hbm_bytes_estimate() == (read + 2 * NVEL * lat.nsites) * 4
 
     def test_estimates_need_geometry(self):
         plan = launch_plan(tdp.KernelSpec(lambda x: x,
