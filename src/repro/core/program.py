@@ -1007,23 +1007,39 @@ class CompiledProgram:
             return self._wrap(state, tuple(state[f]
                                            for f in self.program.fields))
         key = (int(nsteps), bool(donate))
+        fn = self._run_fn(*key)
+        arrays = self._as_tuple(state)
+        with self._span("run", arrays, *key):
+            outs = fn(arrays)
+        return self._wrap(state, outs)
+
+    def _run_fn(self, nsteps: int, donate: bool):
+        """The jitted scan of ``nsteps`` steps, compiled once per
+        ``(nsteps, donate)``."""
+        key = (nsteps, donate)
         fn = self._run_cache.get(key)
         if fn is None:
-            core, n = self._core, int(nsteps)
+            core = self._core
+            # A loop carries its state in one buffer, and a kernel that
+            # cannot write over the state it reads (a Pallas launch)
+            # would cost a copy of the state every trip: two steps a
+            # trip ping-pong between that buffer and one more instead.
+            # XLA's own ops update in place, so a program whose stages
+            # all run on xla keeps one step a trip.
+            unroll = 1 if all(t.executor == "xla"
+                              for t in self.stage_targets) else 2
 
             def many(arrays):
                 def body(carry, _):
                     return core(*carry), None
-                out, _ = jax.lax.scan(body, arrays, None, length=n)
+                out, _ = jax.lax.scan(body, arrays, None, length=nsteps,
+                                      unroll=unroll)
                 return out
 
             many.__name__ = many.__qualname__ = f"tdp_{self._tag}_run"
             fn = jax.jit(many, donate_argnums=(0,) if donate else ())
             self._run_cache[key] = fn
-        arrays = self._as_tuple(state)
-        with self._span("run", arrays, *key):
-            outs = fn(arrays)
-        return self._wrap(state, outs)
+        return fn
 
     def _run_guarded(self, state, nsteps: int, health, *,
                      donate: bool = False):

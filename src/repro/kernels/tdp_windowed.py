@@ -36,7 +36,12 @@ with a depth-1 BlockSpec ``lambda i: (0, i·plane_block + j, 0, ...)``
 into the ghost-extended array, or ``(0, (i·plane_block + j − r₀ + X) mod
 X, 0, ...)`` when x is periodic — so every grid step DMAs exactly its
 window into VMEM, and a ``plane_block`` that does not divide X only
-feeds output rows that are sliced away.
+feeds output rows that are sliced away.  Outputs are stored in the
+lattice's grid layout, ``(ncomp, plane_block, *rest)`` blocks of a
+``(ncomp, X, *rest)`` array, so the program's reshape of them to its grid
+moves no data; a launch whose output rows are not a whole number of
+sublane tiles (the pencil's ghost-extended φ, 130 rows) stores them flat
+and is named ``..._p<plane_block>_flat_<layout>`` (:func:`_grid_store`).
 
 Memory model (vs the gathered path, per ``LaunchPlan`` estimates):
 
@@ -85,6 +90,8 @@ are zero-padded to a vvl multiple here and the pad lanes sliced away
 in-kernel.
 """
 from __future__ import annotations
+
+import itertools
 
 import jax
 import jax.numpy as jnp
@@ -194,13 +201,13 @@ def windowed_execute(plan, extended):
                  for c in consts.vals]
 
     out_ncomp = tuple(plan.out_ncomp)
-    # Outputs are written flat, (ncomp, p·V) lane-dense blocks: Mosaic
-    # cannot reshape a one-component (1, V) value back into (1, p, Y, Z)
-    # planes when Y is not a whole number of sublane tiles.
-    out_specs = [pl.BlockSpec((c, chunk), lambda i: (0, i))
+    grid_out = _grid_store((p, *rest), dtype)
+    block, full = (((p, *rest), (X + x_pad, *rest)) if grid_out
+                   else ((chunk,), ((X + x_pad) * rest_n,)))
+    out_specs = [pl.BlockSpec((c, *block),
+                              lambda i: (0, i, *([0] * (len(block) - 1))))
                  for c in out_ncomp]
-    out_shape = [jax.ShapeDtypeStruct((c, (X + x_pad) * rest_n), dtype)
-                 for c in out_ncomp]
+    out_shape = [jax.ShapeDtypeStruct((c, *full), dtype) for c in out_ncomp]
 
     def body(*refs):
         it = iter(refs[:len(operands)])
@@ -282,11 +289,33 @@ def windowed_execute(plan, extended):
         out_shape=out_shape,
         interpret=plan.interpret,
         compiler_params=_compiler_params(plan),
-        name=f"tdp_windowed_{plan.name}_p{p}_{plan.layout}",
+        name=f"tdp_windowed_{plan.name}_p{p}"
+             f"{'' if grid_out else '_flat'}_{plan.layout}",
     )(*operands, *consts.vals)
 
-    n = X * rest_n
-    return tuple(o[:, :n] for o in outs)
+    if grid_out:
+        return tuple(o[:, :X].reshape(o.shape[0], -1) for o in outs)
+    return tuple(o[:, :X * rest_n] for o in outs)
+
+
+def _grid_store(block, dtype) -> bool:
+    """Whether a launch stores its outputs in the lattice's grid layout,
+    as ``(ncomp, *block)`` blocks (``block`` = ``(plane_block, *rest)``)
+    of a ``(ncomp, X, *rest)`` array, or flat, as ``(ncomp, p·V)`` blocks
+    of ``(ncomp, sites)``.
+
+    Mosaic reshapes the site kernel's ``(ncomp, V)`` value into a grid
+    block only when the block's second-minor extent is a whole number of
+    sublane tiles; its minor extent fills whole 128-lane vregs in every
+    compiled launch (:class:`~repro.core.api.WindowShapeError`).  A grid
+    output reaches the program's reshape to the grid as a bitcast.  A
+    flat one is stored in tiles of 8 rows over the sites, which XLA
+    copies into the grid's tiles and cuts to ``ncomp`` rows after every
+    launch.  Decided from the shape alone, so interpret mode stores the
+    outputs as the chip does.
+    """
+    sublanes = 32 // jnp.dtype(dtype).itemsize
+    return len(block) < 2 or block[-2] % sublanes == 0
 
 
 def _prepared_extent(plan, x, s, wrap):
@@ -335,13 +364,26 @@ def _site_kernel(plan, chunks, consts, const_refs, out_refs):
     if plan.interpret:
         # XLA's CPU backend contracts multiply-adds differently
         # depending on which data movement it fuses into the site
-        # arithmetic; behind a barrier the arithmetic compiles the
-        # same whether its chunk came from slices or rotations.
+        # arithmetic; behind barriers the arithmetic compiles the
+        # same whether its chunk came from slices or rotations, and
+        # whether its outputs are stored flat or as grid blocks.
         chunks = jax.lax.optimization_barrier(chunks)
     vals = plan.kernel(*chunks, **kw)
     vals = (vals,) if not isinstance(vals, tuple) else vals
+    if plan.interpret:
+        vals = jax.lax.optimization_barrier(vals)
     for ref, v in zip(out_refs, vals):
-        ref[...] = v.astype(ref.dtype)
+        v = v.astype(ref.dtype)
+        if ref.shape[0] == 1 and ref.ndim > 2:
+            # One component may be held a sublane per vreg, which Mosaic
+            # cannot reshape into rows wider than one vreg (∇²φ at
+            # Z = 256): store it row by row.
+            n = ref.shape[-1]
+            for k, row in enumerate(itertools.product(
+                    *map(range, ref.shape[1:-1]))):
+                ref[(0, *row)] = v[0, k * n:(k + 1) * n]
+        else:
+            ref[...] = v.reshape(ref.shape)
 
 
 def _compiler_params(plan):
@@ -394,9 +436,10 @@ def _y_blocked_execute(plan, extended, p):
     slot index; a y offset is a rotation of that window followed by an
     aligned slice of its ``yb`` middle rows.  z is resolved as in the
     whole-plane window: a rotation when periodic, a slice of the caller's
-    ghosts otherwise.  Outputs are written as ``(ncomp, p·yb·Z)`` blocks
-    at flat block ``i·nyb + b`` and put back in lattice order after the
-    call (a reshape when ``p = 1`` and the blocks tile Y).
+    ghosts otherwise.  Outputs are stored in the lattice's grid layout,
+    as ``(ncomp, p, yb, Z)`` blocks at ``(i, b)`` of a ``(ncomp, X, Yp,
+    Z)`` array (:func:`_grid_store`: ``yb`` is a multiple of the 8-row
+    sublane tile), and rows past Y, if any, are cut after the call.
     """
     shape = plan.shape
     if len(shape) != 3 or plan.layout != "soa":
@@ -456,9 +499,9 @@ def _y_blocked_execute(plan, extended, p):
     consts = _Consts(plan)
     in_specs += [pl.BlockSpec(c.shape, lambda i, b: (0, 0))
                  for c in consts.vals]
-    out_specs = [pl.BlockSpec((c, chunk), lambda i, b: (0, i * nyb + b))
+    out_specs = [pl.BlockSpec((c, p, yb, Z), lambda i, b: (0, i, b, 0))
                  for c in plan.out_ncomp]
-    out_shape = [jax.ShapeDtypeStruct((c, nwin * nyb * chunk), dtype)
+    out_shape = [jax.ShapeDtypeStruct((c, nwin * p, Yp, Z), dtype)
                  for c in plan.out_ncomp]
 
     def body(*refs):
@@ -532,10 +575,4 @@ def _y_blocked_execute(plan, extended, p):
         name=f"tdp_windowed_{plan.name}_p{p}_yb{yb}_{plan.layout}",
     )(*operands, *consts.vals)
 
-    def lattice_order(o):
-        c = o.shape[0]
-        if p == 1 and Yp == Y:
-            return o
-        o = o.reshape(c, nwin, nyb, p, yb, Z).transpose(0, 1, 3, 2, 4, 5)
-        return o.reshape(c, nwin * p, Yp, Z)[:, :X, :Y].reshape(c, -1)
-    return tuple(lattice_order(o) for o in outs)
+    return tuple(o[:, :X, :Y].reshape(o.shape[0], -1) for o in outs)

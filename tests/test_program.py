@@ -206,6 +206,14 @@ def _pr3_trajectory(st, nsteps, target, pw_target, mode):
     return f, g
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
 @pytest.fixture(scope="module")
 def spinodal_state():
     return BinaryFluidSim(GRID, params=PARAMS).init_spinodal(seed=3,
@@ -255,6 +263,29 @@ class TestTrajectoryBitIdentity:
         b = sim.run(spinodal_state, 10)
         np.testing.assert_array_equal(np.asarray(a.f), np.asarray(b.f))
         np.testing.assert_array_equal(np.asarray(a.g), np.asarray(b.g))
+
+    @pytest.mark.parametrize("nsteps", [4, 5])
+    def test_pallas_scan_takes_two_steps_a_trip(self, spinodal_state,
+                                                nsteps):
+        """Under a Pallas executor ``run`` scans two steps a trip, so a
+        kernel that cannot write over the state it reads costs no copy of
+        the state per step; an odd count ends with one step alone.
+        Streaming moves data only, so the scan is exact against single
+        steps."""
+        exe = lbp.stream_program().compile("pallas_windowed_interpret",
+                                           grid_shape=GRID)
+        state = {"f": spinodal_state.f, "g": spinodal_state.g}
+        jaxpr = jax.make_jaxpr(exe._run_fn(nsteps, False))(
+            exe._as_tuple(state))
+        assert [e.params["unroll"] for e in _eqns(jaxpr.jaxpr)
+                if e.primitive.name == "scan"] == [2]
+        want = state
+        for _ in range(nsteps):
+            want = exe.step(want)
+        got = exe.run(state, nsteps)
+        for f in ("f", "g"):
+            np.testing.assert_array_equal(np.asarray(got[f]),
+                                          np.asarray(want[f]))
 
     def test_run_donated_matches_undonated(self, spinodal_state):
         sim = BinaryFluidSim(GRID, params=PARAMS, fused="two_launch")

@@ -62,19 +62,50 @@ def on_v5e(monkeypatch):
 
 
 def _compile(spec, target, sharding, halo=None, grid=GRID):
+    """Compile one launch as a program stage runs it
+    (``Program._run_stage``): grid operands, ghost-extended by ``halo``
+    for stencil fields, flattened for the launch, and its outputs
+    reshaped back to the lattice's grid (``tdp.outputs``)."""
     lat = Lattice(grid)
-    n_ext = int(np.prod([s + 2 * h for s, h in zip(grid, halo or (0,) * 3)]))
+    ext = tuple(s + 2 * h for s, h in zip(grid, halo or (0,) * 3))
     args = [jax.ShapeDtypeStruct(
-        (f.ncomp, n_ext if f.stencil is not None else lat.nsites),
+        (f.ncomp, *(ext if f.stencil is not None else grid)),
         jnp.float32, sharding=sharding) for f in spec.fields]
     consts = {k: CONSTS[k] for k in spec.consts or ()}
-    fn = jax.jit(lambda *a: tdp.launch(spec, target, *a, lattice=lat,
-                                       halo=halo, consts=consts))
-    return fn.lower(*args).compile()
+
+    def stage(*a):
+        outs = tdp.launch(spec, target, *(x.reshape(x.shape[0], -1)
+                                          for x in a),
+                          lattice=lat, halo=halo, consts=consts)
+        outs = outs if isinstance(outs, tuple) else (outs,)
+        return tuple(o.reshape(o.shape[0], *grid) for o in outs)
+    return jax.jit(stage).lower(*args).compile()
 
 
 def _has_pad(hlo: str) -> bool:
     return re.search(r"\bpad\(", hlo) is not None
+
+
+def _kernel_output_relayouts(hlo: str) -> list[str]:
+    """The copies, slices, transposes and reshapes of an optimised HLO
+    module whose operand is a windowed kernel's output, or a bitcast of
+    one: the relayout XLA adds when the kernel stores its outputs in
+    another layout than the grid's."""
+    derived, found = set(), []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = .*?\s([a-z][\w\-]*)\((.*)",
+                     line)
+        if m is None:
+            continue
+        name, op, rest = m.groups()
+        reads_kernel = bool(derived & set(re.findall(r"%([\w.\-]+)", rest)))
+        if op == "custom-call" and name.startswith("tdp_windowed"):
+            derived.add(name)
+        elif op in ("get-tuple-element", "bitcast") and reads_kernel:
+            derived.add(name)
+        elif op in ("copy", "slice", "transpose", "reshape") and reads_kernel:
+            found.append(name)
+    return found
 
 
 #: the windowed kernels of each fused mode (lb.programs.fused_program)
@@ -144,6 +175,60 @@ def test_windowed_pencil_compiles_within_its_estimate(one_chip, on_v5e):
         assert "tpu_custom_call" in hlo
         assert not _has_pad(hlo)
         assert "_yb" not in hlo
+
+
+#: each benchmark cell's windowed kernels as its program launches them:
+#: (spec, lattice of the launch, caller ghosts, the kernel's name)
+OUTPUT_LAYOUT_CASES = {
+    "one_launch-128": (lbst.FUSED_SPEC, GRID, None,
+                       "tdp_windowed_fused_site_kernel_p1_soa"),
+    "y_blocked-128x256x256": (lbst.FUSED_SPEC, (128, 256, 256), None,
+                              "tdp_windowed_fused_site_kernel_p1_yb128_soa"),
+    "pencil-fused_two": (lbst.FUSED_TWO_SPEC, GRID, (1, 1, 0),
+                         "tdp_windowed_fused_two_site_kernel_p1_soa"),
+    # the collide prologue's gradients: one-component ∇²φ in 256-lane rows
+    "gradients-128x256x256": (lbst.GRAD6_SPEC, (128, 256, 256), None,
+                              "tdp_windowed_grad6_site_kernel_p1_soa"),
+    # the pencil's streamed φ keeps a ghost ring: 130 rows, not a whole
+    # number of 8-row sublane tiles, so its one component is stored flat
+    "pencil-phi_stream-flat": (lbst.PHI_STREAM_SPEC, (130, 130, 128),
+                               (1, 1, 0),
+                               "tdp_windowed_streamed_phi_site_kernel"
+                               "_p1_flat_soa"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(OUTPUT_LAYOUT_CASES))
+def test_windowed_outputs_need_no_relayout(case, one_chip, on_v5e):
+    """Each cell's windowed kernels store their outputs in the lattice's
+    grid layout, so the program's reshape of them to the grid compiles to
+    no copy, slice, transpose or reshape of the kernel's output; only a
+    launch whose output rows are not whole sublane tiles is stored flat,
+    and named so."""
+    spec, grid, halo, name = OUTPUT_LAYOUT_CASES[case]
+    hlo = _compile(spec, tdp.Target("pallas_windowed"), one_chip,
+                   halo=halo, grid=grid).as_text()
+    assert f"%{name}." in hlo
+    assert ("_flat" in name) == ("_flat_" in hlo)
+    assert _kernel_output_relayouts(hlo) == []
+
+
+def test_windowed_run_moves_no_state_outside_the_kernel(one_chip, on_v5e):
+    """A call of the one-chip cell, 100 donated fused steps in one scan,
+    compiles to no copy, slice or transpose as large as f or g: the
+    kernel stores the grid's layout, and the scan takes two steps a trip,
+    so the loop never copies the state it carries into the kernel."""
+    exe = lbp.fused_program("one_launch", CONSTS).compile(
+        tdp.Target("pallas_windowed"), grid_shape=GRID)
+    state = jax.ShapeDtypeStruct((lbst.NVEL, *GRID), jnp.float32,
+                                 sharding=one_chip)
+    hlo = exe._run_fn(100, True).lower((state, state)).compile().as_text()
+    assert "%tdp_windowed_fused_site_kernel_p1_soa." in hlo
+    moves = [(op, shape) for shape, op in re.findall(
+        r"= f32\[([\d,]+)\]\S* (copy|slice|transpose)\(", hlo)
+        if np.prod([int(d) for d in shape.split(",")])
+        >= lbst.NVEL * np.prod(GRID)]
+    assert moves == []
 
 
 def test_pallas_pointwise_collision_compiles(one_chip):

@@ -415,6 +415,71 @@ class TestYBlockedWindow:
                        consts=_FUSED_CONSTS)
 
 
+def _index_body(nb, idx):
+    return nb[0] + nb[1], nb[2, :1] + idx.astype(nb.dtype)
+
+
+#: two outputs of different widths, one carrying the site index; sums
+#: only, so every executor computes them bit for bit alike
+_INDEX_SPEC = tdp.KernelSpec(
+    _index_body, fields=(tdp.field(2, stencil=STENCIL_GRAD_6PT),),
+    out=(2, 1), site_index=True, name="index_sum")
+
+
+class TestOutputStore:
+    """Outputs are stored in the lattice's grid layout where their blocks'
+    rows are whole sublane tiles, flat (``_flat`` in the kernel's name)
+    where they are not; either way every site lands where the xla
+    executor puts it."""
+
+    CASES = {
+        # name: (spec, shape, caller ghosts, plane_block, y_block, form)
+        "grid-p1": (lbst.STREAM_SPEC, (16, 16, 16), None, 1, None, "grid"),
+        "grid-p2-x-pad": (lbst.STREAM_SPEC, (15, 16, 16), None, 2, None,
+                          "grid"),
+        "grid-site-index": (_INDEX_SPEC, (15, 16, 16), None, 2, None,
+                            "grid"),
+        "y-blocked-p2-past-y": (lbst.STREAM_SPEC, (5, 44, 16), None, 2, 16,
+                                "grid"),
+        "y-blocked-site-index": (_INDEX_SPEC, (5, 44, 16), None, 2, 16,
+                                 "grid"),
+        "flat-ghost-rows": (lbst.STREAM_SPEC, (6, 10, 16), (1, 1, 0), 1,
+                            None, "flat"),
+    }
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_bit_identical_to_xla(self, rng, case):
+        spec, shape, halo, p, y_block, form = self.CASES[case]
+        lat = Lattice(shape)
+        xs = [_with_ghosts(x, shape, halo) if halo else x
+              for x in _launch_inputs(spec, shape, rng)]
+        want = tdp.launch(spec, tdp.Target("xla", vvl=64), *xs, lattice=lat,
+                          halo=halo)
+        tuning = {"plane_block": p}
+        if y_block:
+            plan = _blocked_plan(spec, shape, y_block, tuning=tuning)
+            xs = [x.reshape(x.shape[0], *shape) for x in xs]
+
+            def fn(*a):
+                return windowed_execute(plan, a)
+        else:
+            target = WINDOWED.with_(tuning=tuning)
+
+            def fn(*a):
+                return tdp.launch(spec, target, *a, lattice=lat, halo=halo)
+        text = jax.jit(fn).lower(*xs).as_text(debug_info=True)
+        name = (f"tdp_windowed_{spec.name}_p{p}"
+                f"{f'_yb{y_block}' if y_block else ''}"
+                f"{'_flat' if form == 'flat' else ''}_soa")
+        assert name in text
+        assert ("_flat_" in text) == (form == "flat")
+        got = jax.jit(fn)(*xs)
+        got = got if isinstance(got, tuple) else (got,)
+        want = want if isinstance(want, tuple) else (want,)
+        for a, b in zip(got, want, strict=True):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 class TestHaloExtend:
     def test_periodic_matches_roll(self, rng):
         shape = (4, 5, 6)
