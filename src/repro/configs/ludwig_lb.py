@@ -1,9 +1,9 @@
 """The paper's own application: Ludwig D3Q19 binary-fluid benchmark.
 
 Grid/production sizes follow the Ludwig GPU-scaling papers ([2][3] in the
-paper): ~128³ per device.  The benchmark config is what
-``benchmarks/run.py`` sweeps (paper Fig. 1); the production config is the
-dry-run / multi-pod slab-decomposition cell.
+paper): ~128³ per device.  The chip benchmark's configurations live in
+``bench/configs/``; the production config is the dry-run / multi-pod
+slab-decomposition cell.
 """
 from dataclasses import dataclass
 

@@ -81,7 +81,7 @@ from .program import (
     program,
     stage,
 )
-from .state import ProgramState, validate_field
+from .state import FIELD_ATOL, ProgramState, check_fields, validate_field
 from .fleet import FleetDriver, FleetProgram, Ticket
 from .autotune import (
     Candidate,
@@ -130,6 +130,7 @@ __all__ = [
     "stage",
     # fleets (ensemble execution + async service)
     "BatchedConst", "ProgramState", "validate_field",
+    "check_fields", "FIELD_ATOL",
     "FleetProgram", "FleetDriver", "Ticket",
     # autotuning
     "autotune", "default_space", "plane_block_candidates",

@@ -923,8 +923,8 @@ def xla_executor(plan: LaunchPlan, gathered):
     ``(ncomp, vvl)`` tiles contiguous per block — and the chunk loop
     vmaps over the leading block axis.  Each chunk holds exactly the
     sites the SoA path's chunk *i* holds (same zero padding, same
-    grouping), so results are bit-identical across layouts; only the
-    physical operand ordering differs.
+    grouping), so results agree across layouts (the cross-path
+    contract); only the physical operand ordering differs.
     """
     vvl = plan.vvl
     n = gathered[0].shape[-1]
@@ -972,8 +972,7 @@ def _pallas_windowed_executor(plan: LaunchPlan, extended):
 # pointwise block knobs on "pallas" are consumed by the ops layer
 # (repro.kernels.ops reads them off the same Target), not by
 # pallas_execute itself; declaring them here keeps one authoritative
-# table for `benchmarks/run.py --sweep` validation and `tdp.autotune`
-# space construction.
+# table for `tdp.autotune` space construction.
 _PALLAS_TUNABLES = ("block_f", "block_q", "block_k", "block_d", "block_t")
 
 register_executor("xla", xla_executor)

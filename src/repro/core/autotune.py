@@ -7,8 +7,7 @@ Lightweight Approach to Performance Portability with targetDP",
 1609.01479) states that those knobs must be re-chosen per device.  This
 framework exposes the knobs — the executor choice, ``vvl``,
 ``Target.tuning["plane_block"]``, the pointwise block sizes — but until
-now choosing them was manual (``benchmarks/run.py --sweep``).
-:func:`autotune` closes the loop:
+now choosing them was manual.  :func:`autotune` closes the loop:
 
 1. **enumerate** a candidate space of :class:`Candidate` assignments,
    derived from the program/spec and the launch geometry unless given
@@ -31,9 +30,11 @@ now choosing them was manual (``benchmarks/run.py --sweep``).
 
 Correctness is decoupled from tuning by construction — candidates only
 permute *how* the same launches execute, never *what* they compute; the
-optional ``check_identical=True`` verifies this at tune time by
-comparing every candidate's output bit-for-bit against the default
-target's (mismatches are pruned, not chosen).  The base target is always
+optional ``check_outputs=True`` verifies this at tune time by holding
+every candidate's output to the default target's under
+:func:`repro.core.state.check_fields`, the cross-path contract (another
+executor or blocking rounds the last bit differently; a mismatch beyond
+the contract is pruned, not chosen).  The base target is always
 candidate 0, so the tuned median can never exceed the default median.
 
 Results persist in an on-disk cache (``results/tuning/`` by default)
@@ -86,6 +87,7 @@ from .registry import (
     executor_wants,
 )
 from .spec import KernelSpec
+from .state import check_fields
 from .target import Target, as_target
 
 #: on-disk cache entry schema.  v1: PR 5 entries (no predictor fields,
@@ -823,7 +825,7 @@ def autotune(program_or_spec, target: Target | str | None = None,
              lattice: Lattice | None = None, halo=None, consts=None,
              executors: Sequence[str] | None = None,
              vmem_limit: int | None = None,
-             check_identical: bool = False,
+             check_outputs: bool = False,
              scorer: Callable[[Target], float | None] | None = None,
              top_k: int | None = None,
              profile=None,
@@ -854,10 +856,12 @@ def autotune(program_or_spec, target: Target | str | None = None,
       grid_shape / lattice / halo / consts: launch geometry (programs
         infer ``grid_shape`` from ``example_state``).
       executors / vmem_limit: forwarded to :func:`default_space`.
-      check_identical: additionally run every candidate once and prune
-        any whose outputs are not bit-identical to the base target's
-        (tuning must never change results; a mismatch is an executor
-        bug, surfaced in ``report.pruned``, never silently chosen).
+      check_outputs: additionally run every candidate once and prune
+        any whose outputs fail :func:`~repro.core.state.check_fields`
+        against the base target's (tuning must never change results
+        beyond rounding; a mismatch is an executor bug, surfaced in
+        ``report.pruned`` with the field and its gap, never silently
+        chosen).
       scorer: ``(candidate_target) -> predicted seconds | None`` — the
         analytical model ranking the space.  Defaults to the
         :mod:`repro.core.costmodel` roofline predictor.  Every measured
@@ -929,7 +933,7 @@ def autotune(program_or_spec, target: Target | str | None = None,
         pruned = []
         base_cand = Candidate.of(base)
         # the base target is always candidate 0 (the default-median
-        # baseline, the check_identical reference, the must-run entry) —
+        # baseline, the check_outputs reference, the must-run entry) —
         # even when an explicit space lists it elsewhere
         candidates = [base_cand] + [c for c in _as_candidates(space)
                                     if c != base_cand]
@@ -997,18 +1001,18 @@ def autotune(program_or_spec, target: Target | str | None = None,
         tgt = cand.target_from(base)
         try:
             run = runner(tgt)
-            if check_identical:
+            if check_outputs:
                 out = run()
-                flat = jax.tree_util.tree_leaves(out)
+                out = out if isinstance(out, (Mapping, tuple)) else (out,)
                 if i == 0:
-                    ref_out = [np.asarray(x) for x in flat]
-                elif (len(flat) != len(ref_out)
-                      or not all(np.array_equal(a, np.asarray(b))
-                                 for a, b in zip(ref_out, flat))):
-                    pruned.append((cand.label,
-                                   "output not bit-identical to the "
-                                   "default target"))
-                    continue
+                    ref_out = out
+                else:
+                    try:
+                        check_fields(ref_out, out)
+                    except AssertionError as e:
+                        pruned.append((cand.label, f"output differs from "
+                                                   f"the default target: {e}"))
+                        continue
             for _ in range(max(0, int(warmup))):
                 timer(tgt, run)
             times = tuple(float(timer(tgt, run))

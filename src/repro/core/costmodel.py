@@ -1144,10 +1144,8 @@ def collective_bytes(hlo_text: str) -> dict:
 
 def dryrun_record_terms(rec: Mapping, profile: MachineProfile | None = None
                         ) -> dict:
-    """Roofline terms for one ``results/dryrun`` record (the
-    ``benchmarks/roofline.py`` table row, computed here so the CLI is a
-    thin view over the cost model).  ``profile`` defaults to the TPU
-    table profile the dry-run targets."""
+    """Roofline terms for one ``results/dryrun`` record.  ``profile``
+    defaults to the TPU table profile the dry-run targets."""
     p = (profile if profile is not None
          else MachineProfile.default("tpu:TPU v5 lite"))
     ha = rec["hlo_analysis"]

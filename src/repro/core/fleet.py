@@ -7,7 +7,10 @@ trajectories (parameter sweeps, per-user simulations) per device.  Every
 :class:`~repro.core.program.CompiledProgram` step is a pure pytree
 function, so ``vmap`` lifts it over a leading **ensemble axis** for
 free, and members never interact — a fleet trajectory is bit-identical
-to running its members one by one.  Three layers:
+to running its members one by one through the same compiled program
+(one program driven as a batch: the bitwise side of the contract of
+:func:`~repro.core.state.check_fields`; a fleet on another executor or
+decomposition agrees under its tolerance).  Three layers:
 
 1. **Ensemble execution** — :class:`FleetProgram` (built by
    ``compiled.vmap(batch)``): the compiled core vmapped over axis 0 of

@@ -23,9 +23,7 @@ stay bit-identical to unguarded ones).
 
 Cost model: each check is one ``O(state)`` reduction per guarded field
 per ``every`` member steps — ``every=1`` bounds the blast radius to one
-chunk, larger ``every`` amortises the guard under the scan
-(``benchmarks/run.py`` records the measured overhead as
-``health_check_overhead`` in ``BENCH_fleet.json``).
+chunk, larger ``every`` amortises the guard under the scan.
 """
 from __future__ import annotations
 

@@ -495,11 +495,11 @@ class Program:
         pencil, three = block) and one ghost-exchange round per field per
         sharded dim per step.  ``overlap=True`` opts into the
         comm/compute overlap schedule (interior launched while the
-        exchanges are in flight); it is numerically equivalent but not
-        bit-reproducible against the default unsplit schedule — XLA
-        codegen for the region shapes reassociates at the ≤1-ULP level —
-        so the default (``None``/``False``) keeps the bit-identical
-        trajectory."""
+        exchanges are in flight); XLA codegen for the region shapes
+        reassociates at the ≤1-ULP level, so it agrees with the default
+        unsplit schedule under the cross-path contract
+        (:func:`repro.core.state.check_fields`), as any other
+        decomposition does, and stays opt-in (``None``/``False``)."""
         return CompiledProgram(self, target, grid_shape, mesh=mesh,
                                shard_axis=shard_axis, overlap=overlap)
 
@@ -837,11 +837,10 @@ class CompiledProgram:
             _validate_decomposition(program, self.grid_shape, open_mask)
 
             # Overlap is opt-in: splitting a launch into region-shaped
-            # launches is *data*-exact (the eager split is bitwise equal
-            # to the full launch) but XLA codegen for the region shapes
-            # may reassociate float ops at the ≤1-ULP level, so the
-            # default keeps the unsplit schedule and its bit-identical-
-            # to-single-device guarantee.  Feasibility: the interior must
+            # launches is *data*-exact, but XLA codegen for the region
+            # shapes may reassociate float ops at the ≤1-ULP level, which
+            # the cross-path contract (state.check_fields) allows, as it
+            # does for any decomposition.  Feasibility: the interior must
             # be non-empty in every exchanged dim (thin pencils where the
             # exchange width swallows the whole shard stay unsplit).
             W = tuple(max((widths[f][d] for f in fields), default=0)

@@ -85,9 +85,9 @@ def register_executor(name: str, fn: Callable, *, overwrite: bool = False,
 
     ``tunables`` declares the ``Target.tuning`` keys the executor actually
     consults (e.g. ``("plane_block",)`` for the windowed executor) — the
-    contract ``benchmarks/run.py --sweep`` and ``tdp.autotune`` build
-    candidate spaces from; sweeping a key outside this set is rejected up
-    front instead of silently measuring a no-op.
+    contract ``tdp.autotune`` builds candidate spaces from; sweeping a
+    key outside this set is rejected up front instead of silently
+    measuring a no-op.
 
     ``wraps_periodic`` (``"halo_extended"`` executors only) declares that
     the executor wraps periodic dimensions (launch ``halo[d] == 0``)

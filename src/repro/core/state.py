@@ -20,6 +20,9 @@ carries the field names **and** whether an ensemble axis is present.
 ensemble form (or a mapping of pre-batched arrays).  Validation
 (:meth:`ProgramState.validate` / :func:`validate_field`) names the
 offending field and dimension instead of dumping bare shape tuples.
+
+:func:`check_fields` is the one contract for comparing the fields two
+execution paths produce (see its docstring and ``docs/targetdp_api.md``).
 """
 from __future__ import annotations
 
@@ -27,6 +30,7 @@ from collections.abc import Iterator, Mapping
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def _dim_name(i: int, ensemble: bool) -> str:
@@ -77,6 +81,77 @@ def validate_field(name: str, arr, *, ncomp: int | None,
                 f"{_dim_name(i, ensemble is not None)} is {got[i]}, "
                 f"expected grid extent {want} "
                 f"(grid_shape {tuple(grid_shape)})")
+
+
+#: Largest gap :func:`check_fields` allows per field, for values of unit
+#: scale: ``"f"`` and ``"g"`` are the LB populations, ``None`` any other
+#: field.  How the values were set is in :func:`check_fields`.
+FIELD_ATOL: Mapping[str | None, float] = {"f": 1e-6, "g": 1e-6, None: 1e-6}
+
+
+def check_fields(want, got) -> None:
+    """Hold the fields ``got`` to ``want`` under the cross-path contract.
+
+    Two runs of the same steps compare bitwise only when both run the
+    same stage launches on the same executor, layout, fusion mode,
+    decomposition, ``plane_block`` and ``y_block``, and only the host's
+    driving differs (a step loop against ``run``'s scan, donation, a
+    guarded or chunked run, fleet replay, checkpoint restore).  Any other
+    pair -- another executor, fusion mode, layout, vvl, decomposition or
+    blocking, or a plain reference -- is held to :data:`FIELD_ATOL`: the
+    same arithmetic in another order or another fusion rounds the last
+    bits of a value differently on each host, while a wrong neighbour or
+    a wrong weight moves it by far more.
+
+    ``want`` and ``got`` map field names to arrays (a sequence names its
+    entries by position and takes the generic tolerance).  A field's
+    tolerance scales with its largest expected magnitude where that
+    exceeds 1 (the populations stay below it, so theirs is absolute).
+    NaN on both sides at a site agrees; on one side it is a gap of
+    ``inf``.  Raises ``AssertionError`` naming the first field over its
+    tolerance, its largest gap and the index where that gap is.
+
+    The tolerances (1e-6 for f, g and any other field) rest on the
+    largest gap of every cross-path pair the tests run, at their sizes
+    and step counts, on the CPU (x86-64, XLA's CPU backend, Pallas in
+    interpret mode): 8.94e-8 in f and 8.94e-8 in g (10 steps at 16³:
+    unfused against fused, windowed against xla, local against pencil
+    and block, the pre-Program driver, autotune candidates, and every
+    path against the benchmark's plain reference), 0 in every other
+    field.  Each is at least 10× its gap and below the chip benchmark's
+    limits (``max_abs_df`` 5e-6, ``max_abs_dg`` 1e-3); a population
+    streamed one site too far moves f by 7.2e-5 in 10 steps.
+    """
+    want, got = _named(want), _named(got)
+    if set(want) != set(got):
+        raise AssertionError(f"fields differ: expected {sorted(want)}, "
+                             f"got {sorted(got)}")
+    for name in want:
+        a = np.asarray(want[name], np.float64)
+        b = np.asarray(got[name], np.float64)
+        if a.shape != b.shape:
+            raise AssertionError(f"field {name!r}: shape {b.shape}, "
+                                 f"expected {a.shape}")
+        if not a.size:
+            continue
+        with np.errstate(invalid="ignore"):
+            gap = np.where((a == b) | (np.isnan(a) & np.isnan(b)), 0.0,
+                           np.nan_to_num(np.abs(a - b), nan=np.inf))
+        i = np.unravel_index(int(np.argmax(gap)), gap.shape)
+        finite = np.abs(a[np.isfinite(a)])
+        scale = max(1.0, float(finite.max())) if finite.size else 1.0
+        tol = FIELD_ATOL.get(name, FIELD_ATOL[None]) * scale
+        if gap[i] > tol:
+            raise AssertionError(
+                f"field {name!r}: largest gap {gap[i]:.3g} at index "
+                f"{tuple(int(k) for k in i)} (got {b[i]:.9g}, expected "
+                f"{a[i]:.9g}) exceeds its tolerance {tol:.3g}")
+
+
+def _named(fields) -> dict:
+    if isinstance(fields, Mapping):
+        return dict(fields)
+    return {str(i): x for i, x in enumerate(fields)}
 
 
 @jax.tree_util.register_pytree_node_class

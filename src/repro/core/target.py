@@ -19,7 +19,7 @@ from dataclasses import dataclass, field
 from typing import Any, Mapping
 
 # Default VVL: one full TPU vector register row of lanes.  The paper tunes
-# VVL per architecture (8 on AVX, 2 on K40); benchmarks/run.py sweeps it.
+# VVL per architecture (8 on AVX, 2 on K40); tdp.autotune sweeps it.
 _DEFAULT_VVL = 128
 
 
@@ -74,7 +74,8 @@ class Target:
         inner block width).  Callers always pass and receive SoA
         ``(ncomp, nsites)`` arrays; the transforms live at field
         boundaries (:mod:`repro.core.layout`), so kernels stay
-        single-source and results are bit-identical across layouts.
+        single-source and results agree across layouts under the
+        cross-path contract (:func:`repro.core.state.check_fields`).
       mesh / shard_axis: optional sharding hints for mesh-aware callers
         (e.g. :class:`repro.lb.sim.BinaryFluidSim`); the core launch does
         not act on them, it only carries them.  ``shard_axis`` is one

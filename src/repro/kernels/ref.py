@@ -27,11 +27,11 @@ def lb_collision_ref(f, g, phi, gradphi, del2phi, *,
     :func:`repro.kernels.lb_collision.collision_site_kernel` — written
     independently (einsum over the whole lattice at once) but keeping the
     site kernel's exact accumulation/association order (``cu * cu``, not
-    ``cu ** 2``; ``φ·φ·φ``), so the two are **bit-identical** on the xla
-    executor.  The Program-based driver leans on this: the unfused
+    ``cu ** 2``; ``φ·φ·φ``), so the two compute the same numbers on the
+    xla executor.  The Program-based driver leans on this: the unfused
     pipeline's collide stage (``COLLIDE_SPEC`` → the site kernel) must
-    reproduce the historical ``ops.lb_collision`` trajectory bit-for-bit
-    (pinned by ``tests/test_program.py``)."""
+    reproduce the historical ``ops.lb_collision`` trajectory (pinned by
+    ``tests/test_program.py`` under the cross-path contract)."""
     dt = f.dtype
     w = jnp.asarray(WEIGHTS, dt)[:, None]
     c = jnp.asarray(CV, dt)

@@ -90,7 +90,8 @@ def _run_pallas(kernel: Callable, vvl: int, with_site_index: bool,
     ``ncomp`` separate rows of HBM, for AoSoA it is a single dense tile.
     The kernel body still sees ``(ncomp, VVL)`` / ``(noffsets, ncomp,
     VVL)`` chunks with identical contents, so site kernels stay
-    single-source and outputs are bit-identical across layouts.
+    single-source and outputs agree across layouts (the cross-path
+    contract).
     """
     from repro.core.api import pad_sites
     from repro.core.layout import aosoa_to_soa, soa_to_aosoa

@@ -26,8 +26,8 @@ offsets that read it; the compiler keeps only the components a site
 kernel reads.  The ``(noffsets, ncomp, V)`` chunk every site kernel
 already expects is assembled in fast memory and never exists in HBM.
 Site kernels stay single-source; the values moved are the same either
-way, so a periodic launch is bit-identical to the same launch given
-wrap-filled ghosts (``tests/test_windowed.py``).
+way, so a periodic launch matches the same launch given wrap-filled
+ghosts (``tests/test_windowed.py``, under the cross-path contract).
 
 Mechanically, the window is expressed through Pallas block indexing with
 no overlap tricks: the prepared array is passed once per window slot
@@ -81,9 +81,10 @@ resolution, so site kernels are untouched.  Outputs are written as
 plain SoA plane blocks in **both** layouts: re-interleaving the result
 in-kernel feeds a transpose into the fused site-math cluster, and XLA
 then contracts the arithmetic's mul+add chains into FMAs differently
-per vvl — trading a dense output store for broken bit-identity.  With
-SoA output blocks every layout×vvl point is bit-identical to the SoA
-path (pinned by ``tests/test_layout.py``).  ``vvl`` must divide the
+per vvl.  The SoA output blocks were kept for bit-identity across
+layouts, which the cross-path contract (``check_fields``) no longer
+asks for; ``tests/test_layout.py`` holds every layout×vvl point to the
+SoA path under it.  ``vvl`` must divide the
 *interior* plane site count exactly — validated at plan-build time by
 :func:`repro.core.api.launch`; ghost-extended stencil operand planes
 are zero-padded to a vvl multiple here and the pad lanes sliced away

@@ -7,9 +7,10 @@ structure faithfully in JAX: the lattice field is **AoS** ``(X, Y, Z, 19)``
 so every contraction runs over the *minor* axis of extent 19/3 and the
 site axis is not exposed as a vectorisable innermost dimension.
 
-It is numerically identical to the targetDP path (tests assert allclose
-after layout transposition) and exists purely as the measurable baseline
-for ``benchmarks/run.py::bench_fig1``.
+It computes the targetDP path's numbers in another execution structure
+(tests hold it to that path, after layout transposition, under the
+cross-path contract ``repro.core.check_fields``) and exists as the
+paper's Fig. 1 "original code" baseline.
 """
 from __future__ import annotations
 

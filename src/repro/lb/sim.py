@@ -20,8 +20,9 @@ buffers, ``lax.scan`` stepping — is owned by
 * :meth:`BinaryFluidSim.run` executes n steps under one jitted
   ``lax.scan`` (``donate=True`` ping-pongs the field buffers).
 
-``fused`` selects the hot-loop fusion strategy (all trajectories match
-state-for-state):
+``fused`` selects the hot-loop fusion strategy (all trajectories agree
+state for state under the cross-path contract,
+:func:`repro.core.state.check_fields`):
 
 * ``False`` — the 4-launch unfused pipeline above (one 5-stage Program).
 * ``"one_launch"`` (or ``True``) — one stencil stage per step
@@ -34,7 +35,9 @@ state-for-state):
 In every fused mode the iterated state is the pre-stream populations
 w = collide(u), since (stream∘collide)ⁿ = stream ∘ (collide∘stream)ⁿ⁻¹ ∘
 collide — the prologue (collide) and epilogue (stream) run once as their
-own Programs, so fused and unfused trajectories match state-for-state.
+own Programs, so fused and unfused trajectories agree state for state
+(each holds to the benchmark's plain float32 reference,
+``tests/test_reference.py``).
 """
 from __future__ import annotations
 

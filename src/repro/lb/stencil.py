@@ -31,15 +31,17 @@ pre-stream populations that determine φ at itself and its six gradient
 neighbours.  The **two-launch** variant (:data:`PHI_STREAM_SPEC` +
 :data:`FUSED_TWO_SPEC`) trades that 57-offset gather for a 1-component
 streamed-φ intermediate (ROADMAP stencil-memory stage (a)) while keeping
-the identical accumulation order — the trajectories match bit-for-bit.
+the identical accumulation order, so the two compute the same numbers
+(tests hold them to each other under the cross-path contract,
+:func:`repro.core.state.check_fields`).
 
 Every spec here runs unchanged on every registered executor, including
 the gather-free ``"pallas_windowed"`` one (stage (b)): its
 ``wants="halo_extended"`` capability swaps the launch prologue, never
 the kernels — offsets the bodies address via the static ``_PULL_IDX`` /
 ``_FUSED_G_IDX`` slot tables are resolved in-kernel from the same
-``Stencil`` descriptors (bit-identity with ``"xla"`` pinned by
-``tests/test_windowed.py``).
+``Stencil`` descriptors (agreement with ``"xla"`` pinned by
+``tests/test_windowed.py`` under the cross-path contract).
 """
 from __future__ import annotations
 

@@ -133,7 +133,12 @@ from repro.core.health import (  # noqa: F401
     HealthError,
     HealthPolicy,
 )
-from repro.core.state import ProgramState, validate_field  # noqa: F401
+from repro.core.state import (  # noqa: F401
+    FIELD_ATOL,
+    ProgramState,
+    check_fields,
+    validate_field,
+)
 from repro.core.memory import (  # noqa: F401
     BatchedConst,
     TargetConst,
@@ -170,6 +175,7 @@ __all__ = [
     "copy_from_target", "sync_target", "target_free", "target_malloc",
     "fleet", "FleetProgram", "FleetDriver", "Ticket",
     "ProgramState", "BatchedConst", "validate_field",
+    "check_fields", "FIELD_ATOL",
     "health", "faults", "HealthPolicy", "HealthError", "Diagnosis",
     "InjectedFault",
 ]

@@ -311,6 +311,3 @@ class TestAbsorbedAnalysis:
         assert t["dominant"] == "compute"
         assert t["useful_ratio"] == pytest.approx(0.5)
         assert t["fits"] is True
-        # and the roofline CLI's terms() is the same arithmetic
-        from benchmarks.roofline import terms
-        assert terms(rec) == t

@@ -532,7 +532,6 @@ shard = prog.compile(tdp.Target("xla", vvl=64, mesh=mesh,
                      grid_shape=grid).vmap(B)
 a = local.run(state, 3)
 b = shard.run(state, 3)
-for f in ("f", "g"):
-    np.testing.assert_array_equal(np.asarray(a[f]), np.asarray(b[f]))
+tdp.check_fields(a, b)
 print("sharded-fleet-ok")
 """)

@@ -5,14 +5,15 @@ Pinned here:
 * the SoA↔AoSoA transforms are exact inverses for every extent —
   odd sizes, remainder blocks, ``nsites < vvl``, ``ncomp > 1``, and
   leading (``noffsets``) axes — with zero-padded pad lanes;
-* every executor (gathered xla / pallas, windowed pallas) produces
-  **bit-identical** outputs under ``layout="aosoa"`` for every valid
-  vvl, including mixed pointwise+stencil kernels, consts, site_index,
-  multi-output, and ``plane_block > 1`` windows;
-* the 10-step LB fused trajectory at 16³ is bit-identical across
-  layout × vvl × executor;
+* every executor (gathered xla / pallas, windowed pallas) produces the
+  same outputs under ``layout="aosoa"`` as under SoA for every valid
+  vvl (held to the cross-path contract, ``check_fields``), including
+  mixed pointwise+stencil kernels, consts, site_index, multi-output,
+  and ``plane_block > 1`` windows;
+* the 10-step LB fused trajectory at 16³ agrees across layout × vvl ×
+  executor;
 * the ported LM kernels (rmsnorm / gated_act / mamba_scan) run through
-  ``tdp.launch`` on both layouts with bit-identical results — the
+  ``tdp.launch`` on both layouts with the same results — the
   beyond-the-lattice acceptance pin;
 * plan-build validation: an indivisible windowed-AoSoA vvl and a
   VMEM-overflowing window each raise *named* compile-time errors;
@@ -34,6 +35,7 @@ from repro.core import (
     WindowVmemError,
     aosoa_to_soa,
     as_target,
+    check_fields,
     soa_to_aosoa,
 )
 from repro.core.api import launch, launch_plan
@@ -135,14 +137,13 @@ class TestExecutorBitIdentity:
             t = Target(backend, vvl=vvl, layout=layout)
             outs[layout] = launch(spec, t, f, r, lattice=lat,
                                   consts={"alpha": 1.5, "w": w})
-        for a, b in zip(outs["soa"], outs["aosoa"]):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        check_fields(outs["soa"], outs["aosoa"])
 
     @pytest.mark.parametrize("vvl", [8, 16, 32])
     @pytest.mark.parametrize("plane_block", [1, 2, 4])
     def test_windowed_layouts_identical(self, rng, vvl, plane_block):
         """The windowed executor's AoSoA VMEM tiles reproduce the SoA
-        path bit-for-bit for every valid vvl × plane_block."""
+        path for every valid vvl × plane_block."""
         lat = Lattice((8, 8, 4))                 # interior plane = 32 sites
         spec = _mixed_spec()
         f = jnp.asarray(rng.normal(size=(2, lat.nsites)).astype(np.float32))
@@ -154,8 +155,7 @@ class TestExecutorBitIdentity:
                        interpret=True, tuning={"plane_block": plane_block})
             outs[layout] = launch(spec, t, f, r, lattice=lat,
                                   consts={"alpha": 1.5, "w": w})
-        for a, b in zip(outs["soa"], outs["aosoa"]):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        check_fields(outs["soa"], outs["aosoa"])
 
     def test_windowed_matches_xla_under_aosoa(self, rng):
         lat = Lattice((6, 4, 8))
@@ -168,14 +168,13 @@ class TestExecutorBitIdentity:
                    consts={"alpha": 1.5, "w": w})
         b = launch(spec, Target("xla", vvl=64), f, r, lattice=lat,
                    consts={"alpha": 1.5, "w": w})
-        for x, y in zip(a, b):
-            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        check_fields(b, a)
 
 
 @pytest.mark.slow
 class TestLBTrajectory:
-    """Acceptance pin: 10 fused LB steps at 16³, bit-identical across
-    layout × vvl × executor."""
+    """Acceptance pin: 10 fused LB steps at 16³ agree across layout ×
+    vvl × executor under the cross-path contract."""
 
     def test_trajectory_layout_sweep(self):
         from repro.lb.params import LBParams
@@ -193,10 +192,8 @@ class TestLBTrajectory:
                 sim = BinaryFluidSim((16, 16, 16), params=p,
                                      fused="one_launch", target=t)
                 got = sim.step(st0, 10)
-                np.testing.assert_array_equal(np.asarray(got.f),
-                                              np.asarray(want.f))
-                np.testing.assert_array_equal(np.asarray(got.g),
-                                              np.asarray(want.g))
+                check_fields({"f": want.f, "g": want.g},
+                             {"f": got.f, "g": got.g})
 
 
 # ---------------------------------------------------------------------------
@@ -212,7 +209,7 @@ class TestPortedKernels:
         outs = [np.asarray(ops.rmsnorm(
             x, w, target=Target(backend, vvl=32, layout=lay)))
             for lay in ("soa", "aosoa")]
-        np.testing.assert_array_equal(outs[0], outs[1])
+        check_fields([outs[0]], [outs[1]])
         from repro.kernels import ref
         np.testing.assert_allclose(outs[0], np.asarray(ref.rmsnorm_ref(x, w)),
                                    rtol=2e-4, atol=2e-5)
@@ -227,7 +224,7 @@ class TestPortedKernels:
             u, v, kind=kind,
             target=Target("pallas_interpret", vvl=96, layout=lay)))
             for lay in ("soa", "aosoa")]
-        np.testing.assert_array_equal(outs[0], outs[1])
+        check_fields([outs[0]], [outs[1]])
 
     def test_mamba_scan_layouts_identical(self, rng):
         from repro.kernels import ops
@@ -243,8 +240,7 @@ class TestPortedKernels:
         for lay in ("soa", "aosoa"):
             t = Target("pallas_interpret", vvl=16, layout=lay)
             got[lay] = ops.mamba_scan(x, dt, b, c, a, d, target=t)
-        for u, v in zip(got["soa"], got["aosoa"]):
-            np.testing.assert_array_equal(np.asarray(u), np.asarray(v))
+        check_fields(got["soa"], got["aosoa"])
         from repro.kernels import ref
         y_ref, h_ref = ref.mamba_scan_ref(x, dt, b, c, a, d)
         np.testing.assert_allclose(np.asarray(got["soa"][0]),
@@ -403,7 +399,7 @@ class TestAutotuneLayoutAxis:
             spec, Target("xla", vvl=64), [f, r], lattice=lat,
             consts={"alpha": 1.0, "w": jnp.ones((7,))},
             space=[bad, good], timer=lambda t, run: 1.0, reps=1, warmup=0,
-            check_identical=True, cache_dir=str(tmp_path))
+            check_outputs=True, cache_dir=str(tmp_path))
         assert any(bad.label == l for l, _ in report.pruned)
         assert {r_.candidate.label for r_ in report.results} >= \
             {good.label}

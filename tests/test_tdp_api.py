@@ -171,8 +171,7 @@ class TestMockExecutor:
             x = jnp.asarray(rng.normal(size=(2, 42)), jnp.float32)
             got = tdp.launch(scale2, tdp.Target("mock"), x, a=2.0)
             want = tdp.launch(scale2, tdp.Target("xla", vvl=16), x, a=2.0)
-            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                       rtol=1e-6)
+            tdp.check_fields([want], [got])
 
             lat = Lattice((4, 4, 4))
             phi = jnp.asarray(rng.normal(size=(1, lat.nsites)), jnp.float32)
@@ -180,10 +179,7 @@ class TestMockExecutor:
                                 lattice=lat)
             wa, wb = tdp.launch(GRAD_SPEC, tdp.Target("xla", vvl=16), phi,
                                 lattice=lat)
-            np.testing.assert_allclose(np.asarray(ga), np.asarray(wa),
-                                       rtol=1e-6)
-            np.testing.assert_allclose(np.asarray(gb), np.asarray(wb),
-                                       rtol=1e-6)
+            tdp.check_fields([wa, wb], [ga, gb])
         finally:
             tdp.unregister_executor("mock")
 
@@ -203,9 +199,7 @@ class TestMockExecutor:
                                     target=tdp.Target("mock_lb"))
             want = ops.lb_fused_step(f, g, grid_shape=shape, backend="xla",
                                      vvl=32)
-            for x, y in zip(got, want):
-                np.testing.assert_allclose(np.asarray(x), np.asarray(y),
-                                           rtol=1e-5, atol=1e-6)
+            tdp.check_fields(dict(zip("fg", want)), dict(zip("fg", got)))
         finally:
             tdp.unregister_executor("mock_lb")
 
@@ -341,5 +335,4 @@ class TestOpsTargets:
                        tuning={"block_f": 32})
         got = ops.gated_act(u, v, target=t)
         want = ops.gated_act(u, v, backend="xla")
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-5, atol=2e-6)
+        tdp.check_fields([want], [got])

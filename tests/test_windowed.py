@@ -2,10 +2,10 @@
 
 ROADMAP stencil-memory stage (b), pinned here (docs/stencil.md):
 
-* ``pallas_windowed`` (interpret mode on this CPU container) is
-  **bit-identical** to the ``xla`` executor on every LB stencil spec —
-  STREAM, GRAD6, and both fused modes — including the 10-step fused
-  trajectory at 16³ and caller-supplied ghost planes;
+* ``pallas_windowed`` (interpret mode on the CPU) matches the ``xla``
+  executor under the cross-path contract (``tdp.check_fields``) on every
+  LB stencil spec — STREAM, GRAD6, and both fused modes — including the
+  10-step fused trajectory at 16³ and caller-supplied ghost planes;
 * the executor is registered through the *public*
   ``register_executor(..., wants="halo_extended")`` capability surface:
   a mock capability-declaring executor runs end-to-end with zero core
@@ -14,9 +14,8 @@ ROADMAP stencil-memory stage (b), pinned here (docs/stencil.md):
   the windowed estimate depends only on the stencil *radius*, never on
   its offset count;
 * periodic dimensions are wrapped inside the kernel
-  (``wraps_periodic=True``): such a launch is **bit-identical** to the
-  same launch given wrap-filled caller ghosts, and no pad runs outside
-  the kernel;
+  (``wraps_periodic=True``): such a launch matches the same launch given
+  wrap-filled caller ghosts, and no pad runs outside the kernel;
 * a launch whose whole-plane window does not fit the VMEM limit is
   blocked in y (``LaunchPlan.y_block``), and runs the same numbers.
 """
@@ -54,85 +53,94 @@ def _rand_g(rng, n):
     return jnp.asarray(0.05 * rng.normal(size=(NVEL, n)), jnp.float32)
 
 
+def _parity_stream(rng, shape, tuning=None):
+    lat = Lattice(shape)
+    f = _rand_f(rng, lat.nsites)
+    got = tdp.launch(lbst.STREAM_SPEC, WINDOWED.with_(tuning=tuning or {}),
+                     f, lattice=lat)
+    want = tdp.launch(lbst.STREAM_SPEC, tdp.Target("xla", vvl=64), f,
+                      lattice=lat)
+    return {"f": want}, {"f": got}
+
+
+def _parity_grad6(rng):
+    lat = Lattice((16, 16, 16))
+    phi = jnp.asarray(rng.normal(size=(1, lat.nsites)), jnp.float32)
+    got = tdp.launch(lbst.GRAD6_SPEC, WINDOWED, phi, lattice=lat)
+    want = tdp.launch(lbst.GRAD6_SPEC, tdp.Target("xla", vvl=64), phi,
+                      lattice=lat)
+    return dict(zip(("grad", "lap"), want)), dict(zip(("grad", "lap"), got))
+
+
+def _parity_fused_step(rng, mode):
+    from repro.kernels import ops
+    lat = Lattice((16, 16, 16))
+    f, g = _rand_f(rng, lat.nsites), _rand_g(rng, lat.nsites)
+    got = ops.lb_fused_step(f, g, grid_shape=lat.shape, mode=mode,
+                            target=WINDOWED)
+    want = ops.lb_fused_step(f, g, grid_shape=lat.shape, mode=mode,
+                             backend="xla", vvl=64)
+    return dict(zip("fg", want)), dict(zip("fg", got))
+
+
+def _parity_ghost_halo(rng):
+    """Caller-filled ghost planes (the sharded contract, width 2 for the
+    radius-2 fused neighbourhood) reproduce the periodic gather."""
+    from repro.kernels import ops
+    shape = (8, 8, 8)
+    f, g = _rand_f(rng, 512), _rand_g(rng, 512)
+
+    def ext2(x):
+        x = np.asarray(x).reshape(NVEL, *shape)
+        return jnp.asarray(np.concatenate([x[:, -2:], x, x[:, :2]], axis=1)
+                           .reshape(NVEL, -1))
+
+    got = ops.lb_fused_step(ext2(f), ext2(g), grid_shape=shape,
+                            halo=(2, 0, 0), mode="one_launch",
+                            target=WINDOWED)
+    want = ops.lb_fused_step(f, g, grid_shape=shape, mode="one_launch",
+                             backend="xla", vvl=64)
+    return dict(zip("fg", want)), dict(zip("fg", got))
+
+
+def _parity_fused_trajectory(rng):
+    """10 fused steps of a 16³ spinodal quench."""
+    p = LBParams(A=0.125, B=0.125, kappa=0.02)
+    a = BinaryFluidSim((16, 16, 16), params=p, fused="one_launch")
+    b = BinaryFluidSim((16, 16, 16), params=p, fused="one_launch",
+                       target=WINDOWED)
+    st0 = a.init_spinodal(seed=3, noise=0.05)
+    ua, ub = a.step(st0, 10), b.step(st0, 10)
+    return {"f": ua.f, "g": ua.g}, {"f": ub.f, "g": ub.g}
+
+
 class TestWindowedParity:
-    """Bit-equivalence with the xla executor on the single-source LB
-    specs — the portability contract extended to the gather-free path."""
+    """``pallas_windowed`` against the xla executor on the single-source
+    LB specs, under the cross-path contract (:func:`tdp.check_fields`):
+    the portability contract extended to the gather-free path."""
 
-    @pytest.mark.parametrize("shape", [(16, 16, 16), (5, 4, 3)])
-    def test_stream_bit_identical(self, rng, shape):
-        lat = Lattice(shape)
-        f = _rand_f(rng, lat.nsites)
-        a = tdp.launch(lbst.STREAM_SPEC, WINDOWED, f, lattice=lat)
-        b = tdp.launch(lbst.STREAM_SPEC, tdp.Target("xla", vvl=64), f,
-                       lattice=lat)
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    CASES = {
+        "stream-16x16x16": lambda rng: _parity_stream(rng, (16, 16, 16)),
+        "stream-5x4x3": lambda rng: _parity_stream(rng, (5, 4, 3)),
+        "grad6": _parity_grad6,
+        "fused-one_launch": lambda rng: _parity_fused_step(rng,
+                                                           "one_launch"),
+        "fused-two_launch": lambda rng: _parity_fused_step(rng,
+                                                           "two_launch"),
+        # plane_block > 1 (and X not a multiple of it) only changes the
+        # TLP chunking
+        "plane_block-2": lambda rng: _parity_stream(rng, (7, 4, 5),
+                                                    {"plane_block": 2}),
+        "plane_block-3": lambda rng: _parity_stream(rng, (7, 4, 5),
+                                                    {"plane_block": 3}),
+        "ghost-halo": _parity_ghost_halo,
+        "fused-trajectory-10-steps": _parity_fused_trajectory,
+    }
 
-    def test_grad6_bit_identical(self, rng):
-        lat = Lattice((16, 16, 16))
-        phi = jnp.asarray(rng.normal(size=(1, lat.nsites)), jnp.float32)
-        ga, la = tdp.launch(lbst.GRAD6_SPEC, WINDOWED, phi, lattice=lat)
-        gb, lb = tdp.launch(lbst.GRAD6_SPEC, tdp.Target("xla", vvl=64), phi,
-                            lattice=lat)
-        np.testing.assert_array_equal(np.asarray(ga), np.asarray(gb))
-        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
-
-    @pytest.mark.parametrize("mode", ["one_launch", "two_launch"])
-    def test_fused_step_bit_identical(self, rng, mode):
-        from repro.kernels import ops
-        lat = Lattice((16, 16, 16))
-        f, g = _rand_f(rng, lat.nsites), _rand_g(rng, lat.nsites)
-        a = ops.lb_fused_step(f, g, grid_shape=lat.shape, mode=mode,
-                              target=WINDOWED)
-        b = ops.lb_fused_step(f, g, grid_shape=lat.shape, mode=mode,
-                              backend="xla", vvl=64)
-        for x, y in zip(a, b):
-            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
-
-    @pytest.mark.parametrize("plane_block", [2, 3])
-    def test_plane_block_tuning_bit_identical(self, rng, plane_block):
-        """plane_block > 1 (and X not a multiple of it) only changes the
-        TLP chunking, never the numbers."""
-        lat = Lattice((7, 4, 5))
-        f = _rand_f(rng, lat.nsites)
-        t = WINDOWED.with_(tuning={"plane_block": plane_block})
-        a = tdp.launch(lbst.STREAM_SPEC, t, f, lattice=lat)
-        b = tdp.launch(lbst.STREAM_SPEC, "xla", f, lattice=lat)
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-    def test_ghost_halo_mode_bit_identical(self, rng):
-        """Caller-filled ghost planes (the sharded contract, width 2 for
-        the radius-2 fused neighbourhood) reproduce the periodic gather."""
-        from repro.kernels import ops
-        shape = (8, 8, 8)
-        n = 512
-        f, g = _rand_f(rng, n), _rand_g(rng, n)
-        fg = np.asarray(f).reshape(NVEL, *shape)
-        gg = np.asarray(g).reshape(NVEL, *shape)
-
-        def ext2(x):
-            return np.concatenate([x[:, -2:], x, x[:, :2]], axis=1)
-
-        fe = jnp.asarray(ext2(fg).reshape(NVEL, -1))
-        ge = jnp.asarray(ext2(gg).reshape(NVEL, -1))
-        a = ops.lb_fused_step(fe, ge, grid_shape=shape, halo=(2, 0, 0),
-                              mode="one_launch", target=WINDOWED)
-        b = ops.lb_fused_step(f, g, grid_shape=shape, mode="one_launch",
-                              backend="xla", vvl=64)
-        for x, y in zip(a, b):
-            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
-
-    def test_fused_trajectory_bit_identical_to_xla(self):
-        """The acceptance pin: 10 fused steps at 16³ on pallas_windowed
-        produce the bit-identical trajectory to the same steps on xla."""
-        p = LBParams(A=0.125, B=0.125, kappa=0.02)
-        a = BinaryFluidSim((16, 16, 16), params=p, fused="one_launch")
-        b = BinaryFluidSim((16, 16, 16), params=p, fused="one_launch",
-                           target=WINDOWED)
-        st0 = a.init_spinodal(seed=3, noise=0.05)
-        ua = a.step(st0, 10)
-        ub = b.step(st0, 10)
-        np.testing.assert_array_equal(np.asarray(ua.f), np.asarray(ub.f))
-        np.testing.assert_array_equal(np.asarray(ua.g), np.asarray(ub.g))
+    @pytest.mark.parametrize("case", list(CASES))
+    def test_matches_xla(self, rng, case):
+        want, got = self.CASES[case](rng)
+        tdp.check_fields(want, got)
 
 
 #: a 2-D nine-point neighbourhood (radius 1) and a kernel reading all of it
@@ -192,9 +200,9 @@ def _pads_outside_kernel(jaxpr) -> int:
 class TestPeriodicInKernel:
     """``pallas_windowed`` wraps periodic dimensions inside the kernel:
     x through the window's BlockSpec index modulo X, y and z by rotating
-    the loaded planes.  Pure data movement, so a launch is bit-identical
-    to the same launch handed wrap-filled ghost planes in every
-    dimension (nothing wrapped in-kernel)."""
+    the loaded planes.  A launch matches the same launch handed
+    wrap-filled ghost planes in every dimension (nothing wrapped
+    in-kernel) under the cross-path contract."""
 
     CASES = {
         # name: (spec, shape, in-kernel-wrapping launch halo, tuning, layout)
@@ -234,9 +242,7 @@ class TestPeriodicInKernel:
         assert all(w == tuple(d for d, h in enumerate(halo) if h == 0)
                    for w, fs in zip(wraps.wrap_dims, spec.fields)
                    if fs.stencil is not None)
-        got, want = launch(halo), launch(r)
-        for a, b in zip(got, want):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        tdp.check_fields(launch(r), launch(halo))
 
     @pytest.mark.parametrize("halo", [(0, 0, 0), (1, 1, 0)])
     def test_no_pad_outside_the_kernel(self, rng, halo):
@@ -257,14 +263,6 @@ class TestPeriodicInKernel:
         jaxpr = jax.make_jaxpr(lambda x: halo_extend(
             x, shape, halo, lbst.STENCIL_D3Q19_PULL))(f)
         assert _pads_outside_kernel(jaxpr.jaxpr) > 0
-
-
-#: Gap allowed between the y-blocked launch and another executor or the
-#: whole-plane launch.  The values moved are the same, but XLA's CPU
-#: backend may contract the site arithmetic's multiply-adds into FMAs
-#: differently for a chunk of another shape, which moves the last bit of
-#: a population (ROADMAP D1); a wrong neighbour moves it by ~1e-2.
-BLOCKED_ATOL = 1e-6
 
 
 def _blocked_plan(spec, shape, y_block, halo=None, consts=None,
@@ -293,12 +291,6 @@ def _fg(rng, shape):
     return _rand_f(rng, n), _rand_g(rng, n)
 
 
-def _assert_close(got, want):
-    for a, b in zip(got, want):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0,
-                                   atol=BLOCKED_ATOL)
-
-
 class TestYBlockedWindow:
     """A window that does not fit the VMEM limit is cut into y-blocks of
     ``y_block`` rows (plus 8 halo rows on either side), each a step of a
@@ -314,7 +306,7 @@ class TestYBlockedWindow:
                         g.reshape(NVEL, *self.SHAPE))
         want = tdp.launch(lbst.FUSED_SPEC, tdp.Target("xla", vvl=64), f, g,
                           lattice=Lattice(self.SHAPE), consts=_FUSED_CONSTS)
-        _assert_close(got, want)
+        tdp.check_fields(dict(zip("fg", want)), dict(zip("fg", got)))
 
     def test_fused_five_step_trajectory_matches_xla(self, rng):
         plan = _blocked_plan(lbst.FUSED_SPEC, self.SHAPE, 8,
@@ -329,7 +321,7 @@ class TestYBlockedWindow:
         a = b = _fg(rng, self.SHAPE)
         for _ in range(5):
             a, b = step(*a), ref(*b)
-        _assert_close(a, b)
+        tdp.check_fields(dict(zip("fg", b)), dict(zip("fg", a)))
 
     @pytest.mark.parametrize("case", ["fused", "pointwise-site-index-p2"])
     def test_y_block_not_dividing_y(self, rng, case):
@@ -352,7 +344,7 @@ class TestYBlockedWindow:
         want = tdp.launch(spec, tdp.Target("xla", vvl=64), *xs, lattice=lat,
                           consts=consts)
         want = want if isinstance(want, tuple) else (want,)
-        _assert_close(got, want)
+        tdp.check_fields(want, got)
 
     def test_y_wraps_across_first_and_last_block(self, rng):
         """Streaming moves data only, so it is exact: row 0 of the first
@@ -398,7 +390,7 @@ class TestYBlockedWindow:
                         halo[2]:grid.shape[3] - halo[2]]
         got = _run_plan(plan, *(prepared(x, fs.stencil) for x, fs in
                                 zip((fe, ge), lbst.FUSED_SPEC.fields)))
-        _assert_close(got, want)
+        tdp.check_fields(dict(zip("fg", want)), dict(zip("fg", got)))
 
     def test_refused_when_the_smallest_block_does_not_fit(self, rng):
         """A 512-lane z extent overflows the 16 MiB interpret limit even
@@ -430,7 +422,7 @@ class TestOutputStore:
     """Outputs are stored in the lattice's grid layout where their blocks'
     rows are whole sublane tiles, flat (``_flat`` in the kernel's name)
     where they are not; either way every site lands where the xla
-    executor puts it."""
+    executor puts it (held to it under the cross-path contract)."""
 
     CASES = {
         # name: (spec, shape, caller ghosts, plane_block, y_block, form)
@@ -476,8 +468,7 @@ class TestOutputStore:
         got = jax.jit(fn)(*xs)
         got = got if isinstance(got, tuple) else (got,)
         want = want if isinstance(want, tuple) else (want,)
-        for a, b in zip(got, want, strict=True):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        tdp.check_fields(want, got)
 
 
 class TestHaloExtend:
@@ -616,8 +607,7 @@ class TestCapabilitySurface:
             ga, la = tdp.launch(lbst.GRAD6_SPEC, tdp.Target("mock_windowed"),
                                 phi, lattice=lat)
             gb, lb = tdp.launch(lbst.GRAD6_SPEC, "xla", phi, lattice=lat)
-            np.testing.assert_array_equal(np.asarray(ga), np.asarray(gb))
-            np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
+            tdp.check_fields({"grad": gb, "lap": lb}, {"grad": ga, "lap": la})
         finally:
             tdp.unregister_executor("mock_windowed")
 
